@@ -32,24 +32,25 @@ def _inst(n, K, seed=0):
             jnp.asarray(bw, jnp.float32), jnp.asarray(breq, jnp.float32))
 
 
-# Tile configs swept for the batched fused-superstep kernel.  Interpret-mode
-# wall clock is an emulation (relative) number; the TPU-relevant criterion is
-# the VMEM model: pick the largest network tiles that keep the double-
-# buffered live set well inside ~16 MB, then the largest b_tile (each
-# increment amortizes one more request onto the shared lat/bw tile fetch).
+# Tile configs swept for the batched superstep kernel, all (8, 128)-aligned
+# so each also lowers for the TPU.  Interpret-mode wall clock is an
+# emulation (relative) number; the TPU-relevant criterion is the VMEM model:
+# pick the largest network tiles that keep the double-buffered live set well
+# inside ~16 MB, then the largest b_tile (each increment amortizes one more
+# request onto the shared lat/bw tile fetch).
 BATCHED_SWEEP = [
-    (1, 8, 8, 8),
-    (2, 8, 8, 8),
-    (4, 8, 8, 8),
-    (2, 16, 16, 8),
-    (4, 16, 8, 4),
-    (8, 16, 16, 8),
+    (1, 128, 128),
+    (2, 128, 128),
+    (4, 128, 128),
+    (8, 128, 128),
+    (4, 256, 128),
+    (4, 128, 256),
 ]
 
 
 def run_batched_sweep(*, n: int = 12, ps=(4, 6, 3, 5), seed: int = 9,
                       out_path: str = "BENCH_kernel.json"):
-    """Sweep (b_tile, v_tile, w_tile, k_tile) for the batched superstep:
+    """Sweep (b_tile, v_tile, w_tile) for the batched superstep:
     interpret-mode parity vs the fused-jnp oracle + per-config VMEM model,
     plus fused-ref vs vmapped-jnp DP timings at online-placer shapes."""
     from repro.core import random_dataflow, waxman
@@ -67,8 +68,8 @@ def run_batched_sweep(*, n: int = 12, ps=(4, 6, 3, 5), seed: int = 9,
                                 impl="ref")
     sweep = []
     for tiles in BATCHED_SWEEP:
-        b_t, v_t, w_t, k_t = tiles
-        K_pad = -(-(p_max + 1) // k_t) * k_t
+        b_t, v_t, w_t = tiles
+        K_pad = -(-(p_max + 1) // bk.K_ALIGN) * bk.K_ALIGN
         t0 = time.perf_counter()
         out = _leastcost_dp_batched(tensors, B=B, n=n, p=p_max,
                                     max_rounds=n - 1, impl="interpret",
@@ -86,11 +87,11 @@ def run_batched_sweep(*, n: int = 12, ps=(4, 6, 3, 5), seed: int = 9,
             for a, b in zip(ref[:5], out[:5])
         )
         sweep.append({
-            "tiles": {"b": b_t, "v": v_t, "w": w_t, "k": k_t},
+            "tiles": {"b": b_t, "v": v_t, "w": w_t},
             "parity_vs_ref": ok,
             "first_call_s": t_first,
             "interpret_warm_s": t_warm,
-            "vmem_model_bytes": bk.vmem_model_bytes(b_t, v_t, w_t, k_t, K_pad),
+            "vmem_model_bytes": bk.vmem_model_bytes(b_t, v_t, w_t, K_pad),
         })
 
     # fused-ref vs vmapped-jnp at the shapes the online placer sees
@@ -112,7 +113,7 @@ def run_batched_sweep(*, n: int = 12, ps=(4, 6, 3, 5), seed: int = 9,
                         "fused_ref_s": t_k,
                         "speedup": t_v / max(t_k, 1e-9)})
 
-    defaults = dict(zip(("b", "v", "w", "k"), bk.DEFAULT_TILES))
+    defaults = dict(zip(("b", "v", "w"), bk.DEFAULT_TILES))
     record = {
         "defaults": defaults,
         "defaults_vmem_bytes": bk.vmem_model_bytes(*bk.DEFAULT_TILES, 8),
@@ -165,7 +166,7 @@ def run():
         "derived": (
             f"parity={ok}/{len(rec['sweep'])};"
             f"defaults=b{rec['defaults']['b']}v{rec['defaults']['v']}"
-            f"w{rec['defaults']['w']}k{rec['defaults']['k']};"
+            f"w{rec['defaults']['w']};"
             f"vmem_bytes={rec['defaults_vmem_bytes']};"
             f"fused_vs_vmapped={best['speedup']:.2f}x"
         ),
@@ -174,4 +175,7 @@ def run():
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
     print(json.dumps(run_batched_sweep(), indent=2))

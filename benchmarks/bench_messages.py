@@ -487,6 +487,10 @@ def run_regional(
 if __name__ == "__main__":
     import argparse
 
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="regional sweep only, CI sizes; writes "

@@ -48,7 +48,7 @@ def run(n_instances: int = 40, sizes=(15, 25), p: int = 6, seed0: int = 0):
                 if mj is not None:
                     ok, _ = validate_mapping(rg, df, mj)
                     assert ok
-                fallbacks += int(jst.fallback_used)
+                fallbacks += jst.fallbacks
                 ratios.append(est.max_set_size / max(pst.max_set_size, 1))
             if feas == 0:
                 continue

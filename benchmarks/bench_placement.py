@@ -982,6 +982,10 @@ def run():
 if __name__ == "__main__":
     import argparse
 
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="online + streaming + fairness only, small sizes "

@@ -127,6 +127,10 @@ def run_json(seed0: int = 300, smoke: bool = False,
 if __name__ == "__main__":
     import argparse
 
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes; CI fast-lane budget")

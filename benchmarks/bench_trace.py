@@ -436,6 +436,10 @@ def run(smoke: bool = True):
 if __name__ == "__main__":
     import argparse
 
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="n=1024 only; CI slow-lane budget")

@@ -175,4 +175,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.device import enable_compile_cache
+
+    enable_compile_cache()
+
     main()

@@ -39,17 +39,6 @@ from .leastcost import HeuristicStats, _place_step
 from .problem import BIG, EPS_CAP_F32, EPS_IMPROVE, creq_prefix, finite_lat
 from .reconstruct import reconstruct_mapping
 
-# jax >= 0.6 promotes shard_map to the top-level namespace; older releases
-# (the pinned 0.4.x) only ship the experimental entry point.
-_shard_map = getattr(jax, "shard_map", None)
-_SHARD_MAP_KW: dict = {}
-if _shard_map is None:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # the experimental tracer has no replication rule for while_loop
-    _SHARD_MAP_KW = {"check_rep": False}
-
-
 @dataclasses.dataclass
 class DistStats(HeuristicStats):
     messages_total: int = 0  # async-equivalent messages
@@ -135,12 +124,11 @@ def leastcost_shard_map(
     rep = NamedSharding(mesh, P())
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(None, axis), P(None, axis),
                   P(), P(), P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis), P(), P()),
-        **_SHARD_MAP_KW,
     )
     def run(C, pv, pj, cap_loc, lat_cols, bw_cols, prefix, breq_k, out_deg, out_deg_x):
         def cond(s):

@@ -52,7 +52,9 @@ class Stats:
     messages_cross_device: int = 0  # BSP: messages crossing a partition
     max_set_size: int = 0  # peak live partial-map states
     maps_generated: int = 0
-    fallback_used: bool = False  # tensorized backends: path-carrying rescue
+    # tensorized backends: requests whose device answer failed to backtrack
+    # or validate and were re-solved by the host path-carrying solver
+    fallbacks: int = 0
     validated: bool = True
     kernel_impl: str = ""  # use_kernel paths: "pallas" | "interpret" | "ref"
     virtual_time: float = 0.0  # simulator virtual completion time
@@ -103,7 +105,7 @@ def _unify(native, method: str) -> Stats:
     s.messages_cross_device = int(getattr(native, "messages_cross_device", 0))
     s.max_set_size = int(getattr(native, "max_set_size", 0))
     s.maps_generated = int(getattr(native, "total_maps_generated", 0))
-    s.fallback_used = bool(getattr(native, "fallback_used", False))
+    s.fallbacks = int(bool(getattr(native, "fallback_used", False)))
     s.validated = bool(getattr(native, "validated", True))
     s.kernel_impl = str(getattr(native, "kernel_impl", ""))
     s.virtual_time = float(
@@ -225,7 +227,7 @@ def solve_batch(
             stats.messages_sent += st.messages_sent
             stats.rounds = max(stats.rounds, st.rounds)
             stats.max_set_size = max(stats.max_set_size, st.max_set_size)
-            stats.fallback_used |= st.fallback_used
+            stats.fallbacks += st.fallbacks
             stats.validated &= st.validated
             stats.preemptions += st.preemptions
             stats.defrag_rounds += st.defrag_rounds

@@ -23,10 +23,10 @@
 Shared constants/tensors come from ``core.problem``; ``leastcost_jax_batched``
 solves many (possibly mixed-``p``) requests on one shared network in one
 batched DP — the continuous-arrival path behind ``core.online.OnlinePlacer``.
-With ``use_kernel=True`` the whole superstep (place + move + monotone update)
-runs as the fused batched Pallas kernel of ``repro.kernels.minplus.batched``
-(grid over (batch, w, k, v) with network tiles shared across the batch);
-off-TPU the kernel's fused-jnp mirror replaces the vmapped per-request graph.
+With ``use_kernel=True`` the move and monotone update run as the batched
+Pallas kernel of ``repro.kernels.minplus.batched`` (grid over (batch, w, v)
+with network tiles shared across the batch); off-TPU the kernel's fused-jnp
+mirror replaces the vmapped per-request graph.
 """
 from __future__ import annotations
 
@@ -57,6 +57,7 @@ from .problem import (
     stack_requests,
     BATCH_IN_AXES,
 )
+from .device import on_tpu
 from .reconstruct import reconstruct_mapping
 
 
@@ -68,13 +69,6 @@ class HeuristicStats:
     fallback_used: bool = False
     validated: bool = True
     kernel_impl: str = ""  # "", "pallas", "interpret", or "ref"
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 # ---------------------------------------------------------------------------
@@ -508,7 +502,6 @@ def leastcost_jax_batched_dispatch(
     validate: bool = True,
     max_rounds: Optional[int] = None,
     use_kernel: bool = False,
-    kernel_impl: Optional[str] = None,
     tiles=None,
     bucket_batch: bool = False,
     graph_tensors=None,
@@ -554,7 +547,7 @@ def leastcost_jax_batched_dispatch(
     max_rounds = max_rounds or (n - 1 if n > 1 else 1)
     impl = ""
     if use_kernel:
-        impl = kernel_impl or ("pallas" if _on_tpu() else "ref")
+        impl = "pallas" if on_tpu() else "ref"
         C, par_v, par_j, best_cost, best_j, rounds = _leastcost_dp_batched(
             tensors, B=B, n=n, p=p_max, max_rounds=max_rounds,
             impl=impl, tiles=tiles,
@@ -591,7 +584,7 @@ def leastcost_jax_batched_finalize(pending: PendingDP, stats=None) -> list:
             )
         )
         if stats is not None:
-            stats.fallback_used |= per.fallback_used
+            stats.fallbacks += int(per.fallback_used)
             stats.validated &= per.validated
     return out
 
@@ -603,7 +596,6 @@ def leastcost_jax_batched(
     validate: bool = True,
     max_rounds: Optional[int] = None,
     use_kernel: bool = False,
-    kernel_impl: Optional[str] = None,
     tiles=None,
     bucket_batch: bool = False,
     stats=None,
@@ -622,9 +614,8 @@ def leastcost_jax_batched(
 
     ``use_kernel=True`` selects the fused batched superstep path
     (``repro.kernels.minplus.batched``) instead of vmapping the per-request
-    DP: the Pallas kernel on TPU, its fused-jnp mirror elsewhere.
-    ``kernel_impl`` overrides the dispatch ("pallas" | "interpret" | "ref");
-    ``tiles`` = (b_tile, v_tile, w_tile, k_tile) for the Pallas grid.
+    DP: the compiled Pallas kernel on TPU, its fused-jnp mirror elsewhere.
+    ``tiles`` = (b_tile, v_tile, w_tile) for the Pallas grid.
 
     ``bucket_batch=True`` pads the batch dimension to the next power of two
     at the TENSOR level (dummy rows, ignored by the reconstruction loop), so
@@ -632,12 +623,12 @@ def leastcost_jax_batched(
     DP specializations — the online placer's admission path sets this.
 
     ``stats`` (optional, e.g. the engine's unified ``Stats``) aggregates
-    anomaly signals across the batch: ``fallback_used`` is set if ANY
-    request needed the path-carrying rescue, ``validated`` cleared if ANY
+    anomaly signals across the batch: ``fallbacks`` counts the requests
+    that needed the path-carrying rescue, ``validated`` is cleared if ANY
     reconstruction failed validation."""
     pending = leastcost_jax_batched_dispatch(
         rg, dfs, validate=validate, max_rounds=max_rounds,
-        use_kernel=use_kernel, kernel_impl=kernel_impl, tiles=tiles,
+        use_kernel=use_kernel, tiles=tiles,
         bucket_batch=bucket_batch, graph_tensors=graph_tensors,
         warm_starts=warm_starts,
     )
@@ -649,7 +640,6 @@ def leastcost_jax(
     df: DataflowPath,
     *,
     use_kernel: bool = False,
-    kernel_impl: Optional[str] = None,
     tiles=None,
     max_rounds: Optional[int] = None,
     validate: bool = True,
@@ -673,7 +663,7 @@ def leastcost_jax(
     if warm_start is not None and not isinstance(warm_start, dict):
         warm_start = warm_seed_from_mapping(rg, df, warm_start)
     if use_kernel:
-        impl = kernel_impl or ("pallas" if _on_tpu() else "ref")
+        impl = "pallas" if on_tpu() else "ref"
         stats.kernel_impl = impl
         tensors, _ = stack_requests(rg, [df])
         if warm_start is not None:

@@ -107,6 +107,9 @@ class OnlineStats:
     cache_neg_hits: int = 0  # exact-stamp negative entry short-circuited
     warm_solves: int = 0  # bounded correction solves seeded from stale entries
     warm_fallbacks: int = 0  # warm pass placed nothing -> cold re-solve
+    # device answers that failed to backtrack or validate and were re-solved
+    # on the host (core.reconstruct): nonzero means the DP answered wrong
+    fallbacks: int = 0
     # solves per kernel backend ("pallas" / "ref" / native impl name):
     # non-additive engine.Stats fields (kernel_impl) carried as labeled
     # counts instead of last-writer-wins when stats fold across regions
@@ -122,7 +125,7 @@ class OnlineStats:
     _SOLVE_CARRY = (
         "solve_ms", "overhead_ms", "conflict_resolve_ms", "solves",
         "solve_n_sum", "cache_hits", "cache_misses", "cache_stale",
-        "cache_neg_hits", "warm_solves", "warm_fallbacks",
+        "cache_neg_hits", "warm_solves", "warm_fallbacks", "fallbacks",
     )
 
     @property
@@ -236,11 +239,11 @@ class OnlinePlacer:
         max_correction_supersteps: int = 4,
         **solve_cfg,
     ):
-        """``use_kernel=True`` serves admissions through the fused batched
-        Pallas DP path (``kernels/minplus/batched``; Pallas on TPU, its
-        fused-jnp mirror elsewhere) — both micro-batched ``admit_many`` and
-        single-request ``admit`` re-solves take it.  Extra ``solve_cfg``
-        (e.g. ``tiles`` or ``kernel_impl``) is forwarded to the backend.
+        """``use_kernel=True`` serves admissions through the batched
+        Pallas DP path (``kernels/minplus/batched``; the compiled kernel on
+        TPU, its fused-jnp mirror elsewhere) — both micro-batched
+        ``admit_many`` and single-request ``admit`` re-solves take it.
+        Extra ``solve_cfg`` (e.g. ``tiles``) is forwarded to the backend.
 
         ``cache_enabled`` turns on the two-tier incremental fast path: a
         :class:`~repro.core.solution_cache.SolutionCache` of the last
@@ -445,6 +448,7 @@ class OnlinePlacer:
         self.stats.solve_ms += st.solve_ms
         self.stats.solves += 1
         self.stats.solve_n_sum += st.solve_n
+        self.stats.fallbacks += st.fallbacks
         if st.kernel_impl:
             k = self.stats.kernel_impls
             k[st.kernel_impl] = k.get(st.kernel_impl, 0) + 1

@@ -33,6 +33,8 @@ rewrite ``lat`` semantics, not just magnitudes).
 """
 from __future__ import annotations
 
+import itertools
+
 import numpy as np
 
 from .graph import INF, ResourceGraph
@@ -155,9 +157,11 @@ class ResidualState:
         for the DP buckets)."""
         self.device_tensors()  # materialize the mirror (full-upload path)
         n = self.base.n
-        pairs = [(u, v) for u in range(n) for v in range(n) if u != v]
+        limit = min(4 * n, n * (n - 1))
+        pairs = list(itertools.islice(
+            ((u, v) for u in range(n) for v in range(n) if u != v), limit))
         k = 1
-        while k <= min(4 * n, len(pairs)):
+        while k <= limit:
             self._node_delta = {v: 0.0 for v in range(min(k, n))}
             self._edge_delta = {pairs[i]: 0.0 for i in range(k)}
             self.device_tensors()

@@ -1,35 +1,28 @@
-"""Batched fused Pallas TPU kernel: one full DP superstep for a request grid.
+"""Batched Pallas TPU kernel: one DP superstep for a request grid.
 
 ``core.leastcost.leastcost_jax_batched`` serves the online placer: B mapping
-requests relax against ONE shared resource network.  The vmapped-jnp path
-re-streams the shared ``lat``/``bw`` matrices from HBM once per request and
-materializes per-request candidate slabs; this kernel instead runs the whole
-superstep
+requests relax against ONE shared resource network.  One superstep is
 
     place:  P[b,v,k]  = min_{j<=k, prefix[b,k]-prefix[b,j] <= cap[v]} C[b,v,j]
     move:   C'[b,w,k] = min_{v, bw[v,w] >= breq_k[b,k]}  P[b,v,k] + lat[v,w]
     update: Cn = where(C' < C - EPS_IMPROVE, C', C)   (+ parent pointers)
 
-as ONE ``pallas_call`` with grid ``(batch, w_blocks, k_blocks, v_blocks)``.
-The network tiles (``lat``/``bw``/``cap``) use index maps that IGNORE the
-batch coordinate, so they are the same VMEM-resident tiles for every request
-(the pipeline skips the re-fetch whenever consecutive grid steps map to the
-same block); per-request operands (``prefix``/``breq_k``/state) are
-batch-indexed.  The intermediate P tensor and the (V, W, K) move candidates
-never touch HBM: the move reduction is unrolled per k column as fused
-mask/shift/min VPU ops on (V, W) tiles.
+The place step is O(B * n * K^2) and runs as the jnp mirror
+(:func:`_place_batched_ref`).  The move and the update, O(B * n^2 * K), run
+as ONE ``pallas_call`` with grid ``(batch, w_blocks, v_blocks)``:
 
-HBM-traffic model per superstep (fp32 words, K_pad = padded p_max+1):
-  vmapped jnp : O(B * n^2 * K)     (per-request (w, v) slabs for every k,
-                                    link matrices broadcast per request)
-  this kernel : O((B / b_tile) * ceil(K_pad / k_tile) * n^2  +  B * n * K_pad)
-                -> O(n^2 + B * n * K) when one (b, k) block covers the batch
-                   and prefix columns (the common online-placer shape).
+- the network tiles (``lat``/``bw``) use index maps that IGNORE the batch
+  coordinate, so they are the same VMEM-resident tiles for every request;
+- a ``b_tile``-row batch block amortizes each network tile over ``b_tile``
+  requests, walked by a ``fori_loop`` (the compiled body holds one request);
+- the K columns of one request are one block (``K_pad`` = p_max+1 rounded up
+  to 8), unrolled per column as mask/shift/min VPU ops on a (V, W) tile; the
+  (V, W, K) move candidates never touch HBM;
+- ``P`` enters v-major (B, n, K) so a column is a lane broadcast; the state
+  is K-major (B, K, n) inside the call so each column's result is a row.
 
-A ``b_tile``-row batch block amortizes each shared network tile over
-``b_tile`` requests (unrolled in-kernel, so VMEM live-set stays at one
-request's working set).  Min-plus has no MXU path; everything runs on the
-VPU with (8, 128)-aligned tiles.
+HBM traffic per superstep (fp32 words): O((B / b_tile) * n^2 + B * n * K *
+n / w_tile).  Min-plus has no MXU path; everything runs on the VPU.
 
 ``batched_superstep_ref`` is the fused pure-jnp oracle used off-TPU and as
 the CI cross-check: it mirrors ``core.leastcost._superstep``'s exact update
@@ -42,111 +35,84 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.problem import BIG, EPS_CAP_F32, EPS_IMPROVE
 
-try:  # TPU compiler params (ignored in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
-
-# Defaults: largest (8,128)-aligned network tiles that keep the double-
-# buffered live set well inside 16 MB VMEM (see benchmarks/bench_kernel.py
-# sweep; VMEM model below).  b_tile=8 amortizes each lat/bw tile fetch over
-# 8 requests at ~zero extra VMEM (the batch loop is unrolled).
+# Defaults: (8, 128)-aligned network tiles; b_tile=8 amortizes each lat/bw
+# tile fetch over 8 requests (smaller batches use b_tile = B).
 B_TILE = 8
 V_TILE = 128
 W_TILE = 128
-K_TILE = 8
+K_ALIGN = 8  # K_pad = p_max + 1 rounded up to the sublane count
 
-DEFAULT_TILES = (B_TILE, V_TILE, W_TILE, K_TILE)
-
-
-def vmem_model_bytes(b_tile: int, v_tile: int, w_tile: int, k_tile: int,
-                     k_pad: int) -> int:
-    """fp32 VMEM live-set of one grid step (inputs + outputs + the largest
-    in-kernel intermediate, which is one request's place candidate block)."""
-    inputs = (b_tile * k_pad              # prefix (full row)
-              + b_tile * k_tile           # pre_out (this block's k columns)
-              + b_tile * k_tile           # breq_k
-              + v_tile                    # cap
-              + 2 * v_tile * w_tile       # lat, bw
-              + b_tile * v_tile * k_pad   # C slab (place input)
-              + 3 * b_tile * w_tile * k_tile)  # prev C / par_v / par_j
-    outputs = 3 * b_tile * w_tile * k_tile
-    scratch = v_tile * k_tile * k_pad + v_tile * w_tile  # place cand + move tile
-    return 4 * (inputs + outputs + scratch)
+DEFAULT_TILES = (B_TILE, V_TILE, W_TILE)
 
 
-def _superstep_kernel(prefix_ref, pre_out_ref, breq_ref, cap_ref, lat_ref,
-                      bw_ref, c_slab_ref, c_prev_ref, pv_prev_ref, pj_prev_ref,
-                      c_ref, pv_ref, pj_ref):
-    k_blk = pl.program_id(2)
-    v_blk = pl.program_id(3)
-    nv = pl.num_programs(3)
+def resolve_tiles(tiles, B: int) -> tuple[int, int, int]:
+    """``tiles`` or the defaults, with ``b_tile`` capped at the batch size."""
+    b_tile, v_tile, w_tile = tiles or DEFAULT_TILES
+    return min(b_tile, B), v_tile, w_tile
+
+
+def vmem_model_bytes(b_tile: int, v_tile: int, w_tile: int, k_pad: int) -> int:
+    """fp32 VMEM live-set of one grid step, double-buffered: the v-major
+    ``P``/``pj`` blocks (their K minor dim pads to 128 lanes), the network
+    tiles, and six K-major state blocks (three in, three out)."""
+    lanes = -(-k_pad // 128) * 128
+    blocks = (2 * b_tile * v_tile * lanes      # P, pj
+              + 2 * v_tile * w_tile            # lat, bw
+              + 6 * b_tile * k_pad * w_tile)   # prev + new C / par_v / par_j
+    return 4 * 2 * blocks
+
+
+def _move_kernel(breq_ref, p_ref, pj_ref, lat_ref, bw_ref, c_prev_ref,
+                 pv_prev_ref, pj_prev_ref, c_ref, pv_ref, pjo_ref):
+    b_blk = pl.program_id(0)
+    v_blk = pl.program_id(2)
+    nv = pl.num_programs(2)
+    BT, V, KP = p_ref.shape
+    W = lat_ref.shape[1]
 
     @pl.when(v_blk == 0)
     def _init():
-        c_ref[...] = jnp.full_like(c_ref, BIG)
-        pv_ref[...] = jnp.zeros_like(pv_ref)
-        pj_ref[...] = jnp.zeros_like(pj_ref)
+        c_ref[...] = jnp.full(c_ref.shape, BIG, jnp.float32)
+        pv_ref[...] = jnp.zeros(pv_ref.shape, jnp.int32)
+        pjo_ref[...] = jnp.zeros(pjo_ref.shape, jnp.int32)
 
     lat = lat_ref[...]  # (V, W) — shared across the batch dimension
-    bw = bw_ref[...]  # (V, W)
-    cap = cap_ref[...]  # (V, 1)
-    BT, KT = pre_out_ref.shape
-    KP = prefix_ref.shape[1]
-    V, W = lat.shape
-
-    j_idx = jax.lax.broadcasted_iota(jnp.int32, (KT, KP), 1)
-    k_idx = k_blk * KT + jax.lax.broadcasted_iota(jnp.int32, (KT, KP), 0)
+    bw = bw_ref[...]
     v_iota = jax.lax.broadcasted_iota(jnp.int32, (V, W), 0)
 
-    for bi in range(BT):  # unrolled: shared tiles amortized over b_tile reqs
-        C = c_slab_ref[bi]  # (V, KP)
-        prefix = prefix_ref[bi, :]  # (KP,)
-        pre_out = pre_out_ref[bi, :]  # (KT,) = prefix at this block's k cols
-        breq = breq_ref[bi, :]  # (KT,)
-
-        # -- fused place: P[v, kt] = min_{j<=k, window<=cap} C[v, j]
-        window = pre_out[:, None] - prefix[None, :]  # (KT, KP)
-        feas = (j_idx <= k_idx)[None, :, :] & (
-            window[None, :, :] <= cap[:, 0][:, None, None] + EPS_CAP_F32
-        )  # (V, KT, KP)
-        candp = jnp.where(feas, C[:, None, :], BIG)
-        P = jnp.min(candp, axis=2)  # (V, KT)
-        # tie-break: LARGEST feasible j achieving the min (matches the
-        # descending-j strict-improvement scan of core.leastcost._place_step)
-        pj_place = jnp.max(
-            jnp.where(candp == P[:, :, None], j_idx[None, :, :], -1), axis=2
-        ).astype(jnp.int32)
-
-        # -- fused move, one k column at a time: no (V, W, KT) candidate
-        best_cols, argv_cols, pj_cols = [], [], []
-        for t in range(KT):
-            cand = jnp.where(bw >= breq[t], P[:, t][:, None] + lat, BIG)
+    def one_request(bi, carry):
+        row0 = (b_blk * BT + bi) * KP
+        P = p_ref[bi]  # (V, KP)
+        pj = pj_ref[bi]
+        for k in range(KP):
+            cand = jnp.where(bw >= breq_ref[row0 + k], P[:, k:k + 1] + lat,
+                             BIG)
             cand = jnp.minimum(cand, BIG)  # BIG + lat must stay min-plus BIG
-            best_cols.append(jnp.min(cand, axis=0))  # (W,)
-            arg = jnp.argmin(cand, axis=0).astype(jnp.int32)  # first-v ties
-            argv_cols.append(arg + v_blk * V)
+            best = jnp.min(cand, axis=0, keepdims=True)  # (1, W)
+            # first v achieving the min (jnp.argmin's tie rule)
+            arg = jnp.min(jnp.where(cand == best, v_iota, V), axis=0,
+                          keepdims=True)
             # place-argmin at the winning v, one-hot (no dynamic gather)
-            pj_cols.append(jnp.max(
-                jnp.where(v_iota == arg[None, :], pj_place[:, t][:, None], -1),
-                axis=0,
-            ))
-        best = jnp.stack(best_cols, axis=1)  # (W, KT)
-        argv = jnp.stack(argv_cols, axis=1)
-        pjw = jnp.stack(pj_cols, axis=1)
+            pjw = jnp.max(jnp.where(v_iota == arg, pj[:, k:k + 1], -1),
+                          axis=0, keepdims=True)
+            prev = c_ref[bi, k:k + 1, :]
+            take = best < prev  # strict: earlier v-tile wins ties
+            c_ref[bi, k:k + 1, :] = jnp.where(take, best, prev)
+            pv_ref[bi, k:k + 1, :] = jnp.where(take, arg + v_blk * V,
+                                               pv_ref[bi, k:k + 1, :])
+            pjo_ref[bi, k:k + 1, :] = jnp.where(take, pjw,
+                                                pjo_ref[bi, k:k + 1, :])
+        return carry
 
-        prev = c_ref[bi]
-        take = best < prev  # strict: earlier v-tile wins ties (argmin rule)
-        c_ref[bi] = jnp.where(take, best, prev)
-        pv_ref[bi] = jnp.where(take, argv, pv_ref[bi])
-        pj_ref[bi] = jnp.where(take, pjw, pj_ref[bi])
+    jax.lax.fori_loop(0, BT, one_request, 0)
 
     @pl.when(v_blk == nv - 1)
     def _final():  # monotone EPS_IMPROVE update vs the previous superstep
@@ -155,7 +121,7 @@ def _superstep_kernel(prefix_ref, pre_out_ref, breq_ref, cap_ref, lat_ref,
         upd = cmv < cprev - EPS_IMPROVE
         c_ref[...] = jnp.where(upd, cmv, cprev)
         pv_ref[...] = jnp.where(upd, pv_ref[...], pv_prev_ref[...])
-        pj_ref[...] = jnp.where(upd, pj_ref[...], pj_prev_ref[...])
+        pjo_ref[...] = jnp.where(upd, pjo_ref[...], pj_prev_ref[...])
 
 
 def pad_batched_problem(lat, bw, cap, prefix, breq_k, *, tiles=None):
@@ -166,18 +132,18 @@ def pad_batched_problem(lat, bw, cap, prefix, breq_k, *, tiles=None):
     masked in both the place window and the move).  Returns a dict of padded
     arrays; the padded state must be built by the caller with BIG / -1 fill.
     """
-    b_tile, v_tile, w_tile, k_tile = tiles or DEFAULT_TILES
     B, K = prefix.shape
+    b_tile, v_tile, w_tile = resolve_tiles(tiles, B)
     n = lat.shape[0]
     nt = max(v_tile, w_tile)
     assert nt % v_tile == 0 and nt % w_tile == 0, (v_tile, w_tile)
     Bp = -(-B // b_tile) * b_tile
     n_pad = -(-n // nt) * nt
-    K_pad = -(-K // k_tile) * k_tile
+    K_pad = -(-K // K_ALIGN) * K_ALIGN
     return dict(
         lat=jnp.full((n_pad, n_pad), BIG, jnp.float32).at[:n, :n].set(lat),
         bw=jnp.zeros((n_pad, n_pad), jnp.float32).at[:n, :n].set(bw),
-        cap=jnp.full((n_pad, 1), -1.0, jnp.float32).at[:n, 0].set(cap),
+        cap=jnp.full((n_pad,), -1.0, jnp.float32).at[:n].set(cap),
         prefix=jnp.full((Bp, K_pad), BIG, jnp.float32).at[:B, :K].set(prefix),
         breq_k=jnp.full((Bp, K_pad), BIG, jnp.float32).at[:B, :K].set(breq_k),
     )
@@ -186,46 +152,45 @@ def pad_batched_problem(lat, bw, cap, prefix, breq_k, *, tiles=None):
 @functools.partial(jax.jit, static_argnames=("tiles", "interpret"))
 def batched_superstep_pallas(C, par_v, par_j, lat, bw, cap, prefix, breq_k, *,
                              tiles=None, interpret: bool = False):
-    """One fused superstep on PRE-PADDED operands (see pad_batched_problem).
+    """One superstep on PRE-PADDED operands (see pad_batched_problem).
 
     Shapes: C/par_v/par_j (Bp, n_pad, K_pad); lat/bw (n_pad, n_pad);
-    cap (n_pad, 1); prefix/breq_k (Bp, K_pad).  Returns (Cn, par_vn, par_jn).
+    cap (n_pad,); prefix/breq_k (Bp, K_pad).  Returns (Cn, par_vn, par_jn).
     """
-    b_tile, v_tile, w_tile, k_tile = tiles or DEFAULT_TILES
     Bp, n_pad, K_pad = C.shape
+    b_tile, v_tile, w_tile = resolve_tiles(tiles, Bp)
     assert Bp % b_tile == 0 and n_pad % v_tile == 0, (C.shape, tiles)
-    assert n_pad % w_tile == 0 and K_pad % k_tile == 0, (C.shape, tiles)
+    assert n_pad % w_tile == 0 and K_pad % K_ALIGN == 0, (C.shape, tiles)
 
-    grid = (Bp // b_tile, n_pad // w_tile, K_pad // k_tile, n_pad // v_tile)
+    P, pj = _place_batched_ref(C, cap, prefix)
+    kmajor = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    vmajor_spec = pl.BlockSpec((b_tile, v_tile, K_pad),
+                               lambda b, w, v: (b, v, 0))
+    net_spec = pl.BlockSpec((v_tile, w_tile), lambda b, w, v: (v, w))
+    state_spec = pl.BlockSpec((b_tile, K_pad, w_tile),
+                              lambda b, w, v: (b, 0, w))
+    grid = (Bp // b_tile, n_pad // w_tile, n_pad // v_tile)
     out = pl.pallas_call(
-        _superstep_kernel,
+        _move_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((b_tile, K_pad), lambda b, w, k, v: (b, 0)),  # prefix
-            pl.BlockSpec((b_tile, k_tile), lambda b, w, k, v: (b, k)),  # pre_out
-            pl.BlockSpec((b_tile, k_tile), lambda b, w, k, v: (b, k)),  # breq_k
-            pl.BlockSpec((v_tile, 1), lambda b, w, k, v: (v, 0)),  # cap (shared)
-            pl.BlockSpec((v_tile, w_tile), lambda b, w, k, v: (v, w)),  # lat
-            pl.BlockSpec((v_tile, w_tile), lambda b, w, k, v: (v, w)),  # bw
-            pl.BlockSpec((b_tile, v_tile, K_pad), lambda b, w, k, v: (b, v, 0)),
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # breq_k, flat
+            vmajor_spec, vmajor_spec,  # P, pj
+            net_spec, net_spec,  # lat, bw (shared across the batch)
+            state_spec, state_spec, state_spec,  # previous C / par_v / par_j
         ],
-        out_specs=[
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
-            pl.BlockSpec((b_tile, w_tile, k_tile), lambda b, w, k, v: (b, w, k)),
-        ],
+        out_specs=[state_spec, state_spec, state_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, n_pad, K_pad), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, n_pad, K_pad), jnp.int32),
-            jax.ShapeDtypeStruct((Bp, n_pad, K_pad), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, K_pad, n_pad), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, K_pad, n_pad), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, K_pad, n_pad), jnp.int32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(prefix, prefix, breq_k, cap, lat, bw, C, C, par_v, par_j)
-    return tuple(out)
+        name="minplus_move_batched",
+    )(breq_k.reshape(-1), P, pj, lat, bw, kmajor(C), kmajor(par_v),
+      kmajor(par_j))
+    return tuple(kmajor(x) for x in out)
 
 
 # ---------------------------------------------------------------------------

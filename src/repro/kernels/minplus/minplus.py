@@ -27,14 +27,11 @@ from jax.experimental import pallas as pl
 
 from repro.core.problem import BIG
 
-try:  # TPU compiler params (ignored in interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")
-    )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 # Default tile sizes (hillclimbed in EXPERIMENTS.md §Perf; see ops.py).
 V_TILE = 128  # reduction tile (v)

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.device import on_tpu
+
 from . import minplus as _kernel
 from . import ref as _ref
 
@@ -26,13 +28,6 @@ def _breq_k(breq, K):
     )
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def masked_minplus(P, lat, bw, breq, *, tiles: tuple[int, int, int] | None = None):
     """Move step: returns (C' (n,K) float32, pv (n,K) int32)."""
     K = P.shape[1]
@@ -41,7 +36,7 @@ def masked_minplus(P, lat, bw, breq, *, tiles: tuple[int, int, int] | None = Non
     if tiles is not None:
         kw = dict(v_tile=tiles[0], w_tile=tiles[1], k_tile=tiles[2])
     return _kernel.masked_minplus_pallas(
-        P, lat, bw, bq, interpret=not _on_tpu(), **kw
+        P, lat, bw, bq, interpret=not on_tpu(), **kw
     )
 
 

@@ -16,20 +16,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.problem import BIG, EPS_CAP_F32
 
 V_TILE = 128
 K_OUT_TILE = 8
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel")
-    )
-except Exception:  # pragma: no cover
-    _COMPILER_PARAMS = None
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel")
+)
 
 
 def _kernel(prefix_ref, prefix_out_ref, c_ref, cap_ref, p_ref, pj_ref):

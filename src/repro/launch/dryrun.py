@@ -129,8 +129,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # jax<=0.4.x: one dict per program
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
 
     prof = collective_profile(hlo)

@@ -6,11 +6,9 @@ import jax
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6 explicit-sharding API
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

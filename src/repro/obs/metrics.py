@@ -205,7 +205,7 @@ class MetricsRegistry:
 # engine.Stats fields that sum across solves/regions
 _ENGINE_ADDITIVE = (
     "rounds", "messages_sent", "messages_dropped", "maps_generated",
-    "fallback_used", "stale_batches", "preemptions", "defrag_rounds",
+    "fallbacks", "stale_batches", "preemptions", "defrag_rounds",
     "gossip_messages", "twopc_messages",
 )
 

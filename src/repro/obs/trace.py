@@ -150,12 +150,9 @@ class Tracer:
 
     def annotate(self, name: str):
         """``jax.profiler.TraceAnnotation`` around device dispatch so the
-        span shows up in XLA/Perfetto profiles too.  Imported lazily;
-        falls back to a null context when jax is unavailable."""
-        try:
-            from jax.profiler import TraceAnnotation
-        except Exception:  # pragma: no cover - jax always present in CI
-            return _NULL_CTX
+        span shows up in XLA/Perfetto profiles too."""
+        from jax.profiler import TraceAnnotation
+
         return TraceAnnotation(self._prefix + name)
 
     # -- access ---------------------------------------------------------------

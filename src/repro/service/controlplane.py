@@ -639,6 +639,7 @@ class ControlPlane:
         s.overhead_ms = st.overhead_ms
         s.conflict_resolve_ms = st.conflict_resolve_ms
         s.stale_batches = st.stale_batches
+        s.fallbacks = st.fallbacks
         s.batch_size = self.micro_batch
         # non-additive fields, carried through the labeled counters
         # instead of being dropped (or last-writer-won) on the fold

@@ -955,6 +955,7 @@ class HierarchicalControlPlane(ChainBroker):
             s.overhead_ms += cs.overhead_ms
             s.conflict_resolve_ms += cs.conflict_resolve_ms
             s.stale_batches += cs.stale_batches
+            s.fallbacks += cs.fallbacks
             s.gossip_messages += cs.gossip_messages
             s.twopc_messages += cs.twopc_messages
         s.batch_size = self.micro_batch

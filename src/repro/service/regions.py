@@ -1670,6 +1670,7 @@ class RegionalControlPlane(ChainBroker):
             cp.placer.stats.conflict_resolve_ms for cp in self.regions)
         s.stale_batches = sum(
             cp.placer.stats.stale_batches for cp in self.regions)
+        s.fallbacks = sum(cp.placer.stats.fallbacks for cp in self.regions)
         s.batch_size = self.micro_batch
         s.rounds = self.bus.rounds
         s.gossip_messages = self.bus.messages_sent

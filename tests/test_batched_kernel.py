@@ -63,12 +63,17 @@ def test_fused_ref_matches_vmapped_bitforbit(n, ps, seed):
     _assert_dp_equal(out_v, out_b)
 
 
-@pytest.mark.parametrize("tiles", [(1, 8, 8, 8), (2, 8, 8, 8), (4, 16, 16, 8),
-                                   (2, 8, 16, 4)])
-def test_pallas_interpret_matches_ref_bitforbit(tiles):
+@pytest.mark.parametrize("tiles,ps", [
+    ((1, 128, 128), [4, 6, 3]),
+    ((2, 128, 128), [4, 6, 3]),
+    ((4, 128, 256), [4, 6, 3]),
+    ((2, 256, 128), [12, 5, 9]),
+])
+def test_pallas_interpret_matches_ref_bitforbit(tiles, ps):
     """Interpret-mode Pallas kernel vs the fused jnp mirror, including
-    b_tile > 1 (padded batch rows) and k_tile < K (multiple k blocks)."""
-    n, ps = 13, [4, 6, 3]
+    b_tile > 1 (padded batch rows), several v or w blocks, and K_pad = 16
+    (two sublane rows of k columns)."""
+    n = 13
     rg = waxman(n, seed=5)
     dfs = _stream(rg, ps, seed0=40)
     tensors, p_max = stack_requests(rg, dfs)
@@ -142,10 +147,11 @@ def _random_state(B, n, K, seed, big_frac=0.4):
     return (j(C), j(pv), j(pj), j(lat), j(bw), j(cap), j(prefix), j(breq_k))
 
 
-@pytest.mark.parametrize("seed,tiles", [(0, (1, 8, 8, 8)), (1, (2, 8, 8, 4)),
-                                        (2, (4, 16, 8, 8))])
-def test_superstep_random_states(seed, tiles):
-    args = _random_state(B=3, n=12, K=6, seed=seed)
+@pytest.mark.parametrize("seed,tiles,K", [(0, (1, 128, 128), 6),
+                                          (1, (2, 128, 256), 6),
+                                          (2, (4, 256, 128), 11)])
+def test_superstep_random_states(seed, tiles, K):
+    args = _random_state(B=3, n=12, K=K, seed=seed)
     ref, pal = _superstep_pair(*args, tiles=tiles)
     for r, p, name in zip(ref, pal, ("C", "par_v", "par_j")):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(p),
@@ -173,7 +179,7 @@ def test_superstep_ties_break_like_jnp_path():
         [jnp.full((B, 1), BIG), jnp.full((B, K - 2), 1.0),
          jnp.full((B, 1), BIG)], axis=1)
     ref, pal = _superstep_pair(C, pv, pj, lat, bw, cap, prefix, breq_k,
-                               tiles=(1, 8, 8, 8))
+                               tiles=(1, 128, 128))
     for r, p in zip(ref, pal):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
     Cn, pvn, pjn = (np.asarray(x) for x in pal)
@@ -189,7 +195,7 @@ def test_superstep_big_overflow_clamped():
     through the monotone state update."""
     args = list(_random_state(B=2, n=10, K=5, seed=9, big_frac=1.0))
     # C all BIG -> every move candidate is BIG + lat (incl. lat = BIG rows)
-    ref, pal = _superstep_pair(*args, tiles=(1, 8, 8, 8))
+    ref, pal = _superstep_pair(*args, tiles=(1, 128, 128))
     for r, p in zip(ref, pal):
         np.testing.assert_array_equal(np.asarray(r), np.asarray(p))
     # state must be unchanged: nothing can improve on BIG
@@ -205,7 +211,7 @@ def test_padded_columns_stay_masked():
     tensors, p_max = stack_requests(rg, dfs)
     C, *_ = _leastcost_dp_batched(tensors, B=2, n=12, p=p_max,
                                   max_rounds=11, impl="interpret",
-                                  tiles=(2, 8, 8, 8))
+                                  tiles=(2, 128, 128))
     C = np.asarray(C)
     # request 0 has p_eff=3: state columns beyond its true sink (k > 3) are
     # unreachable -> must still hold BIG

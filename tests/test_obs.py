@@ -471,6 +471,38 @@ def test_engine_stats_carries_kernel_impl_across_regions():
     assert cp.engine_stats().kernel_impl == "ref"
 
 
+@pytest.mark.parametrize("plane", ["centralized", "regional", "hierarchical"])
+def test_reconstruction_fallbacks_counted_at_every_plane_level(
+        plane, monkeypatch):
+    """A device answer whose parent pointers do not backtrack is re-solved
+    by the host solver; every such rescue reaches ``OnlineStats``,
+    ``engine_stats()`` and the metrics registry at each plane level."""
+    import repro.core.reconstruct as reconstruct
+
+    monkeypatch.setattr(reconstruct, "backtrack",
+                        lambda *a, **kw: (None, [], False))
+    jm = dict(method="leastcost_jax", micro_batch=4)
+    if plane == "centralized":
+        rg = waxman(10, seed=3)
+        cp = ControlPlane(rg, **jm)
+    elif plane == "regional":
+        rg, assign = region_line(2, 4, seed=2)
+        cp = ControlPlane(rg, region_of=assign, seed=2, **jm)
+    else:
+        rg, assign = region_tree(2, 2, 3, seed=1)
+        cp = ControlPlane(rg, region_of=assign, levels=2, branching=2,
+                          seed=1, **jm)
+    cp.register_tenant("a", weight=1.0)
+    for i in range(4):
+        cp.submit("a", random_dataflow(rg, 3, seed=60 + i,
+                                       creq_range=(0.05, 0.2),
+                                       breq_range=(0.5, 2.0)))
+    cp.pump(rounds=3)
+    s = cp.engine_stats()
+    assert s.fallbacks > 0
+    assert cp.metrics_registry().total("placer.fallbacks") == s.fallbacks
+
+
 def test_consensus_impl_labels_mixed_backends():
     assert ControlPlane._consensus_impl({"ref": 3}) == "ref"
     mixed = ControlPlane._consensus_impl({"ref": 2, "pallas": 5})
