@@ -34,6 +34,7 @@ import time
 from typing import Callable, Optional
 
 from .graph import DataflowPath, Mapping, ResourceGraph
+from ..obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -59,6 +60,10 @@ class Stats:
     kernel_impl: str = ""  # use_kernel paths: "pallas" | "interpret" | "ref"
     virtual_time: float = 0.0  # simulator virtual completion time
     solve_ms: float = 0.0  # wall clock inside the backend (device + reconstruct)
+    # inside solve_ms, batched DP only: host blocked on the device's answer,
+    # and the parent-pointer backtrack (+ un-compaction) after it
+    dp_wait_ms: float = 0.0
+    reconstruct_ms: float = 0.0
     # host-side admission overhead: validation / reserve / commit loops
     # around the solves — the half of admit latency the pipelined path
     # overlaps with the next batch's device work (service-layer counter,
@@ -267,24 +272,31 @@ class PendingBatchSolve:
         self._dispatch_ms = dispatch_ms
         self._solve_n = pending.rg.n if pending is not None else None
 
-    def finalize(self) -> tuple[list[Optional[Mapping]], Stats]:
+    def finalize(self, tracer=obs_trace.NULL
+                 ) -> tuple[list[Optional[Mapping]], Stats]:
         """Block until the solve completes; return ``(mappings, stats)``.
 
         ``stats.solve_ms`` covers dispatch plus the blocking wait and
         reconstruction — the same wall clock :func:`solve_batch` reports,
-        minus whatever the caller overlapped between the two halves."""
+        minus whatever the caller overlapped between the two halves.
+        ``tracer`` takes the ``placer.dp_wait`` / ``placer.reconstruct``
+        spans."""
         if self._ready is not None:
             return self._ready
         from .leastcost import leastcost_jax_batched_finalize
 
         t0 = time.perf_counter()
         stats = Stats(method=self.method)
-        mappings = leastcost_jax_batched_finalize(self._pending, stats=stats)
+        mappings = leastcost_jax_batched_finalize(self._pending, stats=stats,
+                                                  tracer=tracer)
         if self.view is not None and not self.view.is_identity:
-            mappings = [
-                self.view.uncompact_mapping(m) if m is not None else None
-                for m in mappings
-            ]
+            t1 = time.perf_counter()
+            with tracer.span("reconstruct", track="placer"):
+                mappings = [
+                    self.view.uncompact_mapping(m) if m is not None else None
+                    for m in mappings
+                ]
+            stats.reconstruct_ms += 1e3 * (time.perf_counter() - t1)
         stats.solve_n = self._solve_n
         stats.batch_size = len(self.dfs)
         stats.solve_ms = self._dispatch_ms + 1e3 * (time.perf_counter() - t0)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Optional
 
 import jax
@@ -59,6 +60,7 @@ from .problem import (
 )
 from .device import on_tpu
 from .reconstruct import reconstruct_mapping
+from ..obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -376,12 +378,12 @@ def _vmapped_dp(n: int, p: int, max_rounds: int, warm: bool = False):
     per-batch overhead on the online placer's hot path.  ``warm=True``
     expects the ``_WARM_KEYS`` frontier tensors batched along axis 0."""
     axes = dict(BATCH_IN_AXES, **_WARM_IN_AXES) if warm else BATCH_IN_AXES
-    return jax.jit(
-        jax.vmap(
-            lambda t: _leastcost_dp(t, n=n, p=p, max_rounds=max_rounds),
-            in_axes=(axes,),
-        )
-    )
+
+    # named, so the device module reads jit_vmapped_leastcost_dp in traces
+    def vmapped_leastcost_dp(t):
+        return _leastcost_dp(t, n=n, p=p, max_rounds=max_rounds)
+
+    return jax.jit(jax.vmap(vmapped_leastcost_dp, in_axes=(axes,)))
 
 
 @functools.partial(
@@ -448,8 +450,9 @@ def _leastcost_dp_batched(tensors, B: int, n: int, p: int, max_rounds: int,
         return t + 1, Cn, pvn, pjn, jnp.any(Cn < C)
 
     # named scope = free trace-time metadata: the relaxation loop shows up
-    # as one labeled block in XLA/Perfetto profiles (repro.obs annotate()
-    # wraps the dispatch side; this labels the compiled computation itself)
+    # as one labeled block in XLA/Perfetto profiles (the placer's
+    # placer.dispatch span marks the host side; this labels the compiled
+    # computation itself)
     with jax.named_scope(f"minplus_dp_batched[{impl}]"):
         t, Cp, pvp, pjp, _ = jax.lax.while_loop(
             cond, body, (0, *state0, jnp.array(True))
@@ -559,33 +562,47 @@ def leastcost_jax_batched_dispatch(
                      rounds, kernel_impl=impl, validate=validate, warm=warm)
 
 
-def leastcost_jax_batched_finalize(pending: PendingDP, stats=None) -> list:
+def leastcost_jax_batched_finalize(pending: PendingDP, stats=None,
+                                   tracer=obs_trace.NULL) -> list:
     """Block on an in-flight batched DP and reconstruct its mappings.
 
     This is the only host synchronization point of the batched path: the
     ``np.asarray`` pulls force completion of the dispatched computation
-    (the pipelined placer's commit-time ``block_until_ready``)."""
-    par_v, par_j = np.asarray(pending.par_v), np.asarray(pending.par_j)
-    best_cost, best_j = np.asarray(pending.best_cost), np.asarray(pending.best_j)
-    if stats is not None and pending.rounds is not None:
+    (the pipelined placer's commit-time ``block_until_ready``).  The wait
+    and the reconstruction are timed into ``stats.dp_wait_ms`` /
+    ``stats.reconstruct_ms`` and traced as ``placer.dp_wait`` /
+    ``placer.reconstruct``."""
+    t0 = time.perf_counter()
+    with tracer.span("dp_wait", track="placer", cat="solve"):
+        par_v, par_j = np.asarray(pending.par_v), np.asarray(pending.par_j)
+        best_cost = np.asarray(pending.best_cost)
+        best_j = np.asarray(pending.best_j)
+        rounds = (None if pending.rounds is None
+                  else np.asarray(pending.rounds))
+    t1 = time.perf_counter()
+    if stats is not None and rounds is not None:
         if pending.kernel_impl:
             stats.kernel_impl = pending.kernel_impl
         # kernel path: one shared device scalar; vmapped path: (B,) per-
         # request superstep counts — report the batch's slowest request
-        stats.rounds = int(np.max(np.asarray(pending.rounds)))
+        stats.rounds = int(np.max(rounds))
     out = []
-    for i, df in enumerate(pending.dfs):
-        per = HeuristicStats()
-        out.append(
-            reconstruct_mapping(
-                pending.rg, df, par_v[i], par_j[i],
-                float(best_cost[i]), int(best_j[i]),
-                validate=pending.validate, stats=per,
+    with tracer.span("reconstruct", track="placer", cat="solve"):
+        for i, df in enumerate(pending.dfs):
+            per = HeuristicStats()
+            out.append(
+                reconstruct_mapping(
+                    pending.rg, df, par_v[i], par_j[i],
+                    float(best_cost[i]), int(best_j[i]),
+                    validate=pending.validate, stats=per,
+                )
             )
-        )
-        if stats is not None:
-            stats.fallbacks += int(per.fallback_used)
-            stats.validated &= per.validated
+            if stats is not None:
+                stats.fallbacks += int(per.fallback_used)
+                stats.validated &= per.validated
+    if stats is not None:
+        stats.dp_wait_ms = 1e3 * (t1 - t0)
+        stats.reconstruct_ms = 1e3 * (time.perf_counter() - t1)
     return out
 
 

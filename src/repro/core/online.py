@@ -93,6 +93,10 @@ class OnlineStats:
     defrag_rounds: int = 0  # global re-optimization passes attempted
     defrag_commits: int = 0  # ... that improved the objective and committed
     solve_ms: float = 0.0  # device solve + reconstruction wall clock
+    # inside solve_ms, batched solves only: host blocked on the DP's answer,
+    # and backtracking its parent pointers into mappings
+    dp_wait_ms: float = 0.0
+    reconstruct_ms: float = 0.0
     overhead_ms: float = 0.0  # host validation/commit loops around the solves
     conflict_resolve_ms: float = 0.0  # individual conflict re-solves, end to end
     solves: int = 0  # DP solves issued (a micro-batch counts once)
@@ -110,6 +114,9 @@ class OnlineStats:
     # device answers that failed to backtrack or validate and were re-solved
     # on the host (core.reconstruct): nonzero means the DP answered wrong
     fallbacks: int = 0
+    # fail_node/fail_link re-admission of displaced tickets, DP included
+    # (counted with remapped/dropped, so it rolls back with them)
+    remap_ms: float = 0.0
     # solves per kernel backend ("pallas" / "ref" / native impl name):
     # non-additive engine.Stats fields (kernel_impl) carried as labeled
     # counts instead of last-writer-wins when stats fold across regions
@@ -123,9 +130,10 @@ class OnlineStats:
     # probes, defrag): wall clock was really spent and cache traffic really
     # happened even when the state change is rolled back
     _SOLVE_CARRY = (
-        "solve_ms", "overhead_ms", "conflict_resolve_ms", "solves",
-        "solve_n_sum", "cache_hits", "cache_misses", "cache_stale",
-        "cache_neg_hits", "warm_solves", "warm_fallbacks", "fallbacks",
+        "solve_ms", "dp_wait_ms", "reconstruct_ms", "overhead_ms",
+        "conflict_resolve_ms", "solves", "solve_n_sum", "cache_hits",
+        "cache_misses", "cache_stale", "cache_neg_hits", "warm_solves",
+        "warm_fallbacks", "fallbacks",
     )
 
     @property
@@ -286,7 +294,7 @@ class OnlinePlacer:
         if use_kernel:
             solve_cfg = dict(solve_cfg, use_kernel=True)
         self.solve_cfg = solve_cfg
-        self.res = ResidualState(rg)
+        self.res = ResidualState(rg, tracer=self.tracer)
         self.tickets: dict[int, Ticket] = {}
         self.stats = OnlineStats()
         self._tid = itertools.count()
@@ -352,10 +360,11 @@ class OnlinePlacer:
             e += self.view.version
         return e
 
-    def residual_graph(self) -> ResourceGraph:
+    def residual_graph(self, site: str = "other") -> ResourceGraph:
         """The network the next solve sees: committed capacity subtracted,
-        failed nodes/links removed (cap 0 / bw 0 / lat INF)."""
-        return self.res.residual_graph()
+        failed nodes/links removed (cap 0 / bw 0 / lat INF).  ``site``
+        labels the rebuild in ``ResidualState.rebuilds``."""
+        return self.res.residual_graph(site)
 
     def utilization(self) -> dict:
         base_cap = float(np.sum(self.base.cap))
@@ -446,6 +455,8 @@ class OnlinePlacer:
         non-additive ``kernel_impl`` as a labeled count and the superstep
         count as a per-mode histogram bucket."""
         self.stats.solve_ms += st.solve_ms
+        self.stats.dp_wait_ms += st.dp_wait_ms
+        self.stats.reconstruct_ms += st.reconstruct_ms
         self.stats.solves += 1
         self.stats.solve_n_sum += st.solve_n
         self.stats.fallbacks += st.fallbacks
@@ -469,6 +480,11 @@ class OnlinePlacer:
         current residual commits with zero DP work (and is deliberately
         NOT counted as a solve).  Anything else falls through to the full
         solve, exactly the pre-cache path."""
+        return self._admit(df, tenant, klass, "other")
+
+    def _admit(self, df: DataflowPath, tenant: str, klass: int,
+               site: str) -> Optional[Ticket]:
+        """:meth:`admit`, its residual rebuilds counted under ``site``."""
         if not (self.node_up[df.src] and self.node_up[df.dst]):
             self.stats.rejected += 1
             return None
@@ -483,14 +499,14 @@ class OnlinePlacer:
                 return None
             entry = cache.get(sig)
             if entry is not None:
-                if self._admissible(df, entry, self.residual_graph()):
+                if self._admissible(df, entry, self.residual_graph(site)):
                     self.stats.cache_hits += 1
                     self.stats.admitted += 1
                     return self._commit(df, entry, tenant=tenant, klass=klass)
                 self.stats.cache_stale += 1
             else:
                 self.stats.cache_misses += 1
-        rg = self.residual_graph()
+        rg = self.residual_graph(site)
         with self.tracer.span("solve", track="placer", cat="solve"):
             mapping, st = engine.solve(rg, df, method=self.method,
                                        **self.solve_cfg)
@@ -602,10 +618,9 @@ class OnlinePlacer:
                 cfg["max_rounds"] = max_rounds
             graph_tensors = self.res.device_tensors()
         with self.tracer.span("dispatch", track="placer", cat="solve",
-                              batch=len(dfs)), \
-                self.tracer.annotate("minplus.dispatch"):
+                              batch=len(dfs)):
             return engine.solve_batch_dispatch(
-                self.residual_graph(), list(dfs), method=self.method,
+                self.residual_graph("dispatch"), list(dfs), method=self.method,
                 graph_tensors=graph_tensors, **cfg,
             )
 
@@ -638,7 +653,7 @@ class OnlinePlacer:
             return PendingAdmission(dfs, list(metas), handle, self.epoch,
                                     tag=tag)
         t0 = time.perf_counter()
-        rg = self.residual_graph()
+        rg = self.residual_graph("classify")
         stamp = self._stamp()
         warm_ok = (self.method in engine.BATCHED_METHODS
                    and self.max_correction_supersteps > 0)
@@ -716,12 +731,12 @@ class OnlinePlacer:
             self.stats.stale_batches += 1
             with self.tracer.span("solve.resolve_stale", track="placer",
                                   cat="solve", batch=len(dfs)):
-                mappings, st = self._dispatch_solve(dfs).finalize()
+                mappings, st = self._dispatch_solve(dfs).finalize(self.tracer)
             self._note_solve(st)
         elif plan is None:
             with self.tracer.span("solve.wait", track="placer", cat="solve",
                                   batch=len(dfs)):
-                mappings, st = pending.handle.finalize()
+                mappings, st = pending.handle.finalize(self.tracer)
             self._note_solve(st)
         else:
             # merge the classified subsets back into request order; only
@@ -734,14 +749,14 @@ class OnlinePlacer:
             if pending.handle is not None:
                 with self.tracer.span("solve.wait", track="placer",
                                       cat="solve", batch=len(pending.cold_idx)):
-                    cold_maps, st = pending.handle.finalize()
+                    cold_maps, st = pending.handle.finalize(self.tracer)
                 self._note_solve(st)
                 for i, m in zip(pending.cold_idx, cold_maps):
                     mappings[i] = m
             if pending.warm_handle is not None:
                 with self.tracer.span("solve.warm_wait", track="placer",
                                       cat="solve", batch=len(pending.warm_idx)):
-                    warm_maps, wst = pending.warm_handle.finalize()
+                    warm_maps, wst = pending.warm_handle.finalize(self.tracer)
                 self._note_solve(wst, mode="warm")
                 for i, m in zip(pending.warm_idx, warm_maps):
                     mappings[i] = m
@@ -752,7 +767,7 @@ class OnlinePlacer:
         conflict_ms = 0.0
         out: list[Optional[Ticket]] = []
         with span:
-            current = self.residual_graph()
+            current = self.residual_graph("commit")
             for idx, (df, m, (tenant, klass)) in enumerate(
                     zip(dfs, mappings, metas)):
                 kind = plan[idx][0] if plan is not None else "cold"
@@ -766,7 +781,7 @@ class OnlinePlacer:
                         self.stats.cache_hits += 1
                     self.stats.admitted += 1
                     out.append(self._commit(df, m, tenant=tenant, klass=klass))
-                    current = self.residual_graph()
+                    current = self.residual_graph("commit")
                 elif m is not None:
                     # stale snapshot (a commit since dispatch took the
                     # capacity) — optimistic-concurrency retry, individually.
@@ -779,11 +794,11 @@ class OnlinePlacer:
                     t0 = time.perf_counter()
                     with self.tracer.span("conflict.resolve", track="placer",
                                           cat="admit"):
-                        t = self.admit(df, tenant=tenant, klass=klass)
+                        t = self._admit(df, tenant, klass, "conflict")
                     conflict_ms += 1e3 * (time.perf_counter() - t0)
                     out.append(t)
                     if t is not None:
-                        current = self.residual_graph()
+                        current = self.residual_graph("conflict")
                 elif kind == "warm":
                     # the bounded correction pass placed nothing — the fuse:
                     # fall back to a full cold re-solve so admission quality
@@ -792,11 +807,11 @@ class OnlinePlacer:
                     t0 = time.perf_counter()
                     with self.tracer.span("warm.fallback", track="placer",
                                           cat="admit"):
-                        t = self.admit(df, tenant=tenant, klass=klass)
+                        t = self._admit(df, tenant, klass, "conflict")
                     conflict_ms += 1e3 * (time.perf_counter() - t0)
                     out.append(t)
                     if t is not None:
-                        current = self.residual_graph()
+                        current = self.residual_graph("conflict")
                 else:
                     self.stats.rejected += 1
                     if (cache is not None and kind == "cold"
@@ -890,21 +905,27 @@ class OnlinePlacer:
         dropped entries carry their ``df``/``tenant``/``klass`` so the caller
         can re-queue or escalate them.
         """
+        if not displaced:
+            return [], []
+        t0 = time.perf_counter()
         displaced = sorted(displaced, key=lambda t: (-t.klass, t.tid))
-        for t in displaced:
-            self.release(t, reason=None)
         remapped, dropped = [], []
-        tickets = self.admit_many(
-            [t.df for t in displaced],
-            metas=[(t.tenant, t.klass) for t in displaced],
-        )
-        for t, nt in zip(displaced, tickets):
-            if nt is None:
-                dropped.append(t)
-                self.stats.dropped += 1
-            else:
-                remapped.append(self.rekey(nt, t.tid))
-                self.stats.remapped += 1
+        with self.tracer.span("remap", track="placer", cat="churn",
+                              displaced=len(displaced)):
+            for t in displaced:
+                self.release(t, reason=None)
+            tickets = self.admit_many(
+                [t.df for t in displaced],
+                metas=[(t.tenant, t.klass) for t in displaced],
+            )
+            for t, nt in zip(displaced, tickets):
+                if nt is None:
+                    dropped.append(t)
+                    self.stats.dropped += 1
+                else:
+                    remapped.append(self.rekey(nt, t.tid))
+                    self.stats.remapped += 1
+        self.stats.remap_ms += 1e3 * (time.perf_counter() - t0)
         return remapped, dropped
 
     def fail_node(self, v: int) -> tuple[list[Ticket], list[Ticket]]:

@@ -34,11 +34,13 @@ rewrite ``lat`` semantics, not just magnitudes).
 from __future__ import annotations
 
 import itertools
+import time
 
 import numpy as np
 
 from .graph import INF, ResourceGraph
 from .problem import finite_lat
+from ..obs import trace as obs_trace
 
 
 def _pow2_pad(arr: np.ndarray) -> np.ndarray:
@@ -59,8 +61,9 @@ class ResidualState:
     """Residual capacity/bandwidth of one resource network: float64 host
     truth + lazily synchronized float32 device tensors + staleness fences."""
 
-    def __init__(self, base: ResourceGraph):
+    def __init__(self, base: ResourceGraph, tracer=None):
         self.base = base
+        self.tracer = tracer if tracer is not None else obs_trace.NULL
         n = base.n
         self.cap = base.cap.astype(np.float64).copy()
         self.bw = base.bw.astype(np.float64).copy()
@@ -72,21 +75,31 @@ class ResidualState:
         self._node_delta: dict[int, float] = {}  # node -> pending cap delta
         self._edge_delta: dict[tuple, float] = {}  # (u,v) -> pending bw delta
         # telemetry (repro.obs registry reads these): how often the device
-        # mirror paid a full O(n^2) upload vs an O(delta) scatter-add
+        # mirror paid a full O(n^2) upload vs an O(delta) scatter-add, and
+        # the wall clock of every sync
         self.sync_stats = {"full_uploads": 0, "delta_syncs": 0,
-                           "invalidations": 0}
+                           "invalidations": 0, "sync_ms": 0.0}
+        # call site -> [rebuilds, ms] of residual_graph(), three dense
+        # float32 n x n arrays each
+        self.rebuilds: dict[str, list] = {}
 
     # -- host truth ---------------------------------------------------------
 
-    def residual_graph(self) -> ResourceGraph:
+    def residual_graph(self, site: str = "other") -> ResourceGraph:
         """The network the next solve sees: committed capacity subtracted,
-        failed nodes/links removed (cap 0 / bw 0 / lat INF)."""
-        up2 = self.node_up[:, None] & self.node_up[None, :]
-        alive = self.link_up & up2
-        cap = np.where(self.node_up, self.cap, 0.0).astype(np.float32)
-        bw = np.where(alive, self.bw, 0.0).astype(np.float32)
-        lat = np.where(alive, self.base.lat, INF).astype(np.float32)
-        np.fill_diagonal(lat, 0.0)
+        failed nodes/links removed (cap 0 / bw 0 / lat INF).  ``site``
+        names the caller in the rebuild counters."""
+        t0 = time.perf_counter()
+        with self.tracer.span("rebuild", track="residual", site=site):
+            up2 = self.node_up[:, None] & self.node_up[None, :]
+            alive = self.link_up & up2
+            cap = np.where(self.node_up, self.cap, 0.0).astype(np.float32)
+            bw = np.where(alive, self.bw, 0.0).astype(np.float32)
+            lat = np.where(alive, self.base.lat, INF).astype(np.float32)
+            np.fill_diagonal(lat, 0.0)
+        c = self.rebuilds.setdefault(site, [0, 0.0])
+        c[0] += 1
+        c[1] += 1e3 * (time.perf_counter() - t0)
         return ResourceGraph(cap, bw, lat)
 
     def apply_load(self, node_load: dict, edge_load: dict, sign: float) -> None:
@@ -173,10 +186,17 @@ class ResidualState:
         Full upload when the cache was dropped (construction, liveness
         change, restore); otherwise one scatter-add per tensor over the
         pending commit/release deltas."""
+        t0 = time.perf_counter()
+        with self.tracer.span("sync", track="residual"):
+            dev = self._sync()
+        self.sync_stats["sync_ms"] += 1e3 * (time.perf_counter() - t0)
+        return dev
+
+    def _sync(self) -> dict:
         import jax.numpy as jnp  # deferred: numpy-only backends never touch jax
 
         if self._dev is None:
-            rg = self.residual_graph()
+            rg = self.residual_graph("upload")
             self._dev = dict(
                 cap=jnp.asarray(rg.cap),
                 bw=jnp.asarray(rg.bw),

@@ -6,7 +6,7 @@ registry merge semantics, and the disabled-mode guarantees.
 """
 from .metrics import (
     Histogram, MetricsRegistry, absorb_engine_stats, absorb_gossip_stats,
-    absorb_online_stats, absorb_span_stats, absorb_timing,
+    absorb_online_stats, absorb_residual_stats, absorb_span_stats,
 )
 from .trace import NULL, NullTracer, Tracer
 from .export import (
@@ -23,8 +23,8 @@ __all__ = [
     "absorb_engine_stats",
     "absorb_gossip_stats",
     "absorb_online_stats",
+    "absorb_residual_stats",
     "absorb_span_stats",
-    "absorb_timing",
     "reconstruct_request",
     "text_timeline",
     "to_chrome_trace",

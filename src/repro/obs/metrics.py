@@ -1,13 +1,14 @@
 """Labeled metrics registry unifying the scattered stats surfaces.
 
-``engine.Stats``, ``OnlineStats``, the broker's ``span_stats``, the
-``GossipBus`` counters, and the solve/overhead/conflict timing split
-all become *views over one registry*: each plane exposes
-``metrics_registry()`` which absorbs its own surfaces into counters /
-gauges / histograms keyed by ``(name, labels)``, and parent planes
-**merge** their children's registries under a composed ``plane`` label
-(``"g0/r1"``) — mirroring the gossip aggregation structure, so a
-snapshot only ever contains what that plane can legitimately see.
+``engine.Stats``, ``OnlineStats``, the residual mirror's rebuild and
+sync counters, the broker's ``span_stats``, the ``GossipBus`` counters,
+and the solve/overhead/conflict timing split all become *views over one
+registry*: each plane exposes ``metrics_registry()`` which absorbs its
+own surfaces into counters / gauges / histograms keyed by ``(name,
+labels)``, and parent planes **merge** their children's registries under
+a composed ``plane`` label (``"g0/r1"``) — mirroring the gossip
+aggregation structure, so a snapshot only ever contains what that plane
+can legitimately see.
 
 The registry is pull-based: it is built fresh on each
 ``metrics_registry()`` call from the live stats surfaces, so it adds
@@ -23,9 +24,9 @@ __all__ = [
     "MetricsRegistry",
     "absorb_engine_stats",
     "absorb_online_stats",
+    "absorb_residual_stats",
     "absorb_gossip_stats",
     "absorb_span_stats",
-    "absorb_timing",
 ]
 
 
@@ -116,6 +117,14 @@ class MetricsRegistry:
         if h is None:
             h = self._hists[k] = Histogram()
         h.observe(value, n)
+
+    def histogram(self, name: str, h: Histogram, **labels) -> None:
+        """Fold in a histogram its owner keeps outside the registry."""
+        k = _key(name, labels)
+        mine = self._hists.get(k)
+        if mine is None:
+            mine = self._hists[k] = Histogram()
+        mine.merge(h)
 
     # -- read -----------------------------------------------------------------
 
@@ -209,6 +218,12 @@ _ENGINE_ADDITIVE = (
     "gossip_messages", "twopc_messages",
 )
 
+# wall-clock fields reported as ``timing.<field>``.  ``dp_wait_ms`` (host
+# blocked on the batched DP's answer) and ``reconstruct_ms`` (backtracking
+# its parent pointers) lie inside ``solve_ms``; never subtract them from it
+_TIMING = ("solve_ms", "overhead_ms", "conflict_resolve_ms", "dp_wait_ms",
+           "reconstruct_ms")
+
 
 def absorb_engine_stats(reg: MetricsRegistry, s, **labels) -> MetricsRegistry:
     """``engine.Stats`` -> registry.  Additive fields become counters;
@@ -226,7 +241,7 @@ def absorb_engine_stats(reg: MetricsRegistry, s, **labels) -> MetricsRegistry:
         reg.inc("engine.solves", 1.0, kernel_impl=s.kernel_impl, **labels)
     if getattr(s, "method", ""):
         reg.inc("engine.method", 1.0, method=s.method, **labels)
-    for f in ("solve_ms", "overhead_ms", "conflict_resolve_ms"):
+    for f in _TIMING:
         v = getattr(s, f, 0.0)
         if v:
             reg.inc(f"timing.{f}", float(v), **labels)
@@ -238,7 +253,7 @@ def absorb_online_stats(reg: MetricsRegistry, st, **labels) -> MetricsRegistry:
     per-impl solve counts) -> registry."""
     for f in dataclasses.fields(st):
         v = getattr(st, f.name)
-        if f.name in ("solve_ms", "overhead_ms", "conflict_resolve_ms"):
+        if f.name in _TIMING:
             reg.inc(f"timing.{f.name}", float(v), **labels)
         elif isinstance(v, (int, float)) and not isinstance(v, bool):
             if v:
@@ -255,6 +270,21 @@ def absorb_online_stats(reg: MetricsRegistry, st, **labels) -> MetricsRegistry:
                         mode=mode, **labels)
     if st.solves:
         reg.gauge("placer.mean_solve_n", float(st.mean_solve_n), **labels)
+    return reg
+
+
+def absorb_residual_stats(reg: MetricsRegistry, res, **labels
+                          ) -> MetricsRegistry:
+    """``ResidualState`` -> registry: the device mirror's ``sync_stats``
+    as ``residual.<key>`` and the host rebuilds of the residual network as
+    ``residual.rebuilds`` / ``residual.rebuild_ms``, labeled by call site."""
+    for k, v in res.sync_stats.items():
+        if v:
+            reg.inc(f"residual.{k}", float(v), **labels)
+    for site, (count, ms) in res.rebuilds.items():
+        if count:
+            reg.inc("residual.rebuilds", float(count), site=site, **labels)
+            reg.inc("residual.rebuild_ms", float(ms), site=site, **labels)
     return reg
 
 
@@ -280,12 +310,4 @@ def absorb_span_stats(reg: MetricsRegistry, ss: dict, **labels
             reg.gauge(f"twopc.{k}", float(v), **labels)
         else:
             reg.inc(f"twopc.{k}", float(v), **labels)
-    return reg
-
-
-def absorb_timing(reg: MetricsRegistry, timing: dict, **labels
-                  ) -> MetricsRegistry:
-    """``fairness_report()['timing']`` dict -> registry counters."""
-    for k, v in timing.items():
-        reg.inc(f"timing.{k}", float(v), **labels)
     return reg

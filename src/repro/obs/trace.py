@@ -22,27 +22,42 @@ which returns a view whose track names and flow ids carry a
 ``"r0/"``-style prefix — mirroring how regional registries merge into a
 global snapshot.
 
+The profiler is a second sink for spans: while a ``jax.profiler``
+session records, every span — the :data:`NULL` tracer's too — also
+enters a ``jax.profiler.TraceAnnotation`` named
+``<scope prefix><track>.<name>`` (``placer.validate.commit``,
+``r0/plane.pump.round``), so the program's host spans land on the
+profiler's clock beside the chip's ``XLA Modules`` line.  With no
+session recording that costs one ``TraceAnnotation.is_enabled()`` call
+per span.
+
 Disabled mode is the :data:`NULL` singleton: every method is a constant
-no-op (``span``/``annotate`` return one cached reusable null context),
-so instrumented hot paths pay one attribute lookup + call per hook.
-Tracing reads ``time.perf_counter`` only — no RNG, no solver state —
-so enabling it cannot perturb placement decisions (bit-identity suites
-run with tracing on).
+no-op (``span`` returns one cached reusable null context unless a
+profiler session records), so instrumented hot paths pay one attribute
+lookup + call per hook.  Tracing reads ``time.perf_counter`` only — no
+RNG, no solver state — so enabling it cannot perturb placement
+decisions (bit-identity suites run with tracing on).
 """
 from __future__ import annotations
 
 import time
 from contextlib import nullcontext
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Tracer", "NullTracer", "NULL"]
 
 _NULL_CTX = nullcontext()
 
+#: True while a profiler session records (about 40 ns to ask).
+_recording = TraceAnnotation.is_enabled
+
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """Context manager recording one complete ("X") event on exit, and a
+    profiler annotation around its body while a session records."""
 
-    __slots__ = ("_tr", "name", "track", "cat", "args", "_t0")
+    __slots__ = ("_tr", "name", "track", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tr, name, track, cat, args):
         self._tr = tr
@@ -52,11 +67,17 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = None
+        if _recording():
+            self._ann = TraceAnnotation(f"{self.track}.{self.name}")
+            self._ann.__enter__()
         self._t0 = self._tr._now_us()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = self._tr._now_us()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         ev = {
             "ph": "X",
             "name": self.name,
@@ -146,15 +167,6 @@ class Tracer:
                  track: str = "lifecycle", **args) -> None:
         self._flow("e", fid, name, track, args)
 
-    # -- accelerator hook -----------------------------------------------------
-
-    def annotate(self, name: str):
-        """``jax.profiler.TraceAnnotation`` around device dispatch so the
-        span shows up in XLA/Perfetto profiles too."""
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(self._prefix + name)
-
     # -- access ---------------------------------------------------------------
 
     @property
@@ -169,8 +181,9 @@ class NullTracer(Tracer):
     """Disabled tracer: every method is a constant no-op.
 
     ``scoped`` returns itself so plane constructors can scope
-    unconditionally; ``span``/``annotate`` return one cached reusable
-    null context manager (no allocation per hook)."""
+    unconditionally; ``span`` returns one cached reusable null context
+    manager (no allocation per hook), or, while a profiler session
+    records, the profiler annotation alone."""
 
     enabled = False
 
@@ -182,6 +195,8 @@ class NullTracer(Tracer):
         return self
 
     def span(self, name, *, track="main", cat="", **args):
+        if _recording():
+            return TraceAnnotation(f"{track}.{name}")
         return _NULL_CTX
 
     def instant(self, name, *, track="main", cat="", **args):
@@ -195,9 +210,6 @@ class NullTracer(Tracer):
 
     def flow_end(self, fid, name="request", *, track="lifecycle", **args):
         return None
-
-    def annotate(self, name):
-        return _NULL_CTX
 
     @property
     def events(self):

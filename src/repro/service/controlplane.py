@@ -29,6 +29,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import itertools
+import time
 from typing import Optional
 
 import numpy as np
@@ -54,6 +55,7 @@ class Request:
     attempts: int = 0  # failed placement tries this episode (reset on displace)
     cum_attempts: int = 0  # lifetime tries + displacements (never reset)
     creq_sum: float = 0.0
+    queued_at: float = 0.0  # perf_counter at its latest (re)queue
 
     def __post_init__(self):
         self.creq_sum = float(np.sum(self.df.creq))
@@ -202,6 +204,8 @@ class ControlPlane:
         # exhausted) — lets an owner of external rid maps (the regional
         # broker) forget its bookkeeping for terminal requests
         self.on_drop: Optional[callable] = None
+        # ms from (re)queue to dispatch, one sample per dispatched request
+        self.queue_wait_ms = obs_metrics.Histogram()
 
     # -- registration / submission ------------------------------------------
 
@@ -221,7 +225,8 @@ class ControlPlane:
         """Class-major insertion: higher classes drain first, FIFO within a
         class.  ``front_of_class`` re-inserts ahead of the request's own
         class band (preempted/displaced work resumes before new arrivals of
-        its class)."""
+        its class).  Stamps the request's queue time."""
+        r.queued_at = time.perf_counter()
         if front_of_class:
             i = next((i for i, x in enumerate(queue) if x.klass <= r.klass),
                      len(queue))
@@ -418,10 +423,12 @@ class ControlPlane:
                 )
                 if not picked:
                     break
+                now = time.perf_counter()
                 for r in picked:  # selection reads per-tenant heads in order
                     q = self.tenants[r.tenant].queue
                     assert q[0] is r, "policy must select queue heads in order"
                     q.popleft()
+                    self.queue_wait_ms.observe(1e3 * (now - r.queued_at))
                     if self.tracer.enabled:
                         self.tracer.flow_point(r.rid, "dispatch",
                                                attempts=r.attempts)
@@ -656,9 +663,9 @@ class ControlPlane:
         gossip aggregation, a plane only reports what it can see."""
         reg = obs_metrics.MetricsRegistry()
         obs_metrics.absorb_online_stats(reg, self.placer.stats)
-        for k, v in self.placer.res.sync_stats.items():
-            if v:
-                reg.inc(f"residual.{k}", float(v))
+        obs_metrics.absorb_residual_stats(reg, self.placer.res)
+        if self.queue_wait_ms.count:
+            reg.histogram("plane.queue_wait_ms", self.queue_wait_ms)
         committed = self.committed_capacity()
         for t, st in self.tenants.items():
             reg.gauge("tenant.committed", committed[t], tenant=t)

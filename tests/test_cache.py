@@ -51,8 +51,9 @@ def _cache_free(stats):
     the on side counts its misses)."""
     d = dataclasses.asdict(stats)
     for k in ("solve_ms", "overhead_ms", "conflict_resolve_ms",
-              "cache_hits", "cache_misses", "cache_stale",
-              "cache_neg_hits", "warm_solves", "warm_fallbacks"):
+              "dp_wait_ms", "reconstruct_ms", "remap_ms", "cache_hits",
+              "cache_misses", "cache_stale", "cache_neg_hits", "warm_solves",
+              "warm_fallbacks"):
         d.pop(k)
     return d
 

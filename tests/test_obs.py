@@ -191,7 +191,7 @@ def test_tracer_scoped_prefixes_share_one_buffer():
 
 def test_null_tracer_is_inert():
     assert isinstance(NULL, NullTracer) and not NULL.enabled
-    # span/annotate return a shared no-op context: no per-call allocation
+    # span returns a shared no-op context: no per-call allocation
     assert NULL.span("x") is NULL.span("y", track="t", cat="c", k=1)
     with NULL.span("x"):
         pass
@@ -575,3 +575,172 @@ def test_gossip_bus_snapshot_unit():
     bus.snapshot(reset=True)
     assert bus.snapshot()["rounds"] == 0
     assert bus.gossip_stats()["rounds"] == 2
+
+
+# ---------------------------------------------------------------------------
+# profiler sink and the inner-boundary counters
+# ---------------------------------------------------------------------------
+
+JM = dict(method="leastcost_jax")  # the batched DP: dispatch/finalize path
+
+
+def _requests(rg, k, seed):
+    return [random_dataflow(rg, 3, seed=seed + i, creq_range=(0.05, 0.2),
+                            breq_range=(0.5, 2.0)) for i in range(k)]
+
+
+def test_spans_cost_nothing_without_a_profiler_session():
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled()
+    a = NULL.span("validate.commit", track="placer")
+    assert a is NULL.span("rebuild", track="residual", site="commit")
+    sp = Tracer().span("rebuild", track="residual")
+    with sp:
+        assert sp._ann is None  # no profiler annotation opened
+
+
+def _host_events(trace_dir):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns, line.name)
+                    for e in line.events]
+    return out
+
+
+def test_profiler_sink_nests_program_spans_in_the_device_trace(tmp_path):
+    import jax
+    from jax.profiler import TraceAnnotation
+
+    rg = waxman(10, seed=3)
+    cp = ControlPlane(rg, micro_batch=4, **JM)
+    cp.register_tenant("a", weight=1.0)
+    for df in _requests(rg, 3, 40):
+        cp.submit("a", df)
+    scoped = Tracer().scoped("r0")
+    with jax.profiler.trace(str(tmp_path)):
+        with TraceAnnotation("outer"):
+            cp.pump()
+            with scoped.span("round", track="plane"):
+                pass
+    evs = _host_events(tmp_path)
+    (outer,) = [e for e in evs if e[0] == "outer"]
+    for name in ("plane.pump.round", "placer.dispatch", "placer.dp_wait",
+                 "placer.reconstruct", "placer.validate.commit",
+                 "residual.rebuild", "residual.sync", "r0/plane.round"):
+        mine = [e for e in evs if e[0] == name]
+        assert mine, name
+        for _, s, t, line in mine:
+            # same thread line as the enclosing annotation, inside it
+            assert line == outer[3] and outer[1] <= s <= t <= outer[2], name
+    # once the session stops the sink is off again
+    assert NULL.span("x") is NULL.span("y")
+
+
+def test_rebuilds_are_counted_per_call_site():
+    rg = waxman(10, seed=3)
+    cp = ControlPlane(rg, **JM)
+    placer = cp.placer
+    (t,) = placer.admit_many(_requests(rg, 1, 40))
+    assert t is not None
+    counts = {site: c for site, (c, _) in placer.res.rebuilds.items()}
+    # classify the batch, upload the mirror, dispatch, then validate before
+    # and after the one commit
+    assert counts == {"classify": 1, "upload": 1, "dispatch": 1, "commit": 2}
+    for _ in range(3):
+        placer.residual_graph("conflict")
+    placer.residual_graph()
+    reg = cp.metrics_registry()
+    assert reg.get("residual.rebuilds", site="conflict") == 3.0
+    assert reg.get("residual.rebuilds", site="other") == 1.0
+    assert reg.get("residual.rebuilds", site="commit") == 2.0
+    assert reg.get("residual.rebuild_ms", site="commit") > 0.0
+    assert reg.get("residual.full_uploads") == 1.0
+    assert reg.get("residual.sync_ms") > 0.0
+
+
+def test_dp_wait_and_reconstruct_lie_inside_each_solve():
+    rg = waxman(10, seed=6)
+    placer = OnlinePlacer(rg, **JM)
+    seen = []
+    note = placer._note_solve
+
+    def spy(st, **kw):
+        seen.append(st)
+        note(st, **kw)
+
+    placer._note_solve = spy
+    for k in range(3):
+        placer.admit_many(_requests(rg, 4, 500 + 10 * k))
+    batched = [st for st in seen if st.dp_wait_ms > 0.0]
+    assert batched
+    for st in seen:
+        assert st.reconstruct_ms >= 0.0
+        assert st.dp_wait_ms + st.reconstruct_ms <= st.solve_ms
+    reg = MetricsRegistry()
+    absorb_online_stats(reg, placer.stats)
+    assert reg.get("timing.dp_wait_ms") == pytest.approx(
+        sum(st.dp_wait_ms for st in seen))
+    assert reg.get("timing.reconstruct_ms") == pytest.approx(
+        sum(st.reconstruct_ms for st in seen))
+
+
+def test_queue_wait_has_one_sample_per_dispatch():
+    rg = waxman(10, seed=3)
+    tr = Tracer()
+    cp = ControlPlane(rg, micro_batch=2, max_attempts=3, preempt=False,
+                      tracer=tr, **PYM)
+    cp.register_tenant("a", weight=1.0)
+    for df in _requests(rg, 3, 40):
+        cp.submit("a", df)
+    # fits nowhere: rejected, requeued and dispatched again until dropped
+    cp.submit("a", DataflowPath.make([0.0, 1e6, 0.0], [1.0, 1.0], 0, 2))
+    cp.pump(rounds=8)
+    dispatches = [e for e in tr.events
+                  if e["ph"] == "n" and e["name"] == "dispatch"]
+    assert len(dispatches) == 3 + 3  # the infeasible one three times
+    assert cp.queue_wait_ms.count == len(dispatches)
+    snap = cp.metrics_registry().snapshot()
+    assert snap["plane.queue_wait_ms"]["count"] == len(dispatches)
+    assert snap["plane.queue_wait_ms"]["min"] >= 0.0
+
+
+def test_remap_ms_counts_only_failures_that_displace():
+    rg = waxman(10, seed=6)
+    placer = OnlinePlacer(rg, **PYM)
+    tickets = [t for t in placer.admit_many(_requests(rg, 3, 500)) if t]
+    used = {v for t in tickets for v in t.mapping.route}
+    idle = [v for v in range(rg.n) if v not in used]
+    assert idle and tickets
+    placer.fail_node(idle[0])
+    assert placer.stats.remap_ms == 0.0
+    reg = MetricsRegistry()
+    absorb_online_stats(reg, placer.stats)
+    assert reg.get("placer.remap_ms") is None
+    victim = tickets[0].mapping.route[1]
+    remapped, dropped = placer.fail_node(victim)
+    assert remapped or dropped
+    assert placer.stats.remap_ms > 0.0
+    assert placer.stats.remapped + placer.stats.dropped == (
+        len(remapped) + len(dropped))
+
+
+def test_vmapped_dp_module_has_a_stable_name():
+    from repro.core.leastcost import _vmapped_dp
+    from repro.core.problem import stack_requests
+
+    rg = waxman(8, seed=1)
+    tensors, p = stack_requests(rg, _requests(rg, 2, 7))
+    hlo = _vmapped_dp(rg.n, p, rg.n - 1).lower(tensors).as_text()
+    assert "jit_vmapped_leastcost_dp" in hlo
+    assert "lambda" not in hlo.split("\n", 1)[0]

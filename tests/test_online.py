@@ -191,7 +191,8 @@ def _clock_free(stats):
     """Stats minus the wall-clock fields (the only legitimate divergence
     between the synchronous and the depth-1 pipelined path)."""
     d = dataclasses.asdict(stats)
-    for k in ("solve_ms", "overhead_ms", "conflict_resolve_ms"):
+    for k in ("solve_ms", "overhead_ms", "conflict_resolve_ms",
+              "dp_wait_ms", "reconstruct_ms", "remap_ms"):
         d.pop(k)
     return d
 
