@@ -767,7 +767,7 @@ class OnlinePlacer:
         conflict_ms = 0.0
         out: list[Optional[Ticket]] = []
         with span:
-            current = self.residual_graph("commit")
+            current = self.res.residual_graph("commit", frozen=False)
             for idx, (df, m, (tenant, klass)) in enumerate(
                     zip(dfs, mappings, metas)):
                 kind = plan[idx][0] if plan is not None else "cold"
@@ -781,7 +781,7 @@ class OnlinePlacer:
                         self.stats.cache_hits += 1
                     self.stats.admitted += 1
                     out.append(self._commit(df, m, tenant=tenant, klass=klass))
-                    current = self.residual_graph("commit")
+                    current = self.res.residual_graph("commit", frozen=False)
                 elif m is not None:
                     # stale snapshot (a commit since dispatch took the
                     # capacity) — optimistic-concurrency retry, individually.
@@ -798,7 +798,8 @@ class OnlinePlacer:
                     conflict_ms += 1e3 * (time.perf_counter() - t0)
                     out.append(t)
                     if t is not None:
-                        current = self.residual_graph("conflict")
+                        current = self.res.residual_graph(
+                            "conflict", frozen=False)
                 elif kind == "warm":
                     # the bounded correction pass placed nothing — the fuse:
                     # fall back to a full cold re-solve so admission quality
@@ -811,7 +812,8 @@ class OnlinePlacer:
                     conflict_ms += 1e3 * (time.perf_counter() - t0)
                     out.append(t)
                     if t is not None:
-                        current = self.residual_graph("conflict")
+                        current = self.res.residual_graph(
+                            "conflict", frozen=False)
                 else:
                     self.stats.rejected += 1
                     if (cache is not None and kind == "cold"

@@ -22,6 +22,18 @@ Two counters version the state:
   is monotone and never restored from a snapshot, so a stale in-flight
   solve can never be made to look fresh by a rollback.
 
+Host view: ``residual_graph()`` hands out float32 ``cap``/``bw``/``lat``
+with liveness applied, the exact arrays :meth:`ResidualState._full_view`
+computes from the float64 truth.  That view is computed in full once and
+otherwise patched in place: a commit or release rewrites the O(p) entries
+it touched, a liveness change one node's row and column or one link's two
+entries.  Every patched entry is recomputed from float64 by the full
+view's own formula, never accumulated in float32, so the view stays
+bit-identical to a full rebuild.  A frozen handout never changes afterwards
+(a dispatched batch reconstructs on it): it takes its own copy of ``cap``
+and shares ``bw``/``lat`` copy-on-write, as a snapshot does for
+:meth:`restore`.
+
 Float32 drift: the device tensors are updated incrementally in float32
 while the host accumulates in float64, so after many commits they can
 differ from a fresh ``float32(host)`` round-trip by a few ulps.  That is
@@ -59,7 +71,8 @@ def _pow2_pad(arr: np.ndarray) -> np.ndarray:
 
 class ResidualState:
     """Residual capacity/bandwidth of one resource network: float64 host
-    truth + lazily synchronized float32 device tensors + staleness fences."""
+    truth + a float32 host view patched in place + lazily synchronized
+    float32 device tensors + staleness fences."""
 
     def __init__(self, base: ResourceGraph, tracer=None):
         self.base = base
@@ -76,37 +89,90 @@ class ResidualState:
         self._edge_delta: dict[tuple, float] = {}  # (u,v) -> pending bw delta
         # telemetry (repro.obs registry reads these): how often the device
         # mirror paid a full O(n^2) upload vs an O(delta) scatter-add, and
-        # the wall clock of every sync
+        # the wall clock of every sync; how often the host view was
+        # computed in full, the entries patched in place, and the view
+        # arrays copied on write because a handout shared them
         self.sync_stats = {"full_uploads": 0, "delta_syncs": 0,
-                           "invalidations": 0, "sync_ms": 0.0}
-        # call site -> [rebuilds, ms] of residual_graph(), three dense
-        # float32 n x n arrays each
+                           "invalidations": 0, "sync_ms": 0.0,
+                           "full_views": 0, "patched_entries": 0,
+                           "cow_copies": 0}
+        # call site -> [calls, ms] of residual_graph()
         self.rebuilds: dict[str, list] = {}
+        self._view = self._full_view()
+        self._shared: set[str] = set()  # view arrays a handout/snapshot holds
+        self.sync_stats["full_views"] += 1
 
     # -- host truth ---------------------------------------------------------
 
-    def residual_graph(self, site: str = "other") -> ResourceGraph:
+    def _full_view(self) -> dict:
+        """Float32 ``{cap, bw, lat}`` of the residual network computed in
+        full from the float64 truth: committed capacity subtracted, failed
+        nodes/links removed (cap 0 / bw 0 / lat INF), ``lat``'s diagonal 0.
+        The in-place view equals it bit for bit."""
+        up2 = self.node_up[:, None] & self.node_up[None, :]
+        alive = self.link_up & up2
+        cap = np.where(self.node_up, self.cap, 0.0).astype(np.float32)
+        bw = np.where(alive, self.bw, 0.0).astype(np.float32)
+        lat = np.where(alive, self.base.lat, INF).astype(np.float32)
+        np.fill_diagonal(lat, 0.0)
+        return dict(cap=cap, bw=bw, lat=lat)
+
+    def _writable(self, name: str) -> np.ndarray:
+        """View array ``name``, first copied if a handed-out graph or a
+        snapshot shares it (copy-on-write keeps both unchanged)."""
+        if name in self._shared:
+            self._shared.discard(name)
+            self._view[name] = self._view[name].copy()
+            self.sync_stats["cow_copies"] += 1
+        return self._view[name]
+
+    def _patch(self, nodes, us, vs, *, lat: bool = False) -> None:
+        """Recompute the view's ``cap[nodes]`` and ``bw[us, vs]`` (and
+        ``lat[us, vs]``) from the float64 truth by :meth:`_full_view`'s
+        formulas: O(entries), nothing accumulated in float32."""
+        if len(nodes):
+            nodes = np.asarray(nodes, np.intp)
+            self._view["cap"][nodes] = np.where(
+                self.node_up[nodes], self.cap[nodes], 0.0)
+        if len(us):
+            us, vs = np.asarray(us, np.intp), np.asarray(vs, np.intp)
+            alive = self.link_up[us, vs] & self.node_up[us] & self.node_up[vs]
+            self._writable("bw")[us, vs] = np.where(
+                alive, self.bw[us, vs], 0.0)
+            if lat:
+                self._writable("lat")[us, vs] = np.where(
+                    us == vs, 0.0, np.where(alive, self.base.lat[us, vs], INF))
+        self.sync_stats["patched_entries"] += len(nodes) + len(us) * (1 + lat)
+
+    def residual_graph(self, site: str = "other", *,
+                       frozen: bool = True) -> ResourceGraph:
         """The network the next solve sees: committed capacity subtracted,
         failed nodes/links removed (cap 0 / bw 0 / lat INF).  ``site``
-        names the caller in the rebuild counters."""
+        names the caller in the rebuild counters.
+
+        A frozen graph never changes afterwards.  ``frozen=False`` returns
+        the live view instead, which the next mutation changes: for a read
+        that is done before then (validation between commits), and copies
+        nothing."""
         t0 = time.perf_counter()
         with self.tracer.span("rebuild", track="residual", site=site):
-            up2 = self.node_up[:, None] & self.node_up[None, :]
-            alive = self.link_up & up2
-            cap = np.where(self.node_up, self.cap, 0.0).astype(np.float32)
-            bw = np.where(alive, self.bw, 0.0).astype(np.float32)
-            lat = np.where(alive, self.base.lat, INF).astype(np.float32)
-            np.fill_diagonal(lat, 0.0)
+            view = self._view
+            cap = view["cap"]
+            if frozen:
+                cap = cap.copy()
+                self._shared.update(("bw", "lat"))
+            rg = ResourceGraph(cap, view["bw"], view["lat"])
         c = self.rebuilds.setdefault(site, [0, 0.0])
         c[0] += 1
         c[1] += 1e3 * (time.perf_counter() - t0)
-        return ResourceGraph(cap, bw, lat)
+        return rg
 
     def apply_load(self, node_load: dict, edge_load: dict, sign: float) -> None:
         """Commit (``sign=-1``) or release (``sign=+1``) a ticket's loads.
 
-        Host arrays update immediately; the device mirror accumulates the
-        delta and applies it as one scatter-add at the next dispatch."""
+        Host arrays and the host view update immediately; the device mirror
+        accumulates the delta and applies it as one scatter-add at the next
+        dispatch."""
         for v, c in node_load.items():
             d = sign * c
             self.cap[v] += d
@@ -118,16 +184,23 @@ class ResidualState:
             if self._dev is not None and self.link_up[u, v]:
                 key = (u, v)
                 self._edge_delta[key] = self._edge_delta.get(key, 0.0) + d
+        self._patch(list(node_load), [u for u, _ in edge_load],
+                    [v for _, v in edge_load])
         self.version += 1
 
     # -- liveness (drops the device cache: lat changes shape of the problem)
 
     def set_node_up(self, v: int, up: bool) -> None:
         self.node_up[v] = up
+        n = self.base.n
+        row, ids = np.full(n, v), np.arange(n)
+        self._patch([v], np.concatenate([row, ids]),
+                    np.concatenate([ids, row]), lat=True)
         self._invalidate()
 
     def set_link_up(self, u: int, v: int, up: bool) -> None:
         self.link_up[u, v] = self.link_up[v, u] = up
+        self._patch([], [u, v], [v, u], lat=True)
         self._invalidate()
 
     def _invalidate(self) -> None:
@@ -143,11 +216,13 @@ class ResidualState:
     # -- snapshot / restore -------------------------------------------------
 
     def snapshot(self) -> dict:
+        self._shared.update(("bw", "lat"))
         return {
             "cap": self.cap.copy(),
             "bw": self.bw.copy(),
             "node_up": self.node_up.copy(),
             "link_up": self.link_up.copy(),
+            "view": dict(self._view, cap=self._view["cap"].copy()),
         }
 
     def restore(self, snap: dict) -> None:
@@ -157,6 +232,8 @@ class ResidualState:
         self.bw = snap["bw"].copy()
         self.node_up = snap["node_up"].copy()
         self.link_up = snap["link_up"].copy()
+        self._view = dict(snap["view"], cap=snap["view"]["cap"].copy())
+        self._shared = {"bw", "lat"}  # the snapshot stays reusable
         self._invalidate()
 
     # -- device mirror ------------------------------------------------------
