@@ -8,21 +8,25 @@ reference replays that record on its own ledger and judges each decision:
 - every placement the plane made (first placements, re-admissions and
   re-mappings after the failure) keeps the stated rules on the residual it
   was made on (``Reference.invalid``);
-- a request's first placement costs what LeastCostMap gives on that
-  residual.  Placements within one pump commit in ticket order, so the
-  residual of each is the one before the pump less those committed before
-  it.  Re-admissions may commit a cached mapping, which the configuration
-  does not hold to least cost, and a pump that preempted standing work
-  leaves the order of releases unknown; both are checked for validity
-  alone;
-- a dropped request fits nowhere on the smallest residual the pump can
-  have had (everything live before or after it still held);
+- a request's first placement costs what the configuration's reference
+  (``first_cost``, ``bench/references``) promises on that residual:
+  LeastCostMap's cost for ``leastcost``.  Placements within one pump commit
+  in ticket order, so the residual of each is the one before the pump less
+  those committed before it.  Re-admissions may commit a cached mapping,
+  which the configuration does not hold to least cost, and a pump that
+  preempted standing work leaves the order of releases unknown; both are
+  checked for validity alone, as is a request the reference promises
+  nothing for (``None``);
+- a dropped request fits nowhere, by the reference, on the smallest
+  residual the pump can have had (everything live before or after it
+  still held);
 - every request due in the window is decided before the drain ends.
 
 The control puts the reference with its relaxation cut to
 ``control_supersteps`` supersteps in the program's place: each first
-placement's cost is the control's, on the same residual, and is judged as
-the program's would be.
+placement's cost is the control's (``first_cost`` with
+``max_supersteps``), on the same residual, and is judged as the program's
+would be.
 """
 from __future__ import annotations
 
@@ -31,7 +35,7 @@ import math
 
 from .reference import Reference
 
-# A first placement's cost may sit this far from LeastCostMap's, relative
+# A first placement's cost may sit this far from the promised one, relative
 # to the cost: float32 round-off is the only slack (the configurations'
 # latencies are whole numbers, so a different route differs by >= 1).
 COST_TOL = 1e-3
@@ -50,9 +54,9 @@ class Placement:
 class Readings:
     placements: int = 0  # placements checked for validity
     invalid: int = 0
-    checked_cost: int = 0  # first placements compared with LeastCostMap
-    wrong_costs: int = 0  # cost off LeastCostMap's, or fits in one side only
-    cost_gap: float = 0.0  # widest |cost - LeastCostMap| where both fit
+    checked_cost: int = 0  # first placements compared with the reference
+    wrong_costs: int = 0  # cost off the promised one, or fits on one side only
+    cost_gap: float = 0.0  # widest |cost - promised| where both fit
     drops: int = 0
     wrong_drops: int = 0
     undecided: int = 0
@@ -63,9 +67,10 @@ class Readings:
             self.first_fault = why
 
 
-def replay(net, requests: dict, events: list, *, undecided: int,
+def replay(net, requests: dict, events: list, *, first_cost, undecided: int,
            control_supersteps: int | None = None) -> Readings:
-    """Judge the recorded calls.  ``requests`` maps rid -> traffic
+    """Judge the recorded calls.  ``first_cost`` is the configuration's
+    reference (``catalog.reference``); ``requests`` maps rid -> traffic
     ``Request``; ``events`` holds ``("pump", snapshot, drops)``,
     ``("release", rid)``, ``("fail", node, snapshot)`` and
     ``("restore", node)`` in call order, a snapshot mapping rid ->
@@ -95,9 +100,11 @@ def replay(net, requests: dict, events: list, *, undecided: int,
 
     def compare(rid, pl):
         r = requests[rid]
-        best = ref.least_cost(r.creq, r.breq, r.src, r.dst)
-        cost = pl.cost if control_supersteps is None else ref.least_cost(
-            r.creq, r.breq, r.src, r.dst, max_supersteps=control_supersteps)
+        best = first_cost(ref, r)
+        if best is None:
+            return
+        cost = pl.cost if control_supersteps is None else first_cost(
+            ref, r, max_supersteps=control_supersteps)
         out.checked_cost += 1
         if not (math.isinf(best) or math.isinf(cost)):
             out.cost_gap = max(out.cost_gap, abs(cost - best))
@@ -147,8 +154,8 @@ def replay(net, requests: dict, events: list, *, undecided: int,
                 for rid in drops:
                     r = requests[rid]
                     out.drops += 1
-                    if not math.isinf(ref.least_cost(r.creq, r.breq,
-                                                     r.src, r.dst)):
+                    best = first_cost(ref, r)
+                    if best is not None and not math.isinf(best):
                         out.wrong_drops += 1
                         out.fault(f"request {rid} dropped but fits")
                 for pl_rid, pl in vanished.items():

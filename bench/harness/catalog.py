@@ -4,9 +4,17 @@
 
 - ``bench/configs/<config>.json`` (the path ``BENCHMARK.json`` gives),
 - ``bench/traffic/<mix>.json``,
-- ``bench/metrics/<metric>.py``, a module with ``read(ctx) -> float | None``.
+- ``bench/metrics/<metric>.py``, a module with ``read(ctx) -> float | None``;
+- ``bench/planes/<reader>.py``, how the harness reads a plane (the
+  configuration's optional ``"reader"``, default ``centralized``): a module
+  with ``attach(cp, on_drop)``, ``live(cp)``, ``is_live(cp, rid)`` and
+  ``committed_share(cp)``;
+- ``bench/references/<reference>.py``, what the configuration promises for
+  a request's first placement (its optional ``"reference"``, default
+  ``leastcost``): a module with ``first_cost(ref, r, *, max_supersteps=None)``.
 
-A new cell, mix or metric is new files and new entries, never an edit.
+A new cell, mix, metric, plane reader or reference is new files and new
+entries, never an edit.
 """
 from __future__ import annotations
 
@@ -44,13 +52,32 @@ def traffic(name: str, bench_dir: str = BENCH_DIR) -> dict:
         return json.load(f)
 
 
-def metric_reader(name: str, bench_dir: str = BENCH_DIR):
-    path = os.path.join(bench_dir, "metrics", f"{name}.py")
+def _module(kind: str, name: str, bench_dir: str):
+    """The module ``bench/<kind>/<name>.py``; a missing file fails here,
+    naming the path looked for."""
+    path = os.path.join(bench_dir, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} module {name!r}: {path}")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: str = BENCH_DIR):
+    return _module("metrics", name, bench_dir).read
+
+
+def reader(config: dict, bench_dir: str = BENCH_DIR):
+    """The module that reads the configuration's plane."""
+    return _module("planes", config.get("reader", "centralized"), bench_dir)
+
+
+def reference(config: dict, bench_dir: str = BENCH_DIR):
+    """The ``first_cost`` the configuration's placements are judged by."""
+    return _module("references", config.get("reference", "leastcost"),
+                   bench_dir).first_cost
 
 
 def metrics_of(bench: dict, cell_name: str, kind: str) -> list:

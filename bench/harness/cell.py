@@ -86,6 +86,7 @@ class Measured:
     seed: int
     trace: bool
     net: network.Network
+    first_cost: object  # the configuration's reference (catalog.reference)
     rec: loop.Record
     window_rids: list
     counters: dict  # program counters over the window
@@ -111,6 +112,8 @@ def measure(cell_name: str, *, seed: int, seconds: float, trace: bool,
     config = config if config is not None else catalog.config(
         bench, cell["config"])
     mix = mix if mix is not None else catalog.traffic(cell["traffic"])
+    reader = catalog.reader(config)
+    first_cost = catalog.reference(config)
     out_dir = out_dir or os.path.join(catalog.ROOT, ".bench_out")
     devices = jax.devices()
     dev = devices[0]
@@ -122,15 +125,15 @@ def measure(cell_name: str, *, seed: int, seconds: float, trace: bool,
                           standing=int(config["standing"]["count"]))
     cp = build_plane(config, net)
     rec = loop.Record()
-    drv = loop.PlaneDriver(cp, rec)
+    drv = loop.PlaneDriver(cp, rec, reader)
     drv.register(mix["tenants"])
     phases["plane"] = time.perf_counter() - t_start
     for p in range(mix["p"][0], mix["p"][1] + 1):
         cp.warmup(p=p)
     phases["warmup"] = time.perf_counter() - t_start
     loop.preload(drv, sched.standing, make_df)
-    standing_live = len(cp.active)
-    util = cp.placer.utilization()["nodes_committed"]
+    standing_live = len(reader.live(cp))
+    util = reader.committed_share(cp)
     setup = clog.snapshot()
     setup_s = time.perf_counter() - t_start
     c0 = counters(cp)
@@ -174,8 +177,8 @@ def measure(cell_name: str, *, seed: int, seconds: float, trace: bool,
         "pump_log": rec.pump_log[n_pumps0:],
         "submit_lag_max_s": max(rec.submit_lag_s, default=0.0),
     }
-    return Measured(bench, cell_name, seed, trace, net, rec, window_rids,
-                    at_close["counters"], at_close["spans_s"],
+    return Measured(bench, cell_name, seed, trace, net, first_cost, rec,
+                    window_rids, at_close["counters"], at_close["spans_s"],
                     int(cp.engine_stats().fallbacks), ledger_broken, setup_s,
                     device, tdir, diag)
 
@@ -199,7 +202,8 @@ def report(m: Measured, *, control: int | None = None,
     never = sum(1 for r in window_rids if r not in rec.decided)
 
     t_audit = time.perf_counter()
-    readings = audit.replay(m.net, rec.requests, rec.events, undecided=never,
+    readings = audit.replay(m.net, rec.requests, rec.events,
+                            first_cost=m.first_cost, undecided=never,
                             control_supersteps=control)
     audit_s = time.perf_counter() - t_audit
     ok, compared = judge(readings, m.fallbacks, m.ledger_broken)

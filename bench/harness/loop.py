@@ -23,8 +23,6 @@ import time
 
 from jax.profiler import TraceAnnotation
 
-from .audit import Placement
-
 
 @dataclasses.dataclass
 class Record:
@@ -45,29 +43,23 @@ class Record:
 class PlaneDriver:
     """The calls the loop makes into a plane, and what it reads back.
 
-    Works on the centralized ``ControlPlane``: live placements come from
-    its ``active`` table and drops from its ``on_drop`` hook."""
+    ``reader`` is the configuration's plane reader (``catalog.reader``):
+    live placements and drops come through it, so the driver works on any
+    plane that has one."""
 
-    def __init__(self, cp, record: Record, clock=time.perf_counter):
-        if not hasattr(cp, "active"):
-            raise NotImplementedError(
-                "the harness reads placements from ControlPlane.active; "
-                f"{type(cp).__name__} has none")
+    def __init__(self, cp, record: Record, reader, clock=time.perf_counter):
         self.cp = cp
         self.rec = record
+        self.reader = reader
         self.clock = clock
         self._drops: list = []
         self._last: dict = {}
-        cp.on_drop = lambda req: self._drops.append(req.rid)
+        # not the list's own append: ``pump`` swaps in a fresh list
+        reader.attach(cp, lambda rid: self._drops.append(rid))
 
     def _span(self, name: str, t0: float) -> None:
         self.rec.span_s[name] = self.rec.span_s.get(name, 0.0) + (
             self.clock() - t0)
-
-    def _snapshot(self) -> dict:
-        return {rid: Placement(t, t.tid, tuple(t.mapping.assign),
-                               tuple(t.mapping.route), float(t.mapping.cost))
-                for rid, (_, t) in self.cp.active.items()}
 
     def register(self, tenants) -> None:
         for t in tenants:
@@ -94,7 +86,7 @@ class PlaneDriver:
         t = now()
         self.rec.pumps += 1
         drops, self._drops = self._drops, []
-        snap = self._snapshot()
+        snap = self.reader.live(self.cp)
         last = self._last
         self._last = snap
         if (not drops and led == self.cp.conservation() and len(snap) ==
@@ -115,7 +107,7 @@ class PlaneDriver:
 
     def release(self, rid: int) -> bool:
         """Release ``rid`` if it is live; False if it is not (yet)."""
-        if rid not in self.cp.active:
+        if not self.reader.is_live(self.cp, rid):
             return False
         t0 = self.clock()
         with TraceAnnotation("bench.release"):
@@ -129,7 +121,7 @@ class PlaneDriver:
         with TraceAnnotation("bench.churn"):
             for v in nodes:
                 self.cp.fail_node(int(v))
-                self._last = self._snapshot()
+                self._last = self.reader.live(self.cp)
                 self.rec.events.append(("fail", int(v), self._last))
         self._span("bench.churn", t0)
 

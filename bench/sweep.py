@@ -46,10 +46,11 @@ def main(argv=None) -> int:
     config = catalog.config(bench, args.config, ROOT)
     mix = catalog.traffic(args.mix)
     mix.pop("churn", None)
+    reader = catalog.reader(config)
     net = network.build(config["network"])
     cp = cell.build_plane(config, net)
     rec = loop.Record()
-    drv = loop.PlaneDriver(cp, rec)
+    drv = loop.PlaneDriver(cp, rec, reader)
     drv.register(mix["tenants"])
     for p in range(mix["p"][0], mix["p"][1] + 1):
         cp.warmup(p=p)
