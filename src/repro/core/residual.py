@@ -32,7 +32,15 @@ view's own formula, never accumulated in float32, so the view stays
 bit-identical to a full rebuild.  A frozen handout never changes afterwards
 (a dispatched batch reconstructs on it): it takes its own copy of ``cap``
 and shares ``bw``/``lat`` copy-on-write, as a snapshot does for
-:meth:`restore`.
+:meth:`restore`.  The state holds each such holder by weak reference, and a
+write copies an n x n array only while a holder of that very array is still
+alive; one dropped before the write (the depth-1 pipeline's dispatched
+graph, dropped at ``finalize`` before the commits) costs nothing.  The
+device mirror keeps the handout of its last full upload alive for as long
+as the mirror lives, because JAX may read the host array after
+``jnp.asarray`` returns (on the CPU it aliases a large array zero-copy, so a
+write in place would change the device tensor under its scatter-add
+deltas): the first write after a full upload copies, once.
 
 Float32 drift: the device tensors are updated incrementally in float32
 while the host accumulates in float64, so after many commits they can
@@ -47,6 +55,7 @@ from __future__ import annotations
 
 import itertools
 import time
+import weakref
 
 import numpy as np
 
@@ -69,6 +78,11 @@ def _pow2_pad(arr: np.ndarray) -> np.ndarray:
     return np.concatenate([arr, np.zeros(m - k, arr.dtype)])
 
 
+class _SnapshotView(dict):
+    """A snapshot's ``{cap, bw, lat}``: a dict that takes a weak reference,
+    so the state can tell whether the snapshot still holds the arrays."""
+
+
 class ResidualState:
     """Residual capacity/bandwidth of one resource network: float64 host
     truth + a float32 host view patched in place + lazily synchronized
@@ -85,21 +99,25 @@ class ResidualState:
         self.version = 0  # bumps on every host mutation
         self.epoch = 0  # bumps only when in-flight solves become invalid
         self._dev: dict | None = None  # {"cap","bw","lat"} jnp tensors
+        self._upload: ResourceGraph | None = None  # host source of _dev
         self._node_delta: dict[int, float] = {}  # node -> pending cap delta
         self._edge_delta: dict[tuple, float] = {}  # (u,v) -> pending bw delta
         # telemetry (repro.obs registry reads these): how often the device
         # mirror paid a full O(n^2) upload vs an O(delta) scatter-add, and
         # the wall clock of every sync; how often the host view was
-        # computed in full, the entries patched in place, and the view
-        # arrays copied on write because a handout shared them
+        # computed in full, the entries patched in place, the view arrays
+        # copied on write because a live handout shared them, and the first
+        # writes to a shared array whose holders had all died (no copy)
         self.sync_stats = {"full_uploads": 0, "delta_syncs": 0,
                            "invalidations": 0, "sync_ms": 0.0,
                            "full_views": 0, "patched_entries": 0,
-                           "cow_copies": 0}
+                           "cow_copies": 0, "cow_avoided": 0}
         # call site -> [calls, ms] of residual_graph()
         self.rebuilds: dict[str, list] = {}
         self._view = self._full_view()
-        self._shared: set[str] = set()  # view arrays a handout/snapshot holds
+        # view array name -> (holder, array) weak references: a frozen
+        # handout or snapshot that shares that array, dead ones pruned
+        self._holders: dict[str, list] = {"bw": [], "lat": []}
         self.sync_stats["full_views"] += 1
 
     # -- host truth ---------------------------------------------------------
@@ -117,14 +135,31 @@ class ResidualState:
         np.fill_diagonal(lat, 0.0)
         return dict(cap=cap, bw=bw, lat=lat)
 
+    def _hold(self, holder) -> None:
+        """Register ``holder`` (a frozen handout or a snapshot's view) as
+        sharing the view's current ``bw``/``lat`` for as long as it lives."""
+        ref = weakref.ref(holder)
+        for name in ("bw", "lat"):
+            held = [e for e in self._holders[name] if e[0]() is not None]
+            held.append((ref, weakref.ref(self._view[name])))
+            self._holders[name] = held
+
     def _writable(self, name: str) -> np.ndarray:
-        """View array ``name``, first copied if a handed-out graph or a
-        snapshot shares it (copy-on-write keeps both unchanged)."""
-        if name in self._shared:
-            self._shared.discard(name)
-            self._view[name] = self._view[name].copy()
-            self.sync_stats["cow_copies"] += 1
-        return self._view[name]
+        """View array ``name``, first copied if a frozen handout or a
+        snapshot that shares it is still alive (copy-on-write keeps both
+        unchanged).  A first write to a shared array whose holders have all
+        died takes it in place and counts ``cow_avoided``."""
+        arr = self._view[name]
+        held = self._holders[name]
+        shared = [h for h, a in held if a() is arr]
+        if shared:
+            if any(h() is not None for h in shared):
+                arr = self._view[name] = arr.copy()
+                self.sync_stats["cow_copies"] += 1
+            else:
+                self.sync_stats["cow_avoided"] += 1
+            self._holders[name] = [e for e in held if e[0]() is not None]
+        return arr
 
     def _patch(self, nodes, us, vs, *, lat: bool = False) -> None:
         """Recompute the view's ``cap[nodes]`` and ``bw[us, vs]`` (and
@@ -150,18 +185,20 @@ class ResidualState:
         failed nodes/links removed (cap 0 / bw 0 / lat INF).  ``site``
         names the caller in the rebuild counters.
 
-        A frozen graph never changes afterwards.  ``frozen=False`` returns
-        the live view instead, which the next mutation changes: for a read
-        that is done before then (validation between commits), and copies
-        nothing."""
+        A frozen graph never changes afterwards: it owns a copy of ``cap``
+        and shares ``bw``/``lat`` with the view, which the next write to
+        either copies only if this graph is still alive then.  Drop it as
+        soon as it is no longer read.  ``frozen=False`` returns the live
+        view instead, which the next mutation changes: for a read that is
+        done before then (validation between commits); it registers
+        nothing and copies nothing."""
         t0 = time.perf_counter()
         with self.tracer.span("rebuild", track="residual", site=site):
             view = self._view
-            cap = view["cap"]
-            if frozen:
-                cap = cap.copy()
-                self._shared.update(("bw", "lat"))
+            cap = view["cap"].copy() if frozen else view["cap"]
             rg = ResourceGraph(cap, view["bw"], view["lat"])
+            if frozen:
+                self._hold(rg)
         c = self.rebuilds.setdefault(site, [0, 0.0])
         c[0] += 1
         c[1] += 1e3 * (time.perf_counter() - t0)
@@ -208,7 +245,7 @@ class ResidualState:
         and force a full device re-upload on the next dispatch."""
         self.version += 1
         self.epoch += 1
-        self._dev = None
+        self._dev = self._upload = None
         self._node_delta.clear()
         self._edge_delta.clear()
         self.sync_stats["invalidations"] += 1
@@ -216,24 +253,30 @@ class ResidualState:
     # -- snapshot / restore -------------------------------------------------
 
     def snapshot(self) -> dict:
-        self._shared.update(("bw", "lat"))
+        """Copy of the float64 truth and liveness, with the view: its own
+        ``cap``, ``bw``/``lat`` shared copy-on-write for as long as the
+        snapshot's ``"view"`` lives."""
+        view = _SnapshotView(self._view, cap=self._view["cap"].copy())
+        self._hold(view)
         return {
             "cap": self.cap.copy(),
             "bw": self.bw.copy(),
             "node_up": self.node_up.copy(),
             "link_up": self.link_up.copy(),
-            "view": dict(self._view, cap=self._view["cap"].copy()),
+            "view": view,
         }
 
     def restore(self, snap: dict) -> None:
         """Roll back to a snapshot.  ``epoch`` advances (never rewinds): any
-        solve dispatched between snapshot and restore stays stale forever."""
+        solve dispatched between snapshot and restore stays stale forever.
+        The view takes the snapshot's arrays, which the snapshot keeps
+        sharing (it stays reusable): its registration as their holder
+        lasts as long as it lives."""
         self.cap = snap["cap"].copy()
         self.bw = snap["bw"].copy()
         self.node_up = snap["node_up"].copy()
         self.link_up = snap["link_up"].copy()
         self._view = dict(snap["view"], cap=snap["view"]["cap"].copy())
-        self._shared = {"bw", "lat"}  # the snapshot stays reusable
         self._invalidate()
 
     # -- device mirror ------------------------------------------------------
@@ -262,7 +305,9 @@ class ResidualState:
 
         Full upload when the cache was dropped (construction, liveness
         change, restore); otherwise one scatter-add per tensor over the
-        pending commit/release deltas."""
+        pending commit/release deltas.  A full upload keeps its frozen
+        handout for as long as the mirror lives, so the view is copied
+        before its first write instead of changing the uploaded array."""
         t0 = time.perf_counter()
         with self.tracer.span("sync", track="residual"):
             dev = self._sync()
@@ -273,7 +318,7 @@ class ResidualState:
         import jax.numpy as jnp  # deferred: numpy-only backends never touch jax
 
         if self._dev is None:
-            rg = self.residual_graph("upload")
+            rg = self._upload = self.residual_graph("upload")
             self._dev = dict(
                 cap=jnp.asarray(rg.cap),
                 bw=jnp.asarray(rg.bw),

@@ -1,10 +1,14 @@
 """The placer's float32 host view of the residual network: patched in place
 by commits, releases and liveness changes, bit-identical to a full rebuild
 after every mutation, and never changing under a graph already handed out."""
+import gc
+
 import numpy as np
 import pytest
 
 from repro.core import DataflowPath, ResourceGraph, random_dataflow, waxman
+from repro.core import leastcost
+from repro.core.problem import finite_lat
 from repro.core.residual import ResidualState
 from repro.obs import MetricsRegistry, absorb_residual_stats
 from repro.service import ControlPlane
@@ -96,6 +100,128 @@ def test_handed_out_graph_never_changes_and_back_to_back_commits_copy_nothing():
     assert res.sync_stats["cow_copies"] == copies
 
 
+@pytest.mark.parametrize("holder", ["handout", "snapshot"])
+def test_a_dropped_holder_lets_commits_write_the_view_in_place(holder):
+    base = waxman(16, seed=5)
+    res = ResidualState(base)
+    (u, v), (x, y) = list(base.edges())[:2]
+    load = ({u: 0.25, v: 0.5}, {(u, v): 1.5, (x, y): 0.75})
+    held = res.residual_graph() if holder == "handout" else res.snapshot()
+    del held
+    gc.collect()
+    copies = res.sync_stats["cow_copies"]
+    res.apply_load(*load, -1.0)  # commit
+    _assert_is_full_view(res, res.residual_graph(frozen=False))
+    res.apply_load(*load, 1.0)  # release
+    assert res.sync_stats["cow_copies"] == copies
+    assert res.sync_stats["cow_avoided"] == 1  # one copy saved, not two
+    _assert_is_full_view(res, res.residual_graph(frozen=False))
+
+
+def _assert_mirror_is_truth(res: ResidualState) -> None:
+    """Device tensors against float32 of the float64 truth with liveness
+    applied, within the drift of incremental float32 scatter-adds."""
+    dev = res.device_tensors()
+    full = res._full_view()
+    np.testing.assert_allclose(np.asarray(dev["cap"]), full["cap"],
+                               rtol=1e-6, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(dev["bw"]), full["bw"],
+                               rtol=1e-6, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(dev["lat"]),
+                                  finite_lat(ResourceGraph(**full)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_full_upload_is_not_changed_by_later_writes(seed):
+    """On the CPU ``jnp.asarray`` may alias a host array zero-copy: a view
+    written in place after a full upload would change the device tensor
+    and count every later delta twice.  Several upload cycles, each of a
+    freshly allocated view, so that some upload is aliased."""
+    rng = np.random.default_rng(seed)
+    base = waxman(256, seed=seed)
+    res = ResidualState(base)
+    edges = list(base.edges())
+    for cycle in range(8):
+        res.device_tensors()  # full upload
+        for _ in range(3):
+            load = _random_loads(rng, res, edges)
+            res.apply_load(*load, -1.0)
+            _assert_mirror_is_truth(res)
+            res.apply_load(*load, 1.0)
+        res.apply_load(*_random_loads(rng, res, edges), -1.0)
+        _assert_mirror_is_truth(res)
+        held = res.residual_graph()  # so the liveness change copies the view
+        v = int(rng.integers(0, base.n))
+        res.set_node_up(v, False)  # drops the mirror
+        res.set_node_up(v, True)
+        del held
+    assert res.sync_stats["full_uploads"] == 8
+
+
+@pytest.mark.parametrize("others", ["held", "dropped"])
+def test_a_held_snapshot_restores_bit_identically(others):
+    rng = np.random.default_rng(11)
+    base = waxman(48, seed=11)
+    res = ResidualState(base)
+    edges = list(base.edges())
+    res.device_tensors()
+    res.apply_load(*_random_loads(rng, res, edges), -1.0)
+    snap = res.snapshot()
+    want = {k: v.copy() for k, v in res._full_view().items()}
+    truth = (res.cap.copy(), res.bw.copy())
+    extra = []
+    for step in range(3):
+        for _ in range(5):
+            res.apply_load(*_random_loads(rng, res, edges), -1.0)
+        extra.append(res.snapshot())
+        res.restore(snap)
+        _assert_is_full_view(res, res.residual_graph(frozen=False))
+        assert np.array_equal(res.cap, truth[0])
+        assert np.array_equal(res.bw, truth[1])
+        for k, arr in want.items():
+            assert np.array_equal(snap["view"][k], arr), (step, k)
+            assert np.array_equal(res._view[k], arr), (step, k)
+        _assert_mirror_is_truth(res)
+        if others == "dropped":
+            extra.clear()
+            gc.collect()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_dispatched_graph_is_unchanged_until_reconstructed(depth, monkeypatch):
+    """Whatever the pipeline commits between a batch's dispatch and its
+    reconstruction, the batch walks the graph it was solved on."""
+    at_dispatch = {}
+    dispatch = leastcost.leastcost_jax_batched_dispatch
+    finalize = leastcost.leastcost_jax_batched_finalize
+
+    def recording_dispatch(rg, dfs, **kw):
+        pending = dispatch(rg, dfs, **kw)
+        at_dispatch[id(pending)] = {
+            k: getattr(rg, k).copy() for k in ("cap", "bw", "lat")}
+        return pending
+
+    def checking_finalize(pending, **kw):
+        for k, arr in at_dispatch.pop(id(pending)).items():
+            assert np.array_equal(getattr(pending.rg, k), arr), k
+        return finalize(pending, **kw)
+
+    monkeypatch.setattr(leastcost, "leastcost_jax_batched_dispatch",
+                        recording_dispatch)
+    monkeypatch.setattr(leastcost, "leastcost_jax_batched_finalize",
+                        checking_finalize)
+    rg, dfs = _light()
+    cp = ControlPlane(rg, micro_batch=2, pipeline_depth=depth, **JM)
+    cp.register_tenant("a")
+    for df in dfs:
+        cp.submit("a", df)
+    cp.pump(rounds=len(dfs))
+    cp.flush()
+    cp.check_invariants()
+    assert not at_dispatch
+    assert len(cp.placer.tickets) == len(dfs)
+
+
 def _contended() -> tuple[ResourceGraph, list[DataflowPath]]:
     """0 -> 2 via node 1 (latency 2) or node 3 (latency 4), capacity 1 on
     each: the first request fills node 1, so the second, dispatched before
@@ -148,10 +274,21 @@ def test_cold_pump_patches_the_view_and_registers_its_counters():
     assert cp.pump(rounds=1)
     assert stats["full_views"] == full_views
     assert stats["patched_entries"] > 0
-    assert stats["cow_copies"] > 0  # the dispatched graph is held
+    # depth 1: the classify and dispatched graphs are dropped before the
+    # commits, so the one copy is for the mirror's live upload handout
+    assert stats["full_uploads"] == 1
+    assert (stats["cow_copies"], stats["cow_avoided"]) == (1, 0)
+    for i in range(3):
+        cp.submit("a", random_dataflow(rg, 3, seed=60 + i,
+                                       creq_range=(0.05, 0.2),
+                                       breq_range=(0.5, 2.0)))
+    assert cp.pump(rounds=1)  # a second pump copies nothing
+    assert (stats["cow_copies"], stats["cow_avoided"]) == (1, 1)
     reg = absorb_residual_stats(MetricsRegistry(), cp.placer.res)
     assert reg.get("residual.patched_entries") == stats["patched_entries"]
     assert reg.get("residual.cow_copies") == stats["cow_copies"]
+    assert reg.get("residual.cow_avoided") == stats["cow_avoided"]
     assert reg.get("residual.full_views") == full_views
     assert cp.metrics_registry().get("residual.patched_entries") == (
         stats["patched_entries"])
+    assert cp.metrics_registry().get("residual.cow_avoided") == 1
