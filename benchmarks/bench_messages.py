@@ -198,11 +198,11 @@ def run_multi_hop(
     (max chain, multi-hop count) and the compacted solve sizes.
     """
     from repro.core import region_line
-    from repro.service import FairSharePolicy, RegionalControlPlane
+    from repro.service import ControlPlane, FairSharePolicy
 
     rg, assign = region_line(R, k, seed=seed)
-    cp = RegionalControlPlane(
-        rg, regions=R, region_of=assign, fanout=2, seed=seed,
+    cp = ControlPlane(
+        rg, region_of=assign, fanout=2, seed=seed,
         micro_batch=16, policy=FairSharePolicy(slack=0.4), method=method,
     )
     cp.register_tenant("gold", weight=3.0)
@@ -260,12 +260,12 @@ def run_multi_hop_hotspot(
       single-chain 2PC candidate budget.
     """
     from repro.core import region_grid
-    from repro.service import FairSharePolicy, RegionalControlPlane
+    from repro.service import ControlPlane, FairSharePolicy
 
     def _drive(ck, hot):
         rg, assign = region_grid(rows, cols, k, seed=seed)
-        cp = RegionalControlPlane(
-            rg, regions=rows * cols, region_of=assign, fanout=2,
+        cp = ControlPlane(
+            rg, region_of=assign, fanout=2,
             seed=seed, micro_batch=16, chain_k=ck,
             policy=FairSharePolicy(slack=0.4), method=method,
         )

@@ -151,11 +151,8 @@ def replay(make_plane, events, churn, *, rounds: int, warmup: int,
 
     cp.check_invariants()
     led = cp.conservation()
-    cr = cp.coordination_report()
-    if "children" in cr:  # hierarchical: totals aggregated over all levels
-        msgs = cr["gossip_messages_total"] + cr["twopc_messages_total"]
-    else:
-        msgs = cr["gossip_messages"] + cr["twopc_messages"]
+    cr = cp.coordination_report()  # totals aggregated over all levels
+    msgs = cr["gossip_messages_total"] + cr["twopc_messages_total"]
     # incremental-fast-path columns, summed over every per-region placer
     # through the plane's merged metrics registry (zero when disabled)
     reg = cp.metrics_registry()

@@ -93,8 +93,7 @@ def replay(cp, events, churn, rounds: int) -> list[dict]:
 
     for t in TENANTS:
         cp.register_tenant(t, weight=1.0)
-    leaves = ([p for _, p in cp.leaf_planes()] if hasattr(cp, "leaf_planes")
-              else [cp])
+    leaves = [p for _, p in cp.leaf_planes()]
     expiry: dict[int, int] = {}
     record = []
     for t in range(rounds):

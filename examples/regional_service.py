@@ -7,14 +7,16 @@ own **compacted** residual view — every regional DP solve runs over
 n_r = 4 nodes, never the global 24 (the view substrate of
 ``repro.core.compact``) — and fair shares are enforced from *gossiped
 estimates* of what every tenant holds elsewhere (no global lock,
-R * fanout messages per round).
+R * fanout messages per round).  The same broker nests: with
+``ControlPlane(rg, levels=L, branching=b, region_of=...)`` each region is
+itself a broker of regions, down to the region-local leaf planes.
 
 A dataflow pinned from region 0 to region 2 has no direct cut edge: it is
 decomposed over the multi-hop region chain 0 -> 1 -> 2 (one gateway-pinned
 segment per region, region 1 possibly pure transit) and placed by ONE
-bounded two-phase commit — previously such requests retried until
-dropped.  A middle cut-link failure partitions the chain — the spanning
-placement is displaced, queued, and re-admitted after the heal.
+bounded two-phase commit.  A middle cut-link failure partitions the
+chain — the spanning placement is displaced, queued, and re-admitted
+after the heal.
 
 Run:  PYTHONPATH=src python examples/regional_service.py
 """

@@ -264,10 +264,9 @@ class OnlinePlacer:
         revalidated against the float64 residual truth before any
         reserve, so it can never over-commit, and ``cache_enabled=False``
         is bit-identical to the pre-cache admission path (fuzz-enforced).
-        Both knobs ride ``**solve_cfg`` through
-        ``ControlPlane``/``RegionalControlPlane``/``HierarchicalControlPlane``
-        down to every per-region placer, whose caches operate entirely in
-        view-local ids.
+        Both knobs ride ``**solve_cfg`` through ``ControlPlane`` and
+        every level of ``RegionalControlPlane`` down to every per-region
+        placer, whose caches operate entirely in view-local ids.
 
         ``view`` (a :class:`~repro.core.compact.CompactedView`) makes this
         a *region-local* placer: ``rg`` may be the global graph — it is

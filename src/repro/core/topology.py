@@ -119,7 +119,7 @@ def region_line(
     a spanning chain through every region in between — the multi-hop
     decomposition scenario of the regional control plane.  Returns
     ``(graph, assign)`` where ``assign`` is the canonical node -> region
-    map (pass it as ``RegionalControlPlane(region_of=assign)`` to pin the
+    map (pass it as ``ControlPlane(rg, region_of=assign)`` to pin the
     partition to the topology).
     """
     assert R >= 1 and k >= 1 and 1 <= gateways <= k

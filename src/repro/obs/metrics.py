@@ -6,7 +6,7 @@ and the solve/overhead/conflict timing split all become *views over one
 registry*: each plane exposes ``metrics_registry()`` which absorbs its
 own surfaces into counters / gauges / histograms keyed by ``(name,
 labels)``, and parent planes **merge** their children's registries under
-a composed ``plane`` label (``"g0/r1"``) — mirroring the gossip
+a composed ``plane`` label (``"r0/r1"``) — mirroring the gossip
 aggregation structure, so a snapshot only ever contains what that plane
 can legitimately see.
 

@@ -11,35 +11,27 @@ front ends:
   defrag:       atomic global re-optimization of the standing ticket set
   gossip:       GossipBus — push-gossip of versioned per-region share
                 estimates (R * fanout messages per round)
-  regions:      RegionalControlPlane — R sharded planes over compacted
-                region-local subgraphs (core.compact views: every solve
-                sized n_r, not n), coordinated only by gossip + one
-                bounded 2PC per spanning dataflow over its multi-hop
-                region chain; constructed by ``ControlPlane(rg,
-                regions=R)``, bit-identical to the centralized plane at
-                R = 1
-  hierarchy:    HierarchicalControlPlane — regions of regions: per-level
-                brokers that translate ids only at their own boundary,
-                recursive spanning decomposition, tree-structured gossip
-                (O(branching * fanout) msgs/round per level); constructed
-                by ``ControlPlane(rg, levels=L, branching=b)``,
-                bit-identical to the flat regional plane at levels = 1
+  regions:      RegionalControlPlane — one recursive broker over R
+                regions, each a region-local ControlPlane over a compacted
+                subgraph (core.compact views: every solve sized n_r, not
+                n) or, nested ``levels`` deep, a broker of the same class;
+                coordinated only by gossip among siblings + one bounded
+                2PC per spanning dataflow over its multi-hop region chain;
+                constructed by ``ControlPlane(rg, regions=R)`` or
+                ``ControlPlane(rg, levels=L, branching=b)``, bit-identical
+                to the centralized plane at R = 1
 """
 from .controlplane import ControlPlane, Request, TenantState  # noqa: F401
 from .defrag import DefragResult, defrag, global_objective  # noqa: F401
 from .gossip import GossipBus, ShareRecord  # noqa: F401
-from .hierarchy import (  # noqa: F401
-    HierarchicalControlPlane,
-    resolve_nesting,
-)
 from .regions import (  # noqa: F401
-    ChainBroker,
     RegionalControlPlane,
     SpanPart,
     SpanningTicket,
     cut_edges,
     partition_regions,
     region_subgraph,
+    resolve_nesting,
     split_dataflow,
     split_dataflow_chain,
     validate_region_of,

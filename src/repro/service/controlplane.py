@@ -77,42 +77,34 @@ class TenantState:
 class ControlPlane:
     """Fair admission + preemption classes + background defrag.
 
-    ``ControlPlane(rg, regions=R)`` with ``R > 1`` constructs the
-    decentralized regional plane instead (``service.regions``): the network
-    is sharded into R regions, each with its own queues/residual/placer,
-    coordinated only by gossiped share estimates and a bounded two-phase
-    commit for region-spanning dataflows.  ``R = 1`` (the default) is this
+    ``ControlPlane(rg, regions=R)`` with ``R > 1`` (or ``region_of=``, or
+    ``levels=``) constructs the decentralized plane instead
+    (``service.regions``): the network is sharded into regions, each with
+    its own queues/residual/placer, coordinated only by gossiped share
+    estimates and a bounded two-phase commit for region-spanning
+    dataflows, nested ``levels`` deep.  ``R = 1`` (the default) is this
     centralized plane — the bit-identical degenerate case.
+
+    A plane built over a ``view`` is a *leaf* region of such a broker, and
+    serves it through the same reservation interface a broker serves its
+    own enclosing broker (``broker_admit`` / ``broker_release`` /
+    ``broker_uses_node`` / ``broker_uses_link`` / ``broker_holds``, and
+    the ``on_broker_displace`` hook).
     """
 
     def __new__(cls, rg=None, *args, regions: int = 1, **kwargs):
-        levels = kwargs.get("levels")
-        if levels is not None and int(levels) < 1:
-            raise ValueError(f"levels={levels} must be >= 1")
-        if cls is ControlPlane and levels is not None and int(levels) > 1:
-            from .hierarchy import HierarchicalControlPlane
-
-            # nested planes: levels >= 2 builds the hierarchy regardless of
-            # how the leaf partition is given (regions=, region_of=, or
-            # branching=); contradictions fail fast in resolve_nesting.
-            return HierarchicalControlPlane(
-                rg,
-                regions=int(regions) if int(regions) > 1 else None,
-                **kwargs,
-            )
-        regional = (
+        if cls is ControlPlane and (
             int(regions) > 1
             or kwargs.get("region_of") is not None
-            or levels is not None  # levels=1 asks for the flat regional plane
+            or kwargs.get("levels") is not None
             or kwargs.get("branching") is not None  # fails fast there
-        )
-        if cls is ControlPlane and regional:
+        ):
             from .regions import RegionalControlPlane
 
             # not a ControlPlane subclass, so __init__ below is not re-run.
             # A caller-pinned region_of alone implies the regional plane
-            # (its region count comes from the assignment); an explicit
-            # regions= is cross-checked against it there.
+            # (its region count comes from the assignment); contradicting
+            # nesting kwargs fail fast in resolve_nesting.
             return RegionalControlPlane(
                 rg,
                 regions=int(regions) if int(regions) > 1 else None,
@@ -196,10 +188,10 @@ class ControlPlane:
         self.active: dict[int, tuple[Request, Ticket]] = {}  # by rid
         self._rid_of_tid: dict[int, int] = {}
         self._rid = itertools.count()
-        # victims preempted here that this plane does not own (e.g. spanning
-        # segments reserved by the regional broker) are handed to this hook
-        # so their composite placements can be reconciled
-        self.on_foreign_preempt: Optional[callable] = None
+        # a broker reservation (broker_admit) this plane displaces — by
+        # preemption or churn — is reported as (tid, [old ticket]), so the
+        # broker can reconcile the composite placement it belongs to
+        self.on_broker_displace: Optional[callable] = None
         # called with the Request whenever this plane drops it (attempts
         # exhausted) — lets an owner of external rid maps (the regional
         # broker) forget its bookkeeping for terminal requests
@@ -360,9 +352,7 @@ class ControlPlane:
         )
         if ticket is None:
             return None
-        leftovers = self.preempt_reclaim(victims)
-        if leftovers and self.on_foreign_preempt is not None:
-            self.on_foreign_preempt(leftovers)
+        self._hand_over(self.preempt_reclaim(victims))
         self._activate(req, ticket)
         return ticket
 
@@ -508,9 +498,8 @@ class ControlPlane:
         # it to the owner BEFORE the rescue pass, so the broker can tear
         # down the rest of the composite placement instead of leaking its
         # sibling reservations (the partial-teardown regression)
-        foreign = [t for t in dropped if self._rid_of_tid.get(t.tid) is None]
-        if foreign and self.on_foreign_preempt is not None:
-            self.on_foreign_preempt(foreign)
+        self._hand_over(
+            [t for t in dropped if self._rid_of_tid.get(t.tid) is None])
         rescued: list[Ticket] = []
         requeued: list[Ticket] = []
         to_requeue: list[Request] = []
@@ -536,23 +525,133 @@ class ControlPlane:
         ]
         return alive, requeued
 
+    def _invalidate_view(self) -> None:
+        """A leaf region's slice of truth changed: bump its view's
+        generation (it propagates to the enclosing brokers' views)."""
+        if self.placer.view is not None:
+            self.placer.view.invalidate()
+
     def fail_node(self, v: int) -> tuple[list[Ticket], list[Ticket]]:
         """Take node ``v`` down.  Returns ``(alive, requeued)``: the
         tickets still active after re-mapping and preemptive rescue, and
         the old tickets of displaced requests now waiting in their tenant
         queues (see :meth:`_reconcile_churn`)."""
+        self._invalidate_view()
         return self._reconcile_churn(*self.placer.fail_node(v))
 
     def fail_link(self, u: int, v: int) -> tuple[list[Ticket], list[Ticket]]:
         """Take the (symmetric) link down; same contract as
         :meth:`fail_node`."""
+        self._invalidate_view()
         return self._reconcile_churn(*self.placer.fail_link(u, v))
 
     def restore_node(self, v: int) -> None:
+        self._invalidate_view()
         self.placer.restore_node(v)
 
     def restore_link(self, u: int, v: int) -> None:
+        self._invalidate_view()
         self.placer.restore_link(u, v)
+
+    # -- reservation interface to a broker ------------------------------------
+
+    def _hand_over(self, tickets: list[Ticket]) -> None:
+        """Report displaced tickets this plane holds no request for — the
+        broker's reservations — to the broker."""
+        if self.on_broker_displace is not None:
+            for t in tickets:
+                self.on_broker_displace(t.tid, [t])
+
+    def broker_admit(self, tenant: str, df: DataflowPath, *, klass: int = 0,
+                     preempt: bool = False) -> Optional[int]:
+        """Phase-1 reserve of a broker's segment ``df`` (in this plane's
+        ids) against this plane's residual; returns its tid, or None.  A
+        failed reserve is a 2PC probe, not a rejection, so the placer's
+        rejected counter is reconciled.  ``preempt`` escalates to budgeted
+        class preemption (the broker asks only for a candidate's last
+        segment): owned victims requeue here, other broker reservations
+        go to ``on_broker_displace``."""
+        victims: list[Ticket] = []
+        if preempt:
+            t, victims = self.placer.admit_preempting(
+                df, tenant=tenant, klass=klass,
+                max_displaced_cost=self.preempt_budget,
+            )
+        else:
+            t = self.placer.admit(df, tenant=tenant, klass=klass)
+        if t is None:
+            self.placer.stats.rejected -= 1
+        if victims:
+            self._hand_over(self.preempt_reclaim(victims))
+        return None if t is None else t.tid
+
+    def broker_release(self, tid: int, *, abort: bool = False) -> list[Ticket]:
+        """Release a :meth:`broker_admit` reservation (bookkeeping only;
+        an ``abort`` also takes back its admitted count, since the reserve
+        never served); returns ``[ticket]``, or ``[]`` if it is gone."""
+        tk = self.placer.tickets.get(tid)
+        if tk is None:
+            return []
+        self.placer.release(tid, reason=None)
+        if abort:
+            self.placer.stats.admitted -= 1
+        return [tk]
+
+    def broker_holds(self, tid: int, seg: DataflowPath) -> bool:
+        """Is ``tid`` a live reservation of exactly ``seg``?"""
+        tk = self.placer.tickets.get(tid)
+        return tk is not None and tk.df is seg
+
+    def broker_uses_node(self, tid: int, v: int) -> bool:
+        tk = self.placer.tickets.get(tid)
+        return tk is not None and v in tk.mapping.route
+
+    def broker_uses_link(self, tid: int, u: int, v: int) -> bool:
+        tk = self.placer.tickets.get(tid)
+        return tk is not None and (
+            (u, v) in tk.edge_load or (v, u) in tk.edge_load)
+
+    def ticket_live(self, t) -> bool:
+        return self.placer.tickets.get(getattr(t, "tid", -1)) is t
+
+    def held_capacity(self) -> dict[str, float]:
+        """Per-tenant compute of every ticket the placer holds — broker
+        reservations included, unlike :meth:`committed_capacity`."""
+        held = {t: 0.0 for t in self.tenants}
+        for tk in self.placer.tickets.values():
+            if tk.tenant in held:
+                held[tk.tenant] += float(np.sum(tk.df.creq))
+        return held
+
+    def residual_capacity(self) -> float:
+        """Summed live residual node capacity."""
+        pl = self.placer
+        return float(np.sum(np.where(pl.node_up, pl.cap, 0.0)))
+
+    def node_occupancy(self, v: int) -> float:
+        """Compute occupancy of node ``v`` in [0, 1] (1.0 when down)."""
+        pl = self.placer
+        if not pl.node_up[v]:
+            return 1.0
+        base = float(pl.base.cap[v])
+        if base <= 0.0:
+            return 0.0
+        return min(1.0, max(0.0, 1.0 - float(pl.cap[v]) / base))
+
+    def leaf_planes(self) -> list:
+        """``[(None, self)]``: this plane is its own only leaf, under no
+        further view."""
+        return [(None, self)]
+
+    def resident_state_report(self, peers: int = 0) -> dict:
+        """This plane's resident state: its ``n``-sized solve plus one
+        gossip record per sibling region (``peers``)."""
+        n = self.placer.base.n
+        return {
+            "components": [{"component": "plane", "solve_n": n,
+                            "peers": peers, "state": n + peers}],
+            "max_component_state": n + peers,
+        }
 
     # -- defragmentation ------------------------------------------------------
 

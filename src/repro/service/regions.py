@@ -1,55 +1,57 @@
-"""Decentralized regional control plane: sharded queues, gossiped shares,
-compacted region-local solves, and bounded two-phase commit for
-region-spanning dataflows decomposed over multi-hop region chains.
+"""Decentralized regional control plane: one recursive broker over sharded
+queues, gossiped shares, compacted region-local solves, and bounded
+two-phase commit for dataflows that span regions.
 
 The paper argues mapping should be computable *without* aggregating global
-network state at one node.  PR 3's :class:`ControlPlane` still held a
-global view; this module shards it.  ``ControlPlane(rg, regions=R)``
-builds a :class:`RegionalControlPlane`:
+network state at one node.  The centralized :class:`ControlPlane` holds a
+global view; :class:`RegionalControlPlane` shards it, recursively::
 
-- the network is partitioned into R balanced, BFS-grown regions
-  (:func:`partition_regions`, or a caller-pinned ``region_of``
-  assignment); each region owns a full centralized :class:`ControlPlane`
-  over its **compacted** subgraph: a
-  :class:`~repro.core.compact.CompactedView` remaps the region's nodes
-  onto the contiguous local id space ``[0, n_r)``, so every piece of
-  regional state — residual arrays, liveness masks, tickets, DP state,
-  kernel tiles — is sized ``n_r``, not the global ``n``.  R regions are
-  R x smaller solves, not just R x smaller mailboxes.  Composition makes
-  ``R = 1`` the *bit-identical* degenerate case: the identity view
-  translates by returning its inputs unchanged, so one region runs the
-  centralized plane's exact objects.
+    RegionalControlPlane (levels=L, branching=b)
+      ids: its own network's — but only at its boundary (cut gateways)
+      owns: a cut ledger, spanning queues, one GossipBus over its R regions
+        |
+        +-- region r in 0..R-1: a CompactedView of r's nodes, and under it
+            a plane in the view's LOCAL ids [0, n_r): a region-local
+            ControlPlane at levels=1 (the leaves), else a broker of this
+            same class with levels L-1 — regions of regions
+
+- ``ControlPlane(rg, regions=R)`` (or ``region_of=``) builds the one-level
+  broker: the network is partitioned into R balanced, BFS-grown regions
+  (:func:`partition_regions`, or a caller-pinned ``region_of``) and each
+  region owns a full centralized :class:`ControlPlane` over its
+  **compacted** subgraph, so every piece of regional state — residual
+  arrays, liveness masks, tickets, DP state, kernel tiles — is sized
+  ``n_r``, not the global ``n``.  ``R = 1`` is the *bit-identical*
+  degenerate case: the identity view translates by returning its inputs.
+  ``ControlPlane(rg, levels=L, branching=b)`` nests brokers so that no
+  component holds more than O(branching + n_leaf) state.
 - regions never read each other's live accounting.  A
-  :class:`~repro.service.gossip.GossipBus` spreads versioned per-tenant
-  committed-share / residual estimates on a configurable fanout & period
-  (``R * fanout`` messages per round, independent of node count) and each
+  :class:`~repro.service.gossip.GossipBus` per broker spreads versioned
+  per-tenant committed-share / residual / gateway-occupancy estimates
+  among its R regions (``R * fanout`` messages per round), and each
   region's fair-share drain runs against *local truth + gossiped
-  estimates* (``ControlPlane.pump(extra_committed=...)``).  Stale
-  estimates can only skew drain order — admission always validates
-  against the region's own residual, so capacity is never over-committed
-  (property-tested with maximally stale gossip in ``tests/test_regions``).
-- a request whose endpoints live in different regions is decomposed over
-  a **region chain**: the fewest-hop path from the source region to the
-  destination region over the quotient graph of regions (edges = alive
-  cut links), possibly through intermediate regions.  The dataflow is cut
-  at one edge per hop (:func:`split_dataflow_chain`) into one
-  gateway-pinned segment per region on the chain; the broker tries at
-  most ``max_cut_attempts`` (splits, cut-edges) candidates — splits
-  ordered by compute balance across the segments, cuts by latency — and
-  places each candidate with ONE bounded two-phase commit: reserve every
-  segment in its region (the single blocker may escalate to budgeted
-  class preemption, only as the candidate's *last* reservation), reserve
-  every cut's bandwidth, then commit — or roll every reservation back.
-  A candidate costs at most ``2 * len(chain) + 2`` messages; 2PC traffic
-  is counted in ``Stats.twopc_messages``, gossip in
-  ``Stats.gossip_messages``.
+  estimates*, with an enclosing broker's estimate pushed down through
+  ``pump(extra_committed=...)``.  Stale estimates can only skew drain
+  order; admission always validates against the region's own residual.
+- a request whose endpoints lie in different regions is decomposed over a
+  **region chain** of the quotient graph (regions joined by alive cut
+  links), cut at one edge per hop (:func:`split_dataflow_chain`) into one
+  gateway-pinned segment per region.  The broker tries at most
+  ``max_cut_attempts`` (splits, cut-edges) candidates — over the fewest-hop
+  chain (``chain_k == 1``) or raced over Yen k-shortest chains priced by
+  congestion (``chain_k > 1``) — and places each candidate with ONE
+  bounded two-phase commit: each segment's phase-1 reserve is its region's
+  ``broker_admit`` (a leaf reserves on its placer; a broker region may
+  split the segment again at its own cuts), then the cut bandwidth, then
+  commit — or every reservation rolls back.  Only a leaf accepts a
+  preempting reserve, and only for a candidate's *last* segment.  A
+  candidate costs at most ``2 * len(chain) + 2`` messages.
 
-The broker is the only holder of global node ids: regional tickets live
-in their region's local id space, and every spanning reservation is
-recorded as a :class:`SpanPart` — ``(region, tid, local segment,
-bijection version)`` — so a handle minted under a stale view generation
-is detectable.  Cross-region (cut) links belong to no region; their
-bandwidth is the broker's own conservation ledger.
+Each broker holds ids of exactly two kinds: its own boundary gateways (the
+cut ledger) and opaque region handles.  Every spanning reservation is a
+:class:`SpanPart` — ``(region, handle, local segment, view version)`` —
+and cross-region (cut) links belong to no region: their bandwidth is the
+broker's own ledger.
 """
 from __future__ import annotations
 
@@ -160,6 +162,56 @@ def validate_region_of(rg: ResourceGraph, region_of) -> np.ndarray:
     return assign
 
 
+def resolve_nesting(levels, branching, regions, detected_leaves=None):
+    """Fail-fast resolution of the nesting kwargs into
+    ``(levels, branching, leaf_regions)``.  Contradictory combinations
+    raise with a clear message instead of silently building some other
+    plane (mirrors the flat plane's ``regions=`` vs ``region_of=``
+    contradiction check)."""
+    levels = int(levels)
+    if levels < 1:
+        raise ValueError(f"levels={levels} must be >= 1")
+    leaves = detected_leaves
+    if regions is not None:
+        if leaves is not None and int(regions) != leaves:
+            raise ValueError(
+                f"regions={regions} contradicts region_of, which defines "
+                f"{leaves} regions"
+            )
+        leaves = int(regions)
+    if levels == 1:
+        if branching is not None and leaves is not None \
+                and int(branching) != leaves:
+            raise ValueError(
+                f"branching={branching} contradicts {leaves} leaf regions "
+                "at levels=1 (a 1-level plane has branching == regions)"
+            )
+        if leaves is None:
+            leaves = int(branching) if branching is not None else 2
+        return levels, leaves, leaves
+    if branching is None:
+        if leaves is None:
+            branching = 2
+        else:
+            branching = round(leaves ** (1.0 / levels))
+            if branching**levels != leaves:
+                raise ValueError(
+                    f"regions={leaves} is not a perfect levels={levels} "
+                    "power; pass branching= explicitly (leaf regions = "
+                    "branching ** levels)"
+                )
+    branching = int(branching)
+    if branching < 1:
+        raise ValueError(f"branching={branching} must be >= 1")
+    if leaves is not None and branching**levels != leaves:
+        raise ValueError(
+            f"regions={leaves} contradicts levels={levels} x "
+            f"branching={branching} (expected {branching ** levels} "
+            "leaf regions)"
+        )
+    return levels, branching, branching**levels
+
+
 def region_subgraph(rg: ResourceGraph, assign: np.ndarray, r: int) -> ResourceGraph:
     """The subgraph region ``r`` owns, in the *global* id space:
     out-of-region nodes keep their ids but lose all capacity and links.
@@ -268,9 +320,10 @@ def split_dataflow(
 @dataclasses.dataclass(frozen=True)
 class SpanPart:
     """One reserved segment of a spanning placement: the owning region,
-    the region-local ticket id, the *local-id* segment object the
-    region's ticket holds (identity-checked by the invariants), and the
-    region view's bijection version at reserve time — a part minted under
+    the region's reservation handle (a leaf's ticket id, a broker
+    region's rid), the *local-id* segment object the reservation holds
+    (identity-checked by the invariants), and the region view's
+    bijection version at reserve time — a part minted under
     an older generation than the view's current one is a churn survivor,
     and one minted under a newer-than-current version is a bug."""
 
@@ -326,40 +379,151 @@ class SpanningTicket:
         return self.splits[0]
 
 
-class ChainBroker:
-    """Cut-edge ledger + quotient-graph chain selection, shared by every
-    plane that brokers spanning placements over child partitions: the flat
-    :class:`RegionalControlPlane` over its regions, and the
-    :class:`~repro.service.hierarchy.HierarchicalControlPlane` over its
-    child planes.
+class RegionalControlPlane:
+    """A broker over R regions, each a region-local :class:`ControlPlane`
+    (``levels == 1``) or a broker of this class (``levels >= 2``).
 
-    Subclasses provide ``base`` (the network in THIS plane's id space),
-    ``region_of`` (node -> child index), ``node_up`` and
-    ``max_cut_attempts`` before calling :meth:`_init_cut_ledger`.  The
-    broker's resident state is deliberately small: the cut ledger holds
-    only the *boundary* gateway ids plus the quotient graph over direct
-    children — never the full membership of any child."""
+    Mirrors the centralized :class:`ControlPlane` surface (register_tenant
+    / submit / pump / release / fail_* / restore_* / defrag /
+    committed_capacity / conservation / fairness_report / engine_stats /
+    check_invariants / active_ids), so call sites are plane-agnostic, and
+    exposes the reservation interface every region exposes to its broker
+    (``broker_admit`` / ``broker_release`` / ``broker_uses_node`` /
+    ``broker_uses_link`` / ``broker_holds`` and the ``on_broker_displace``
+    hook), so brokers nest to any depth.  ``pump`` returns a mix of
+    :class:`Ticket` (in one leaf, in that leaf's *local* ids — lift them
+    through :meth:`owner_region` and ``plane.views[r]``) and
+    :class:`SpanningTicket` handles; ``defrag`` returns one
+    :class:`~repro.service.defrag.DefragResult` per leaf region — there is
+    no global re-solve, by design.
 
-    base: ResourceGraph
-    region_of: np.ndarray
-    node_up: np.ndarray
-    max_cut_attempts: int
-    chain_k: int
-    congestion_weight: float
-    max_cum_attempts: int
+    ``**solve_cfg`` (including ``cache_enabled`` / ``cache_size`` /
+    ``max_correction_supersteps``) reaches every leaf placer, whose
+    solution cache works on view-local signatures against its own residual
+    truth, so no cross-region coherence is needed.
+    """
 
-    def _init_cut_ledger(self) -> None:
-        """Build the cut-edge bandwidth ledger: cut links belong to no
-        child (they are outside every compacted submatrix), so this ledger
-        is their only accounting, reserved/released by the plane's 2PC."""
+    def __init__(
+        self,
+        rg: ResourceGraph,
+        *,
+        regions: Optional[int] = None,
+        region_of=None,
+        levels: Optional[int] = None,
+        branching: Optional[int] = None,
+        policy: Optional[FairSharePolicy] = None,
+        micro_batch: int = 32,
+        max_attempts: int = 8,
+        preempt: bool = True,
+        preempt_budget: Optional[float] = None,
+        pipeline_depth: int = 1,
+        method: str = "leastcost_jax",
+        use_kernel: bool = False,
+        fanout: int = 2,
+        gossip_period: int = 1,
+        max_cut_attempts: int = 4,
+        chain_k: int = 2,
+        congestion_weight: float = 1.0,
+        max_cum_attempts: Optional[int] = None,
+        seed: int = 0,
+        tracer=None,
+        **solve_cfg,
+    ):
+        self.base = rg
+        if branching is not None and levels in (None, 1):
+            raise ValueError(
+                f"branching={branching} requires a hierarchical plane "
+                "(levels >= 2); the flat plane takes regions= or region_of="
+            )
+        assign = None if region_of is None else validate_region_of(rg, region_of)
+        self.levels, self.branching, _ = resolve_nesting(
+            1 if levels is None else levels, branching, regions,
+            detected_leaves=None if assign is None else int(assign.max()) + 1,
+        )
+        # leaves per region; region_of maps node -> direct region
+        sub = self.branching ** (self.levels - 1)
+        if assign is not None:
+            self.region_of = assign // sub
+        else:
+            self.region_of = partition_regions(rg, self.branching, seed=seed)
+        self.R = int(self.region_of.max()) + 1
+        self.policy = policy or FairSharePolicy()
+        self.micro_batch = int(micro_batch)
+        self.max_attempts = int(max_attempts)
+        self.preempt = bool(preempt)
+        self.preempt_budget = preempt_budget
+        self.method = method
+        self.max_cut_attempts = int(max_cut_attempts)
+        # chain_k > 1 races k-shortest region chains under the load-aware
+        # cost; chain_k == 1 is the single fewest-hop chain
+        self.chain_k = max(1, int(chain_k))
+        self.congestion_weight = float(congestion_weight)
+        # lifetime attempt budget across displacement episodes: a request
+        # ping-ponging between admission and displacement resets its
+        # per-episode attempts but never this one (livelock backstop)
+        self.max_cum_attempts = (
+            4 * self.max_attempts if max_cum_attempts is None
+            else int(max_cum_attempts)
+        )
+        self.gossip_period = max(1, int(gossip_period))
+        # each region gets a scoped view of this tracer ("r{r}/" prefixes),
+        # so flow ids and track names nest the way the planes do
+        self.tracer = tracer if tracer is not None else obs_trace.NULL
+        # the compacted solve substrate: one bijection per region
+        self.views = [
+            CompactedView.from_assign(rg, self.region_of, r)
+            for r in range(self.R)
+        ]
+        kw = dict(policy=self.policy, micro_batch=micro_batch,
+                  max_attempts=max_attempts, preempt=preempt,
+                  preempt_budget=preempt_budget,
+                  pipeline_depth=pipeline_depth, method=method,
+                  use_kernel=use_kernel, **solve_cfg)
+        if self.levels == 1:
+            self.regions: list = [
+                ControlPlane(rg, view=view, tracer=self.tracer.scoped(f"r{r}"),
+                             **kw)
+                for r, view in enumerate(self.views)
+            ]
+        else:
+            self.regions = []
+            for r, view in enumerate(self.views):
+                child = RegionalControlPlane(
+                    view.graph(), levels=self.levels - 1, regions=sub,
+                    region_of=(None if assign is None
+                               else assign[view.nodes] - r * sub),
+                    fanout=fanout, gossip_period=gossip_period,
+                    max_cut_attempts=max_cut_attempts, chain_k=chain_k,
+                    congestion_weight=congestion_weight,
+                    max_cum_attempts=max_cum_attempts,
+                    seed=seed + 1000 * (r + 1),
+                    tracer=self.tracer.scoped(f"r{r}"), **kw,
+                )
+                # a leaf churn's invalidate() propagates up to this
+                # level's view (and an invalidation here cascades down)
+                for cv in child.views:
+                    view.adopt(cv)
+                self.regions.append(child)
+        for r, child in enumerate(self.regions):
+            child.on_broker_displace = (
+                lambda h, old, r=r: self._child_displaced(r, h, old))
+            child.on_drop = lambda req, r=r: self._forget_local(r, req)
+        self.leaf_regions = len(self.leaf_planes())
+        self.bus = GossipBus(self.R, fanout=fanout,
+                             seed=seed + (1 if self.levels == 1 else 7))
+        self.node_up = np.ones(rg.n, bool)
+
+        # cut-edge bandwidth ledger: cut links belong to no region (they
+        # are outside every compacted submatrix), so this ledger is their
+        # only accounting, reserved/released by the broker's 2PC
         self.cut_base: dict[tuple[int, int], float] = {}
         self.cut_residual: dict[tuple[int, int], float] = {}
         self.cut_link_up: dict[tuple[int, int], bool] = {}
         self._cut_by_pair: dict[tuple[int, int], list[tuple[int, int]]] = {}
         self._gateways_of: dict[int, list[int]] = {}
-        for (u, v) in cut_edges(self.base, self.region_of):
-            self.cut_base[(u, v)] = float(self.base.bw[u, v])
-            self.cut_residual[(u, v)] = float(self.base.bw[u, v])
+        for (u, v) in cut_edges(rg, self.region_of):
+            self.cut_base[(u, v)] = float(rg.bw[u, v])
+            self.cut_residual[(u, v)] = float(rg.bw[u, v])
             self.cut_link_up[(u, v)] = True
             self._cut_by_pair.setdefault(
                 (int(self.region_of[u]), int(self.region_of[v])), []
@@ -369,6 +533,418 @@ class ChainBroker:
                 gws.append(u)
         for gws in self._gateways_of.values():
             gws.sort()
+
+        # spanning-request bookkeeping (the broker's ledger)
+        self.span_tenants: dict[str, TenantState] = {}
+        self._span_q: list[dict[str, collections.deque]] = [
+            {} for _ in range(self.R)
+        ]
+        self._span_active: dict[int, SpanningTicket] = {}
+        self._part_of: dict[tuple[int, int], int] = {}  # (region, h) -> rid
+        # rid space over both in-region and spanning requests
+        self._rid = itertools.count()
+        self._local: dict[int, tuple[int, int]] = {}  # rid -> (region, lrid)
+        self._grid_of: dict[tuple[int, int], int] = {}  # (region, lrid) -> rid
+        self._pumps = 0
+        self._twopc_msgs = 0
+        # while a churn call (fail_node/fail_link) is reconciling, spanning
+        # placements torn down by in-region rescue preemptions collect here
+        # so the churn return contract covers them too
+        self._churn_collector: Optional[list] = None
+        # reservations held by an enclosing broker's 2PC (broker_admit):
+        # their lifecycle belongs to that broker — a displacement fires
+        # on_broker_displace instead of requeueing here, and they are not
+        # caller-visible active requests
+        self._broker_held: set[int] = set()
+        self.on_broker_displace = None  # enclosing broker: (rid, old) -> None
+        self.on_drop = None  # called with each Request this plane drops
+        self.span_stats = {
+            "attempts": 0, "admitted": 0, "dropped": 0,
+            "displaced": 0, "no_cut": 0,
+            "multi_hop": 0,  # admitted over chains of >= 3 regions
+            "max_chain": 0,  # longest admitted region chain
+            "broker_local": 0,  # enclosing-broker reservations in one region
+            "rerouted": 0,  # admitted via a non-fewest-hop chain
+            "livelock_dropped": 0,  # dropped by the cumulative budget
+            "max_req_attempts": 0,  # highest lifetime attempts on one req
+        }
+
+    # -- registration / submission ------------------------------------------
+
+    def register_tenant(
+        self, name: str, *, weight: float = 1.0,
+        budget: Optional[float] = None,
+    ) -> TenantConfig:
+        if name in self.span_tenants:
+            raise ValueError(f"tenant {name!r} already registered")
+        cfg = TenantConfig(name, weight=weight, budget=budget)
+        for child in self.regions:
+            child.register_tenant(name, weight=weight, budget=budget)
+        self.span_tenants[name] = TenantState(cfg)
+        for q in self._span_q:
+            q[name] = collections.deque()
+        return cfg
+
+    def submit(self, tenant: str, df: DataflowPath, *, klass: int = 0) -> int:
+        """Queue a request with its *home* (source) region: one whose
+        endpoints lie in one region is compacted into that region's local
+        id space and submitted there (a broker region may still split it
+        across ITS regions); one crossing this broker's cuts queues on the
+        home region's broker side and is placed by 2PC at pump time.
+        Returns an rid of this plane."""
+        st = self.span_tenants[tenant]  # KeyError for unregistered
+        rid = next(self._rid)
+        ra = int(self.region_of[df.src])
+        rb = int(self.region_of[df.dst])
+        if ra == rb:
+            lrid = self.regions[ra].submit(
+                tenant, self.views[ra].compact_df(df), klass=klass
+            )
+            self._local[rid] = (ra, lrid)
+            self._grid_of[(ra, lrid)] = rid
+        else:
+            st.submitted += 1
+            ControlPlane._enqueue(
+                self._span_q[ra][tenant], Request(rid, tenant, df, klass=klass)
+            )
+            if self.tracer.enabled:
+                self.tracer.flow_begin(
+                    rid, "submit", tenant=tenant, klass=klass,
+                    spanning=True, home=ra,
+                )
+        return rid
+
+    # -- live accounting -----------------------------------------------------
+
+    def committed_capacity(self) -> dict[str, float]:
+        """Per-tenant compute held anywhere under this broker, from the
+        leaf placers' tickets (spanning segments included)."""
+        held = {t: 0.0 for t in self.span_tenants}
+        for child in self.regions:
+            for t, c in child.held_capacity().items():
+                held[t] = held.get(t, 0.0) + c
+        return held
+
+    held_capacity = committed_capacity
+
+    def residual_capacity(self) -> float:
+        """Summed live residual node capacity of every leaf region (the
+        scalar this plane publishes into its enclosing broker's gossip)."""
+        return float(sum(c.residual_capacity() for c in self.regions))
+
+    def queued_demand(self) -> dict[str, float]:
+        out = {t: 0.0 for t in self.span_tenants}
+        for child in self.regions:
+            for t, c in child.queued_demand().items():
+                out[t] = out.get(t, 0.0) + c
+        for q in self._span_q:
+            for t, dq in q.items():
+                out[t] += sum(r.creq_sum for r in dq)
+        return out
+
+    def owner_region(self, ticket: Ticket) -> Optional[int]:
+        """The region holding ``ticket`` (by object identity — tids are
+        per-leaf counters and collide across regions).  Use it to pick the
+        ``plane.views[r]`` that lifts an in-region handle to this plane's
+        ids."""
+        for r, child in enumerate(self.regions):
+            if child.ticket_live(ticket):
+                return r
+        return None
+
+    def active_ids(self) -> list[int]:
+        """Rids of active requests in every region + spanning.  Reservations
+        held for an enclosing broker are excluded — they are segments of a
+        composite that broker accounts for."""
+        out = [
+            self._grid_of[(r, h)]
+            for r, child in enumerate(self.regions)
+            for h in child.active_ids()
+            if (r, h) in self._grid_of
+        ]
+        out += [rid for rid in self._span_active if rid not in self._broker_held]
+        return sorted(out)
+
+    def ticket_live(self, t) -> bool:
+        """Is a handle returned by :meth:`pump` still standing?  (A later
+        round — or an enclosing broker's 2PC — may have displaced it.)"""
+        if self._span_active.get(getattr(t, "rid", -1)) is t:
+            return True
+        return any(child.ticket_live(t) for child in self.regions)
+
+    def conservation(self) -> dict[str, int]:
+        """The regions' ledgers + this broker's spanning ledger.  ``ok``
+        iff every submitted request is in exactly one state *summed over
+        regions*; a reservation held for an enclosing broker counts in
+        both ledgers, and each balances on its own."""
+        agg = {"submitted": 0, "queued": 0, "in_flight": 0, "active": 0,
+               "released": 0, "dropped": 0}
+        for child in self.regions:
+            led = child.conservation()
+            for k in agg:
+                agg[k] += led[k]
+        agg["submitted"] += sum(
+            st.submitted for st in self.span_tenants.values())
+        agg["queued"] += sum(
+            len(dq) for q in self._span_q for dq in q.values())
+        agg["active"] += len(self._span_active)
+        agg["released"] += sum(
+            st.released for st in self.span_tenants.values())
+        agg["dropped"] += sum(
+            st.dropped for st in self.span_tenants.values())
+        agg["ok"] = agg["submitted"] == (
+            agg["queued"] + agg["in_flight"] + agg["active"]
+            + agg["released"] + agg["dropped"]
+        )
+        return agg
+
+    # -- gossip --------------------------------------------------------------
+
+    def node_occupancy(self, v: int) -> float:
+        """Compute occupancy of node ``v`` in [0, 1] (1.0 when down), read
+        from the leaf placer that holds it."""
+        if not self.node_up[v]:
+            return 1.0
+        r = int(self.region_of[v])
+        return self.regions[r].node_occupancy(int(self.views[r].to_local(v)))
+
+    def _publish(self, r: int) -> None:
+        """Publish region r's aggregated accounting into this broker's bus,
+        with the occupancy of its gateways as the per-cut congestion
+        estimate."""
+        child = self.regions[r]
+        queued = child.queued_demand()
+        for t, dq in self._span_q[r].items():
+            queued[t] = queued.get(t, 0.0) + sum(x.creq_sum for x in dq)
+        self.bus.publish(
+            r, child.held_capacity(), queued, child.residual_capacity(),
+            congestion={u: self.node_occupancy(u)
+                        for u in self._gateways_of.get(r, ())},
+        )
+
+    # -- admission -----------------------------------------------------------
+
+    def pump(self, *, rounds: int = 1, extra_committed=None) -> list:
+        """One decentralized drain round per ``rounds``: publish + gossip
+        share estimates, drain every region's queues under estimated
+        global fair shares, then place queued spanning requests by bounded
+        2PC.  Returns the still-live admitted handles.
+
+        ``extra_committed`` is an enclosing broker's estimate of per-tenant
+        holdings *outside this plane* (the gossip downlink); it folds into
+        every region's drain as gossiped sibling estimates do — advisory
+        for drain order, never capacity."""
+        admitted: list = []
+        spanned: list[SpanningTicket] = []
+        for _ in range(int(rounds)):
+            self._pumps += 1
+            for r in range(self.R):
+                self._publish(r)
+            if self.R > 1 and self._pumps % self.gossip_period == 0:
+                with self.tracer.span("gossip.round", track="gossip",
+                                      cat="gossip", round=self._pumps):
+                    self.bus.tick()
+            for r, child in enumerate(self.regions):
+                extra: dict[str, float] = dict(extra_committed or {})
+                if self.R > 1:
+                    # gossiped estimate of remote holdings
+                    for t, c in self.bus.remote_committed(r).items():
+                        extra[t] = extra.get(t, 0.0) + c
+                if self.R > 1 and self.levels == 1:
+                    # plus what a leaf holds for brokers: spanning segments
+                    # are placer tickets but not the leaf's own requests,
+                    # so its accounting cannot see them (a broker region's
+                    # accounting counts its reservations itself)
+                    own = child.committed_capacity()
+                    for t, c in child.held_capacity().items():
+                        diff = c - own.get(t, 0.0)
+                        if diff > _EPS:
+                            extra[t] = extra.get(t, 0.0) + diff
+                admitted += child.pump(rounds=1, extra_committed=extra or None)
+            spanned += self._pump_spanning(extra_committed)
+        live = [t for t in admitted if self.ticket_live(t)]
+        live += [s for s in spanned if s.rid in self._span_active]
+        return live
+
+    def flush(self) -> list:
+        """Commit every leaf's in-flight pipeline windows (barrier); see
+        :meth:`ControlPlane.flush`.  The 2PC needs no flush of its own: it
+        reserves host-side, and an in-flight batch that loses capacity to
+        a reservation re-solves its conflicts at commit."""
+        admitted: list = []
+        for child in self.regions:
+            admitted += child.flush()
+        return [t for t in admitted if self.ticket_live(t)]
+
+    def warmup(self, *, max_batch: Optional[int] = None, p: int = 5) -> int:
+        """Pre-compile every leaf's jit buckets (their ``n_r`` differ)."""
+        return max(
+            (c.warmup(max_batch=max_batch, p=p) for c in self.regions),
+            default=0,
+        )
+
+    def _pump_spanning(self, extra_committed=None) -> list[SpanningTicket]:
+        if self.R <= 1:
+            return []
+        out: list[SpanningTicket] = []
+        cfgs = {t: st.cfg for t, st in self.span_tenants.items()}
+        for r in range(self.R):
+            queues = self._span_q[r]
+            if not any(queues.values()):
+                continue
+            committed = self.regions[r].held_capacity()
+            for t, c in self.bus.remote_committed(r).items():
+                if t in committed:
+                    committed[t] += c
+            for t, c in (extra_committed or {}).items():
+                if t in committed:
+                    committed[t] += c
+            picked = self.policy.select(
+                cfgs, queues, committed, self.micro_batch
+            )
+            # pop every selected head BEFORE placing: a 2PC attempt may
+            # displace another spanning request to the front of one of
+            # these very queues, which must not disturb the drain order
+            for req in picked:
+                q = queues[req.tenant]
+                assert q[0] is req, "policy must select queue heads in order"
+                q.popleft()
+            for req in picked:
+                q = queues[req.tenant]
+                st = self._try_place_spanning(req)
+                if st is not None:
+                    self.span_tenants[req.tenant].admitted += 1
+                    if self.tracer.enabled:
+                        self.tracer.flow_point(
+                            req.rid, "admit", chain=len(st.parts))
+                    out.append(st)
+                else:
+                    req.attempts += 1
+                    req.cum_attempts += 1
+                    self.span_stats["max_req_attempts"] = max(
+                        self.span_stats["max_req_attempts"], req.cum_attempts)
+                    exhausted = req.attempts >= self.max_attempts
+                    livelocked = req.cum_attempts >= self.max_cum_attempts
+                    if exhausted or livelocked:
+                        self.span_tenants[req.tenant].dropped += 1
+                        self.span_stats["dropped"] += 1
+                        if livelocked and not exhausted:
+                            self.span_stats["livelock_dropped"] += 1
+                        if self.tracer.enabled:
+                            self.tracer.flow_end(
+                                req.rid, "drop", outcome="dropped",
+                                attempts=req.attempts,
+                                cum_attempts=req.cum_attempts,
+                            )
+                        if self.on_drop is not None:
+                            self.on_drop(req)
+                    else:
+                        ControlPlane._enqueue(q, req, front_of_class=True)
+        return out
+
+    def _requeue_or_livelock_drop(self, st: SpanningTicket) -> None:
+        """Requeue a displaced spanning request at its home region — or
+        drop it when its *cumulative* attempt budget is spent.  The
+        per-episode ``attempts`` resets (displacement is not the
+        request's fault) but ``cum_attempts`` never does: a request
+        ping-ponging between a saturated chain and displacement meets
+        ``max_cum_attempts`` instead of livelocking forever."""
+        st.req.attempts = 0
+        st.req.cum_attempts += 1
+        self.span_stats["max_req_attempts"] = max(
+            self.span_stats["max_req_attempts"], st.req.cum_attempts)
+        if st.req.cum_attempts >= self.max_cum_attempts:
+            self.span_tenants[st.tenant].dropped += 1
+            self.span_stats["dropped"] += 1
+            self.span_stats["livelock_dropped"] += 1
+            if self.tracer.enabled:
+                self.tracer.flow_end(
+                    st.rid, "drop", outcome="livelock",
+                    cum_attempts=st.req.cum_attempts,
+                )
+            if self.on_drop is not None:
+                self.on_drop(st.req)
+            return
+        home = int(self.region_of[st.df.src])
+        ControlPlane._enqueue(
+            self._span_q[home][st.tenant], st.req, front_of_class=True
+        )
+
+    # -- reservation interface to an enclosing broker -------------------------
+
+    def broker_admit(self, tenant: str, df: DataflowPath, *,
+                     klass: int = 0) -> Optional[int]:
+        """Synchronous, abortable phase-1 reserve for an enclosing
+        broker's 2PC: place ``df`` (in THIS plane's ids) now — in one
+        region, or across this plane's own cuts (the recursion that lets a
+        segment split again one level down).
+
+        Returns a handle for :meth:`broker_release`, or None (nothing
+        reserved).  The reservation is a spanning entry of this plane's
+        ledger, so conservation and invariants hold at every level; if
+        churn or preemption here later displaces it,
+        ``on_broker_displace`` fires instead of a local requeue.  Never
+        escalates to preemption: only a leaf does that."""
+        st = self.span_tenants[tenant]  # KeyError for unregistered
+        rid = next(self._rid)
+        req = Request(rid, tenant, df, klass=klass)
+        ra = int(self.region_of[df.src])
+        rb = int(self.region_of[df.dst])
+        if ra == rb:
+            lseg = self.views[ra].compact_df(df)
+            h = self.regions[ra].broker_admit(tenant, lseg, klass=klass)
+            if h is None:
+                return None
+            self.span_stats["broker_local"] += 1
+            span = SpanningTicket(
+                rid=rid, req=req,
+                parts=[SpanPart(ra, h, lseg, self.views[ra].version)],
+                cuts=[], cut_bws=[], splits=[],
+            )
+            self._span_active[rid] = span
+            self._part_of[(ra, h)] = rid
+        else:
+            span = self._try_place_spanning(req)
+            if span is None:
+                return None
+        st.submitted += 1
+        st.admitted += 1
+        self._broker_held.add(rid)
+        return rid
+
+    def broker_release(self, rid: int, *, abort: bool = False) -> list[Ticket]:
+        """Release (or phase-1 abort; both count as released here) a
+        :meth:`broker_admit` reservation; returns the leaf tickets it
+        freed.  Idempotent: a reservation this plane already displaced
+        (and reported via ``on_broker_displace``) releases nothing."""
+        if rid not in self._broker_held:
+            return []
+        self._broker_held.discard(rid)
+        st = self._span_active.pop(rid)
+        released = self._teardown_span(st)
+        self.span_tenants[st.tenant].released += 1
+        return released
+
+    def broker_holds(self, rid: int, seg: DataflowPath) -> bool:
+        """Is ``rid`` a live reservation of exactly ``seg``?"""
+        st = self._span_active.get(rid)
+        return rid in self._broker_held and st is not None and st.df is seg
+
+    def broker_uses_node(self, rid: int, v: int) -> bool:
+        """Does a reservation touch node ``v`` (this plane's ids)?  Used by
+        the enclosing broker to scope churn displacement."""
+        st = self._span_active.get(rid)
+        return st is not None and self._span_uses_node(st, int(v))
+
+    def broker_uses_link(self, rid: int, u: int, v: int) -> bool:
+        st = self._span_active.get(rid)
+        if st is None:
+            return False
+        u, v = int(u), int(v)
+        return any(c in ((u, v), (v, u)) for c in st.cuts) or \
+            self._span_uses_link(st, u, v)
+
+    # -- region chains: quotient graph and congestion-aware k-shortest --------
 
     def _cut_alive(self, u: int, v: int) -> bool:
         return (
@@ -482,8 +1058,8 @@ class ChainBroker:
         algorithm under the load-aware edge cost, cheapest first.  Chains
         through hot gateways cost more, so a saturated fewest-hop chain
         sorts behind a longer cold bypass *before* any 2PC probes it.
-        ``chain_k == 1`` planes never call this — they take the legacy
-        fewest-hop :meth:`_region_chain` path unchanged."""
+        ``chain_k == 1`` planes take the fewest-hop :meth:`_region_chain`
+        instead."""
         adj = self._cost_adjacency(occ_view)
         first = self._dijkstra_chain(adj, ra, rb)
         if first is None:
@@ -544,34 +1120,6 @@ class ChainBroker:
                     if len(out) >= budget:
                         break
         return out
-
-    def _requeue_or_livelock_drop(self, st: SpanningTicket) -> None:
-        """Requeue a displaced spanning request at its home child — or
-        drop it when its *cumulative* attempt budget is spent.  The
-        per-episode ``attempts`` resets (displacement is not the
-        request's fault) but ``cum_attempts`` never does: a request
-        ping-ponging between a saturated chain and displacement meets
-        ``max_cum_attempts`` instead of livelocking forever."""
-        st.req.attempts = 0
-        st.req.cum_attempts += 1
-        self.span_stats["max_req_attempts"] = max(
-            self.span_stats["max_req_attempts"], st.req.cum_attempts)
-        if st.req.cum_attempts >= self.max_cum_attempts:
-            self.span_tenants[st.tenant].dropped += 1
-            self.span_stats["dropped"] += 1
-            self.span_stats["livelock_dropped"] += 1
-            if self.tracer.enabled:
-                self.tracer.flow_end(
-                    st.rid, "drop", outcome="livelock",
-                    cum_attempts=st.req.cum_attempts,
-                )
-            if self.on_drop is not None:
-                self.on_drop(st.rid)
-            return
-        home = int(self.region_of[st.df.src])
-        ControlPlane._enqueue(
-            self._span_q[home][st.tenant], st.req, front_of_class=True
-        )
 
     def _chain_feasible(self, df: DataflowPath, splits, gates) -> bool:
         """Cut-bandwidth screen for one candidate.  Ghost gateway
@@ -650,681 +1198,65 @@ class ChainBroker:
                     return out
         return out
 
-
-class RegionalControlPlane(ChainBroker):
-    """R sharded control planes + gossip + a multi-hop cut-edge 2PC broker.
-
-    Mirrors the centralized :class:`ControlPlane` surface (register_tenant
-    / submit / pump / release / fail_* / restore_* / defrag /
-    committed_capacity / conservation / fairness_report / engine_stats /
-    check_invariants / active_ids), so call sites are plane-agnostic.
-    ``pump`` returns a mix of :class:`Ticket` (in-region; their
-    mappings/routes are in the owning region's *local* id space —
-    resolve the owner with :meth:`owner_region` and lift through
-    ``plane.views[r]``) and :class:`SpanningTicket` (cross-region,
-    global gateways) handles; ``defrag`` returns one
-    :class:`~repro.service.defrag.DefragResult` per region — there is no
-    global re-solve, by design.
-
-    ``**solve_cfg`` (including the incremental-fast-path knobs
-    ``cache_enabled`` / ``cache_size`` / ``max_correction_supersteps``)
-    is forwarded to every per-region placer: each region keeps its own
-    :class:`~repro.core.solution_cache.SolutionCache` over *view-local*
-    request signatures, invalidated by its own residual version + epoch —
-    no cross-region cache coherence is needed because a region only ever
-    admits against its own residual truth.
-    """
-
-    def __init__(
-        self,
-        rg: ResourceGraph,
-        *,
-        regions: Optional[int] = None,
-        region_of=None,
-        levels: Optional[int] = None,
-        branching: Optional[int] = None,
-        policy: Optional[FairSharePolicy] = None,
-        micro_batch: int = 32,
-        max_attempts: int = 8,
-        preempt: bool = True,
-        preempt_budget: Optional[float] = None,
-        pipeline_depth: int = 1,
-        method: str = "leastcost_jax",
-        use_kernel: bool = False,
-        fanout: int = 2,
-        gossip_period: int = 1,
-        max_cut_attempts: int = 4,
-        chain_k: int = 2,
-        congestion_weight: float = 1.0,
-        max_cum_attempts: Optional[int] = None,
-        seed: int = 0,
-        tracer=None,
-        **solve_cfg,
-    ):
-        self.base = rg
-        # nesting kwargs fail fast: this class IS the levels=1 plane — a
-        # levels > 1 request must go through ControlPlane(levels=...) /
-        # HierarchicalControlPlane, never silently build flat
-        if levels is not None and int(levels) != 1:
-            raise ValueError(
-                f"levels={levels}: RegionalControlPlane is the flat "
-                "(levels=1) plane; build a hierarchy with "
-                "ControlPlane(rg, levels=...) or HierarchicalControlPlane"
-            )
-        if branching is not None:
-            raise ValueError(
-                f"branching={branching} requires a hierarchical plane "
-                "(levels >= 2); the flat plane takes regions= or region_of="
-            )
-        if region_of is not None:
-            # caller-pinned partition (e.g. a line-of-regions topology
-            # whose canonical assignment the BFS grower would not find);
-            # the region count comes from the assignment, and an
-            # explicitly contradicting regions= fails fast
-            self.region_of = validate_region_of(rg, region_of)
-            detected = int(self.region_of.max()) + 1
-            if regions is not None and int(regions) != detected:
-                raise ValueError(
-                    f"regions={regions} contradicts region_of, which "
-                    f"defines {detected} regions"
-                )
-        else:
-            self.region_of = partition_regions(
-                rg, 2 if regions is None else regions, seed=seed)
-        self.R = int(self.region_of.max()) + 1
-        self.policy = policy or FairSharePolicy()
-        self.micro_batch = int(micro_batch)
-        self.max_attempts = int(max_attempts)
-        self.preempt = bool(preempt)
-        self.preempt_budget = preempt_budget
-        self.pipeline_depth = max(1, int(pipeline_depth))
-        self.method = method
-        self.max_cut_attempts = int(max_cut_attempts)
-        # chain_k > 1 races k-shortest region chains under the load-aware
-        # cost; chain_k == 1 is the legacy single fewest-hop chain,
-        # bit-identical by construction (same code path)
-        self.chain_k = max(1, int(chain_k))
-        self.congestion_weight = float(congestion_weight)
-        # lifetime attempt budget across displacement episodes: a request
-        # ping-ponging between admission and displacement resets its
-        # per-episode attempts but never this one (livelock backstop)
-        self.max_cum_attempts = (
-            4 * self.max_attempts if max_cum_attempts is None
-            else int(max_cum_attempts)
-        )
-        # the broker's tracer; each region gets a scoped view sharing the
-        # same event buffer ("r{r}/" track prefixes, so region-local rids
-        # never collide with broker-level flow ids)
-        self.tracer = tracer if tracer is not None else obs_trace.NULL
-        # the compacted solve substrate: one global<->local bijection per
-        # region; every regional plane below is sized n_r, not n
-        self.views = [
-            CompactedView.from_assign(rg, self.region_of, r)
-            for r in range(self.R)
-        ]
-        self.regions = [
-            ControlPlane(
-                rg,
-                view=self.views[r],
-                policy=self.policy,
-                micro_batch=micro_batch,
-                max_attempts=max_attempts,
-                preempt=preempt,
-                preempt_budget=preempt_budget,
-                pipeline_depth=pipeline_depth,
-                method=method,
-                use_kernel=use_kernel,
-                tracer=self.tracer.scoped(f"r{r}"),
-                **solve_cfg,
-            )
-            for r in range(self.R)
-        ]
-        for r, cp in enumerate(self.regions):
-            # an in-region preemption OR churn re-map may displace/drop a
-            # spanning segment; the broker must then tear down its sibling
-            # reservations (the region plane hands over every foreign tid)
-            cp.on_foreign_preempt = (
-                lambda tickets, r=r: [
-                    self._displace_span_part(r, t) for t in tickets
-                ]
-            )
-            # a region dropping a local request terminates its lifecycle;
-            # forget the broker's global-rid bookkeeping for it
-            cp.on_drop = (
-                lambda lreq, r=r: self._forget_local(r, lreq.rid)
-            )
-        self.bus = GossipBus(self.R, fanout=fanout, seed=seed + 1)
-        self.gossip_period = max(1, int(gossip_period))
-        self.node_up = np.ones(rg.n, bool)
-
-        # cut-edge bandwidth ledger: owned by the broker, reserved by 2PC
-        # (see ChainBroker._init_cut_ledger)
-        self._init_cut_ledger()
-
-        # spanning-request bookkeeping (the broker's ledger)
-        self.span_tenants: dict[str, TenantState] = {}
-        self._span_q: list[dict[str, collections.deque]] = [
-            {} for _ in range(self.R)
-        ]
-        self._span_active: dict[int, SpanningTicket] = {}
-        self._part_of: dict[tuple[int, int], int] = {}  # (region, tid) -> rid
-        # global rid space over both local and spanning requests
-        self._rid = itertools.count()
-        self._local: dict[int, tuple[int, int]] = {}  # rid -> (region, lrid)
-        self._grid_of: dict[tuple[int, int], int] = {}  # (region, lrid) -> rid
-        self._pumps = 0
-        self._twopc_msgs = 0
-        # while a churn call (fail_node/fail_link) is reconciling, spanning
-        # placements torn down by in-region rescue preemptions collect here
-        # so the churn return contract covers them too
-        self._churn_collector: Optional[list] = None
-        # reservations held by a PARENT plane's 2PC (broker_admit): their
-        # lifecycle belongs to the parent — a displacement fires
-        # on_broker_displace(rid) instead of requeueing locally, and they
-        # are not caller-visible active requests
-        self._broker_held: set[int] = set()
-        self.on_broker_displace = None  # parent hook: rid -> None
-        self.on_drop = None  # parent hook: plane-level rid -> None
-        self.span_stats = {
-            "attempts": 0, "admitted": 0, "dropped": 0,
-            "displaced": 0, "no_cut": 0,
-            "multi_hop": 0,  # admitted over chains of >= 3 regions
-            "max_chain": 0,  # longest admitted region chain
-            "broker_local": 0,  # parent-held single-region reservations
-            "rerouted": 0,  # admitted via a non-fewest-hop chain
-            "livelock_dropped": 0,  # dropped by the cumulative budget
-            "max_req_attempts": 0,  # highest lifetime attempts on one req
-        }
-
-    # -- registration / submission ------------------------------------------
-
-    def register_tenant(
-        self, name: str, *, weight: float = 1.0,
-        budget: Optional[float] = None,
-    ) -> TenantConfig:
-        if name in self.span_tenants:
-            raise ValueError(f"tenant {name!r} already registered")
-        cfg = TenantConfig(name, weight=weight, budget=budget)
-        for cp in self.regions:
-            cp.register_tenant(name, weight=weight, budget=budget)
-        self.span_tenants[name] = TenantState(cfg)
-        for q in self._span_q:
-            q[name] = collections.deque()
-        return cfg
-
-    def submit(self, tenant: str, df: DataflowPath, *, klass: int = 0) -> int:
-        """Queue a request with its *home* (source) region; a request whose
-        endpoints straddle regions queues with the home region's broker
-        side instead and is placed by 2PC at pump time.  ``df`` is in
-        global ids; in-region requests are compacted into the owning
-        region's local id space here, at the broker boundary.  Returns a
-        global rid valid across regions."""
-        st = self.span_tenants[tenant]  # KeyError for unregistered
-        rid = next(self._rid)
-        ra = int(self.region_of[df.src])
-        rb = int(self.region_of[df.dst])
-        if ra == rb:
-            lrid = self.regions[ra].submit(
-                tenant, self.views[ra].compact_df(df), klass=klass
-            )
-            self._local[rid] = (ra, lrid)
-            self._grid_of[(ra, lrid)] = rid
-        else:
-            st.submitted += 1
-            ControlPlane._enqueue(
-                self._span_q[ra][tenant], Request(rid, tenant, df, klass=klass)
-            )
-            if self.tracer.enabled:
-                self.tracer.flow_begin(
-                    rid, "submit", tenant=tenant, klass=klass,
-                    spanning=True, home=ra,
-                )
-        return rid
-
-    # -- live accounting -----------------------------------------------------
-
-    def _region_committed(self, r: int) -> dict[str, float]:
-        """Region r's exact local per-tenant committed compute, from the
-        placer tickets (includes spanning segments reserved there)."""
-        held = {t: 0.0 for t in self.span_tenants}
-        for tk in self.regions[r].placer.tickets.values():
-            if tk.tenant in held:
-                held[tk.tenant] += float(np.sum(tk.df.creq))
-        return held
-
-    def committed_capacity(self) -> dict[str, float]:
-        held = {t: 0.0 for t in self.span_tenants}
-        for r in range(self.R):
-            for t, c in self._region_committed(r).items():
-                held[t] += c
-        return held
-
-    def residual_capacity(self) -> float:
-        """Summed live residual node capacity across every region (the
-        scalar a parent plane publishes as this child's aggregate)."""
-        return float(sum(
-            np.sum(np.where(cp.placer.node_up, cp.placer.cap, 0.0))
-            for cp in self.regions
-        ))
-
-    def queued_demand(self) -> dict[str, float]:
-        out = {t: 0.0 for t in self.span_tenants}
-        for cp in self.regions:
-            for t, c in cp.queued_demand().items():
-                out[t] += c
-        for q in self._span_q:
-            for t, dq in q.items():
-                out[t] += sum(r.creq_sum for r in dq)
-        return out
-
-    def owner_region(self, ticket: Ticket) -> Optional[int]:
-        """The region whose placer holds ``ticket`` (by object identity —
-        tids are per-region counters and collide across regions).  Use it
-        to pick the right ``plane.views[r]`` for lifting an in-region
-        handle's local-id mapping/route back to global ids."""
-        for r, cp in enumerate(self.regions):
-            if cp.placer.tickets.get(ticket.tid) is ticket:
-                return r
-        return None
-
-    def active_ids(self) -> list[int]:
-        """Global rids of active requests across every region + spanning.
-        Parent-held broker reservations are excluded — they are segments
-        of a composite the parent plane accounts for."""
-        out = [
-            self._grid_of[(r, lrid)]
-            for r, cp in enumerate(self.regions)
-            for lrid in cp.active
-        ]
-        out += [rid for rid in self._span_active if rid not in self._broker_held]
-        return sorted(out)
-
-    def ticket_live(self, t) -> bool:
-        """Is a handle returned by :meth:`pump` still standing?  (A later
-        round — or an enclosing plane's 2PC — may have displaced it.)"""
-        if self._span_active.get(getattr(t, "rid", -1)) is t:
-            return True
-        return any(
-            cp.placer.tickets.get(getattr(t, "tid", -1)) is t
-            for cp in self.regions
-        )
-
-    def conservation(self) -> dict[str, int]:
-        """The global ticket ledger: regional ledgers + the broker's
-        spanning ledger.  ``ok`` iff every submitted request is in exactly
-        one state *summed over regions*."""
-        agg = {"submitted": 0, "queued": 0, "in_flight": 0, "active": 0,
-               "released": 0, "dropped": 0}
-        for cp in self.regions:
-            led = cp.conservation()
-            for k in agg:
-                agg[k] += led[k]
-        agg["submitted"] += sum(
-            st.submitted for st in self.span_tenants.values())
-        agg["queued"] += sum(
-            len(dq) for q in self._span_q for dq in q.values())
-        agg["active"] += len(self._span_active)
-        agg["released"] += sum(
-            st.released for st in self.span_tenants.values())
-        agg["dropped"] += sum(
-            st.dropped for st in self.span_tenants.values())
-        agg["ok"] = agg["submitted"] == (
-            agg["queued"] + agg["in_flight"] + agg["active"]
-            + agg["released"] + agg["dropped"]
-        )
-        return agg
-
-    # -- gossip --------------------------------------------------------------
-
-    def node_occupancy(self, v: int) -> float:
-        """Compute occupancy of global node ``v`` in [0, 1] from its
-        owning region's live residual (1.0 when the node is down)."""
-        r = int(self.region_of[v])
-        cp = self.regions[r]
-        lv = int(self.views[r].to_local(v))
-        if not (bool(self.node_up[v]) and bool(cp.placer.node_up[lv])):
-            return 1.0
-        base = float(cp.placer.base.cap[lv])
-        if base <= 0.0:
-            return 0.0
-        return min(1.0, max(0.0, 1.0 - float(cp.placer.cap[lv]) / base))
-
-    def _gateway_occupancy(self, r: int) -> dict[int, float]:
-        """Occupancy of region ``r``'s own gateway nodes (global ids) —
-        the per-cut congestion estimate it publishes into gossip."""
-        return {u: self.node_occupancy(u)
-                for u in self._gateways_of.get(r, ())}
-
-    def _publish(self, r: int) -> None:
-        cp = self.regions[r]
-        queued = cp.queued_demand()
-        for t, dq in self._span_q[r].items():
-            queued[t] = queued.get(t, 0.0) + sum(x.creq_sum for x in dq)
-        residual = float(
-            np.sum(np.where(cp.placer.node_up, cp.placer.cap, 0.0))
-        )
-        self.bus.publish(r, self._region_committed(r), queued, residual,
-                         congestion=self._gateway_occupancy(r))
-
-    # -- admission -----------------------------------------------------------
-
-    def pump(self, *, rounds: int = 1, extra_committed=None) -> list:
-        """One decentralized drain round per ``rounds``: publish + gossip
-        share estimates, drain every region's queues under
-        estimated-global fair shares, then place queued spanning requests
-        by bounded 2PC.  Returns the still-live admitted handles
-        (:class:`Ticket` for in-region, :class:`SpanningTicket` for
-        cross-region).
-
-        ``extra_committed`` is a parent plane's downward-published
-        estimate of per-tenant holdings *outside this plane entirely*
-        (the tree-gossip downlink); it folds into every region's drain
-        the same way gossiped sibling estimates do — advisory for drain
-        order, never capacity."""
-        admitted: list[Ticket] = []
-        spanned: list[SpanningTicket] = []
-        for _ in range(int(rounds)):
-            self._pumps += 1
-            for r in range(self.R):
-                self._publish(r)
-            if self.R > 1 and self._pumps % self.gossip_period == 0:
-                with self.tracer.span("gossip.round", track="gossip",
-                                      cat="gossip", round=self._pumps):
-                    self.bus.tick()
-            for r, cp in enumerate(self.regions):
-                extra: dict[str, float] = dict(extra_committed or {})
-                if self.R > 1:
-                    # gossiped estimate of remote holdings, plus the
-                    # broker-reserved spanning segments physically held in
-                    # THIS region (they are placer tickets but not local
-                    # control-plane requests, so the local accounting
-                    # cannot see them)
-                    for t, c in self.bus.remote_committed(r).items():
-                        extra[t] = extra.get(t, 0.0) + c
-                    local_cp = cp.committed_capacity()
-                    for t, c in self._region_committed(r).items():
-                        diff = c - local_cp.get(t, 0.0)
-                        if diff > _EPS:
-                            extra[t] = extra.get(t, 0.0) + diff
-                admitted += cp.pump(rounds=1, extra_committed=extra or None)
-            spanned += self._pump_spanning(extra_committed)
-        live = [t for t in admitted if self.ticket_live(t)]
-        live += [s for s in spanned if s.rid in self._span_active]
-        return live
-
-    def flush(self) -> list[Ticket]:
-        """Commit every region's in-flight pipeline windows (barrier); see
-        :meth:`ControlPlane.flush`.  The broker's spanning 2PC needs no
-        flush of its own — it reserves host-side through ``placer.admit``,
-        and an in-flight regional batch that loses capacity to a spanning
-        reservation simply re-solves its conflicts at commit."""
-        admitted: list[Ticket] = []
-        for cp in self.regions:
-            admitted += cp.flush()
-        return [
-            t for t in admitted
-            if any(cp.placer.tickets.get(t.tid) is t for cp in self.regions)
-        ]
-
-    def warmup(self, *, max_batch: Optional[int] = None, p: int = 5) -> int:
-        """Pre-compile each region's jit buckets (region-local ``n_r``
-        shapes differ per region, so every placer warms its own)."""
-        return max(
-            (cp.warmup(max_batch=max_batch, p=p) for cp in self.regions),
-            default=0,
-        )
-
-    def _pump_spanning(self, extra_committed=None) -> list[SpanningTicket]:
-        if self.R <= 1:
-            return []
-        out: list[SpanningTicket] = []
-        cfgs = {t: st.cfg for t, st in self.span_tenants.items()}
-        for r in range(self.R):
-            queues = self._span_q[r]
-            if not any(queues.values()):
-                continue
-            committed = self._region_committed(r)
-            for t, c in self.bus.remote_committed(r).items():
-                if t in committed:
-                    committed[t] += c
-            for t, c in (extra_committed or {}).items():
-                if t in committed:
-                    committed[t] += c
-            picked = self.policy.select(
-                cfgs, queues, committed, self.micro_batch
-            )
-            # pop every selected head BEFORE placing: a 2PC attempt may
-            # displace another spanning request to the front of one of
-            # these very queues, which must not disturb the drain order
-            for req in picked:
-                q = queues[req.tenant]
-                assert q[0] is req, "policy must select queue heads in order"
-                q.popleft()
-            for req in picked:
-                q = queues[req.tenant]
-                st = self._try_place_spanning(req)
-                if st is not None:
-                    self.span_tenants[req.tenant].admitted += 1
-                    if self.tracer.enabled:
-                        self.tracer.flow_point(
-                            req.rid, "admit", chain=len(st.parts))
-                    out.append(st)
-                else:
-                    req.attempts += 1
-                    req.cum_attempts += 1
-                    self.span_stats["max_req_attempts"] = max(
-                        self.span_stats["max_req_attempts"], req.cum_attempts)
-                    exhausted = req.attempts >= self.max_attempts
-                    livelocked = req.cum_attempts >= self.max_cum_attempts
-                    if exhausted or livelocked:
-                        self.span_tenants[req.tenant].dropped += 1
-                        self.span_stats["dropped"] += 1
-                        if livelocked and not exhausted:
-                            self.span_stats["livelock_dropped"] += 1
-                        if self.tracer.enabled:
-                            self.tracer.flow_end(
-                                req.rid, "drop", outcome="dropped",
-                                attempts=req.attempts,
-                                cum_attempts=req.cum_attempts,
-                            )
-                        if self.on_drop is not None:
-                            self.on_drop(req.rid)
-                    else:
-                        ControlPlane._enqueue(q, req, front_of_class=True)
-        return out
-
-    # -- parent-plane broker interface (hierarchical nesting) ----------------
-
-    def broker_admit(self, tenant: str, df: DataflowPath, *,
-                     klass: int = 0) -> Optional[int]:
-        """Synchronous, abortable admission used by a PARENT plane's 2PC:
-        place ``df`` (in THIS plane's id space) immediately — in one
-        region, or spanning this plane's own regions (the recursion that
-        lets a top-level segment split again at the child's cuts).
-
-        Returns a rid releasable with :meth:`broker_release`, or None
-        (nothing reserved).  The reservation is a first-class spanning
-        entry in this plane's ledger, so conservation and invariants hold
-        at every level; if churn or preemption inside this plane later
-        displaces it, ``on_broker_displace(rid)`` fires instead of a local
-        requeue — the composite belongs to the parent."""
-        st = self.span_tenants[tenant]  # KeyError for unregistered
-        rid = next(self._rid)
-        req = Request(rid, tenant, df, klass=klass)
-        ra = int(self.region_of[df.src])
-        rb = int(self.region_of[df.dst])
-        if ra == rb:
-            t = self._reserve_plain(ra, df, tenant, klass)
-            if t is None:
-                return None
-            self.span_stats["broker_local"] += 1
-            span = SpanningTicket(
-                rid=rid, req=req,
-                parts=[SpanPart(ra, t.tid, t.df, self.views[ra].version)],
-                cuts=[], cut_bws=[], splits=[],
-            )
-            self._span_active[rid] = span
-            self._part_of[(ra, t.tid)] = rid
-        else:
-            span = self._try_place_spanning(req)
-            if span is None:
-                return None
-        st.submitted += 1
-        st.admitted += 1
-        self._broker_held.add(rid)
-        return rid
-
-    def broker_release(self, rid: int) -> None:
-        """Release (or phase-1 abort) a :meth:`broker_admit` reservation.
-        Idempotent: releasing a reservation this plane already displaced
-        (and reported via ``on_broker_displace``) is a no-op."""
-        if rid not in self._broker_held:
-            return
-        self._broker_held.discard(rid)
-        st = self._span_active.pop(rid)
-        self._teardown_span(st)
-        self.span_tenants[st.tenant].released += 1
-
-    def broker_uses_node(self, rid: int, v: int) -> bool:
-        """Does a broker reservation touch node ``v`` (this plane's id
-        space)?  Used by the parent to scope churn displacement."""
-        st = self._span_active.get(rid)
-        return st is not None and self._span_uses_node(st, int(v))
-
-    def broker_uses_link(self, rid: int, u: int, v: int) -> bool:
-        st = self._span_active.get(rid)
-        if st is None:
-            return False
-        return self._span_uses_link(st, int(u), int(v)) or any(
-            c in ((int(u), int(v)), (int(v), int(u))) for c in st.cuts
-        )
-
     # -- two-phase commit over the chain -------------------------------------
-
-    def _reserve_plain(self, r: int, seg: DataflowPath, tenant: str,
-                       klass: int) -> Optional[Ticket]:
-        """Phase-1 reserve of one segment in region ``r`` against its own
-        residual only — freely abortable, displaces nothing.  The segment
-        (global gateway pins) is compacted into the region's local id
-        space here.  A failed reserve is a 2PC probe, not a service
-        rejection (the spanning outcome is accounted by the broker's
-        ledger/span_stats), so the placer's rejected counter is
-        reconciled — same convention as ``admit_preempting``'s probes."""
-        placer = self.regions[r].placer
-        t = placer.admit(
-            self.views[r].compact_df(seg), tenant=tenant, klass=klass
-        )
-        if t is None:
-            placer.stats.rejected -= 1
-        return t
-
-    def _reserve_preempting(self, r: int, seg: DataflowPath, tenant: str,
-                            klass: int) -> Optional[Ticket]:
-        """Preemptive phase-1 reserve under the displaced-cost budget.
-
-        Only called for the LAST missing reservation of a candidate — every
-        sibling reservation is already held, so success here guarantees the
-        commit and victims are never displaced by an admission that then
-        aborts (a failed probe rolls back inside ``admit_preempting``).
-        Victims owned by the region's plane re-enter its tenant queues; a
-        victim that is itself a spanning segment displaces its whole
-        spanning placement back to the broker queue (accounted, never
-        dropped)."""
-        cp = self.regions[r]
-        t, victims = cp.placer.admit_preempting(
-            self.views[r].compact_df(seg), tenant=tenant, klass=klass,
-            max_displaced_cost=self.preempt_budget,
-        )
-        if t is None:
-            cp.placer.stats.rejected -= 1  # a probe, not a rejection
-        if victims:
-            for part in cp.preempt_reclaim(victims):
-                self._displace_span_part(r, part)
-        return t
-
-    def _abort_reservation(self, r: int, ticket: Ticket) -> None:
-        """Undo a phase-1 reserve: bookkeeping-only release (no released
-        counter, no admitted inflation)."""
-        cp = self.regions[r]
-        cp.placer.release(ticket.tid, reason=None)
-        cp.placer.stats.admitted -= 1  # the reserve never really served
-
-    def _commit_spanning(self, req: Request, chain: list[int], splits,
-                         gates, tickets: list[Ticket]) -> SpanningTicket:
-        cut_bws = [float(req.df.breq[s]) for s in splits]
-        for e, b in zip(gates, cut_bws):
-            self.cut_residual[e] -= b
-        parts = [
-            SpanPart(chain[i], t.tid, t.df, self.views[chain[i]].version)
-            for i, t in enumerate(tickets)
-        ]
-        st = SpanningTicket(
-            rid=req.rid, req=req, parts=parts,
-            cuts=[tuple(e) for e in gates], cut_bws=cut_bws,
-            splits=list(splits),
-        )
-        self._span_active[req.rid] = st
-        for part in parts:
-            self._part_of[(part.region, part.tid)] = req.rid
-        self.span_stats["admitted"] += 1
-        if len(chain) >= 3:
-            self.span_stats["multi_hop"] += 1
-        self.span_stats["max_chain"] = max(
-            self.span_stats["max_chain"], len(chain))
-        return st
 
     def _attempt_candidate(self, req: Request, chain: list[int], splits,
                            gates, can_preempt: bool) -> Optional[SpanningTicket]:
         """One bounded 2PC over every segment of one candidate.
 
-        Reservations are plain (freely abortable) in chain order; at most
-        ONE may escalate to budgeted preemption, and only as the *last*
-        reservation of the candidate while every sibling is already held —
-        so preemption victims are displaced only by an admission that
-        commits.  A candidate that cannot complete aborts every
-        reservation it took; nothing standing is ever destroyed by a
-        failed attempt.  Message cost per candidate is at most
-        ``2 * len(chain) + 2`` (prepare/commit per segment, plus the
-        nack + preemptive re-prepare of the single blocker).
+        Each segment is compacted into its region's ids and reserved by
+        that region's ``broker_admit`` in chain order (a broker region may
+        run its own chain split and 2PC inside).  With ``can_preempt`` (a
+        leaf level only) at most ONE failed reserve may be retried with
+        budgeted preemption, and only as the candidate's *last* missing
+        reservation while every sibling is held — so victims are displaced
+        only by an admission that commits.  A candidate that cannot
+        complete aborts every reservation it took.  Message cost per
+        candidate is at most ``2 * len(chain) + 2`` (prepare/commit per
+        segment, plus the nack + preemptive re-prepare of the one blocker).
         """
         df = req.df
         segs = split_dataflow_chain(df, splits, gates)
-        held: dict[int, Ticket] = {}
+        local: dict[int, DataflowPath] = {}
+        held: dict[int, int] = {}
         failed: list[int] = []
         tr = self.tracer
         for i, seg in enumerate(segs):
+            r = chain[i]
+            local[i] = self.views[r].compact_df(seg)
             self._twopc_msgs += 1  # prepare segment i
-            with tr.span("2pc.reserve", track="2pc", cat="2pc",
-                         region=chain[i]):
-                t = self._reserve_plain(chain[i], seg, req.tenant, req.klass)
-            if t is None:
+            with tr.span("2pc.reserve", track="2pc", cat="2pc", region=r):
+                h = self.regions[r].broker_admit(
+                    req.tenant, local[i], klass=req.klass)
+            if h is None:
                 self._twopc_msgs += 1  # nack i
                 if tr.enabled:
-                    tr.flow_point(req.rid, "2pc.nack", region=chain[i])
+                    tr.flow_point(req.rid, "2pc.nack", region=r)
                 failed.append(i)
                 if not can_preempt or len(failed) > 1:
                     break  # candidate dead: >1 blocker can't be rescued
             else:
-                held[i] = t
+                held[i] = h
                 if tr.enabled:
-                    tr.flow_point(req.rid, "2pc.reserve", region=chain[i])
+                    tr.flow_point(req.rid, "2pc.reserve", region=r)
         if len(failed) == 1 and can_preempt and len(held) == len(segs) - 1:
             i = failed[0]
+            r = chain[i]
             self._twopc_msgs += 1  # prepare i, preemptive retry (last)
             with tr.span("2pc.reserve.preempt", track="2pc", cat="2pc",
-                         region=chain[i]):
-                t = self._reserve_preempting(chain[i], segs[i],
-                                             req.tenant, req.klass)
-            if t is None:
+                         region=r):
+                h = self.regions[r].broker_admit(
+                    req.tenant, local[i], klass=req.klass, preempt=True)
+            if h is None:
                 self._twopc_msgs += 1  # nack i
                 if tr.enabled:
-                    tr.flow_point(req.rid, "2pc.nack", region=chain[i],
+                    tr.flow_point(req.rid, "2pc.nack", region=r,
                                   preempting=True)
             else:
-                held[i] = t
+                held[i] = h
                 failed = []
                 if tr.enabled:
-                    tr.flow_point(req.rid, "2pc.reserve", region=chain[i],
+                    tr.flow_point(req.rid, "2pc.reserve", region=r,
                                   preempting=True)
         ok = not failed and len(held) == len(segs) and all(
             self.cut_residual[e] + _EPS >= float(df.breq[s])
@@ -1335,27 +1267,42 @@ class RegionalControlPlane(ChainBroker):
                 self._twopc_msgs += 1  # abort i
                 if tr.enabled:
                     tr.flow_point(req.rid, "2pc.abort", region=chain[i])
-                self._abort_reservation(chain[i], held[i])
+                self.regions[chain[i]].broker_release(held[i], abort=True)
             return None
         self._twopc_msgs += len(segs)  # commit every segment
         if tr.enabled:
             tr.flow_point(req.rid, "2pc.commit", chain=len(segs))
-        return self._commit_spanning(
-            req, chain, splits, gates, [held[i] for i in range(len(segs))]
+        cut_bws = [float(df.breq[s]) for s in splits]
+        for e, b in zip(gates, cut_bws):
+            self.cut_residual[e] -= b
+        st = SpanningTicket(
+            rid=req.rid, req=req,
+            parts=[SpanPart(chain[i], held[i], local[i],
+                            self.views[chain[i]].version)
+                   for i in range(len(segs))],
+            cuts=[tuple(e) for e in gates], cut_bws=cut_bws,
+            splits=list(splits),
         )
+        self._span_active[req.rid] = st
+        for part in st.parts:
+            self._part_of[(part.region, part.tid)] = req.rid
+        self.span_stats["admitted"] += 1
+        if len(chain) >= 3:
+            self.span_stats["multi_hop"] += 1
+        self.span_stats["max_chain"] = max(
+            self.span_stats["max_chain"], len(chain))
+        return st
 
     def _try_place_spanning(self, req: Request) -> Optional[SpanningTicket]:
         """Chain selection + bounded 2PC over the cut candidates.  This is
         the single accounting site for spanning placement attempts —
         ``span_stats["attempts"]`` counts every entry here (from the pump
-        drain AND from a parent plane's ``broker_admit``), ``admitted``
+        drain AND from an enclosing broker's ``broker_admit``), ``admitted``
         every 2PC commit, so ``attempts >= admitted`` holds by
         construction (see :meth:`check_invariants`).
 
-        ``chain_k == 1``: the legacy single fewest-hop region chain over
-        the quotient graph, with latency-ordered gate candidates —
-        dataflows spanning >= 3 regions decompose into one gateway-pinned
-        segment per region instead of retrying until dropped.
+        ``chain_k == 1``: the single fewest-hop region chain over the
+        quotient graph, with latency-ordered gate candidates.
 
         ``chain_k > 1``: Yen k-shortest chains under the load-aware cost
         (the broker's own cut-ledger utilization + gossiped gateway
@@ -1366,28 +1313,17 @@ class RegionalControlPlane(ChainBroker):
         self.span_stats["attempts"] += 1
         ra = int(self.region_of[df.src])
         rb = int(self.region_of[df.dst])
-        can_preempt = self.preempt and req.klass > 0
+        can_preempt = self.levels == 1 and self.preempt and req.klass > 0
         if self.chain_k <= 1:
             chain = self._region_chain(ra, rb)
-            if chain is None:
-                self.span_stats["no_cut"] += 1
-                return None
-            candidates = self._candidate_chains(df, chain)
-            if not candidates:
-                self.span_stats["no_cut"] += 1
-                return None
-            for (splits, gates) in candidates:
-                st = self._attempt_candidate(req, chain, splits, gates,
-                                             can_preempt)
-                if st is not None:
-                    return st
-            return None
-        occ = self.bus.congestion_view(ra)
-        chains = self._region_chains(ra, rb, occ)
-        if not chains:
-            self.span_stats["no_cut"] += 1
-            return None
-        raced = self._race_candidates(df, chains, occ)
+            raced = [] if chain is None else [
+                (chain, splits, gates)
+                for splits, gates in self._candidate_chains(df, chain)
+            ]
+        else:
+            occ = self.bus.congestion_view(ra)
+            raced = self._race_candidates(
+                df, self._region_chains(ra, rb, occ), occ)
         if not raced:
             self.span_stats["no_cut"] += 1
             return None
@@ -1400,69 +1336,99 @@ class RegionalControlPlane(ChainBroker):
                 return st
         return None
 
-    def _forget_local(self, r: int, lrid: int) -> None:
-        """A region terminated (dropped) a local request: the global-rid
-        maps must not grow without bound over the plane's lifetime.  The
-        plane-level ``on_drop`` hook chains the same cleanup upward when
-        this plane is itself a child of a hierarchy."""
-        rid = self._grid_of.pop((r, lrid), None)
+    # -- teardown / displacement ---------------------------------------------
+
+    def _forget_local(self, r: int, req: Request) -> None:
+        """Region r dropped one of its requests: forget its rid mapping
+        (the maps must not grow without bound) and pass the drop up as a
+        Request of this plane — its rid, its endpoints in this plane's
+        ids."""
+        rid = self._grid_of.pop((r, req.rid), None)
         if rid is not None:
             self._local.pop(rid, None)
             if self.on_drop is not None:
-                self.on_drop(rid)
+                self.on_drop(dataclasses.replace(
+                    req, rid=rid, df=self.views[r].uncompact_df(req.df)))
 
     def _teardown_span(self, st: SpanningTicket,
                        skip: Optional[tuple[int, int]] = None) -> list[Ticket]:
-        """Release every still-live reservation of a spanning placement
-        (``skip`` names a (region, tid) already gone, e.g. the preempted
-        part) and return the cut bandwidth.  Tolerates parts whose region
-        already dropped the local ticket — the teardown must always
-        complete for *all* siblings, never leak a partial reservation."""
+        """Release every still-held reservation of a spanning placement
+        (``skip`` names a (region, handle) already gone) and return the cut
+        bandwidth; returns the leaf tickets freed.  Region releases are
+        idempotent, so the teardown always completes for *all* siblings
+        and never leaks a partial reservation."""
         old: list[Ticket] = []
         for part in st.parts:
             self._part_of.pop((part.region, part.tid), None)
             if skip is not None and (part.region, part.tid) == skip:
                 continue
-            tk = self.regions[part.region].placer.tickets.get(part.tid)
-            if tk is not None:
-                self.regions[part.region].placer.release(part.tid, reason=None)
-                old.append(tk)
+            old += self.regions[part.region].broker_release(part.tid)
         for e, b in zip(st.cuts, st.cut_bws):
             self.cut_residual[e] += b
         return old
 
-    def _displace_span_part(self, r: int, part: Ticket) -> None:
-        """A spanning segment was preempted (or churn-dropped) out of
-        region ``r``: tear down the rest of its composite placement
-        (other-region segments + the cut reservations) and requeue the
-        whole request with its home region, front of its class band.
-        Idempotent — a second displacement of an already-torn-down span
-        is a no-op."""
-        rid = self._part_of.get((r, part.tid))
+    def _hand_up(self, rid: int, st: SpanningTicket, old: list) -> bool:
+        """After a displacement teardown: a reservation held for an
+        enclosing broker ends here and is reported up (True); anything
+        else stays this plane's to requeue (False)."""
+        if rid not in self._broker_held:
+            return False
+        self._broker_held.discard(rid)
+        self.span_tenants[st.tenant].released += 1
+        if self.on_broker_displace is not None:
+            self.on_broker_displace(rid, old)
+        return True
+
+    def _child_displaced(self, r: int, h: int, old: list) -> None:
+        """Region r displaced (preemption or churn) reservation ``h`` of
+        one of this broker's spanning placements; ``old`` lists the leaf
+        tickets that went with it.  Tear the rest of the composite down
+        and requeue it at its home region (or hand it further up).
+        Idempotent: a second report of a torn-down span is a no-op."""
+        rid = self._part_of.get((r, h))
         if rid is None:
             return  # not a spanning segment (or span already torn down)
         st = self._span_active.pop(rid, None)
         if st is None:
-            self._part_of.pop((r, part.tid), None)
+            self._part_of.pop((r, h), None)
             return
         # the displacement event was already counted once by the victim
         # segment's preemption/drop — siblings are bookkeeping
-        old_parts = [part] + self._teardown_span(st, skip=(r, part.tid))
+        old = old + self._teardown_span(st, skip=(r, h))
         self.span_stats["displaced"] += 1
         self.span_tenants[st.tenant].preempted += 1
         if self.tracer.enabled:
             self.tracer.flow_point(rid, "displaced", region=r)
-        if rid in self._broker_held:
-            # a parent plane's reservation: its lifecycle here ends — the
-            # parent tears down the composite and requeues at its level
-            self._broker_held.discard(rid)
-            self.span_tenants[st.tenant].released += 1
-            if self.on_broker_displace is not None:
-                self.on_broker_displace(rid)
-        else:
+        if not self._hand_up(rid, st, old):
             self._requeue_or_livelock_drop(st)
         if self._churn_collector is not None:
-            self._churn_collector.extend(old_parts)
+            # one level up, a churn call reports the leaf tickets it freed
+            # (the centralized plane's contract); higher up, the spans
+            self._churn_collector.extend(old if self.levels == 1 else [st])
+
+    def _displace_spans(self, pred) -> list:
+        """Tear down every active spanning placement matching ``pred`` and
+        requeue its request at its home region (environment displacement
+        is handled exactly like preemption: accounted, never dropped).
+        Returns what the churn call reports: the freed leaf tickets at
+        ``levels == 1``, the requeued spans above."""
+        old: list[Ticket] = []
+        requeue: list[SpanningTicket] = []
+        for rid in [g for g, st in self._span_active.items() if pred(st)]:
+            st = self._span_active.pop(rid)
+            released = self._teardown_span(st)
+            old += released
+            self.span_stats["displaced"] += 1
+            self.span_tenants[st.tenant].preempted += 1
+            if self.tracer.enabled:
+                self.tracer.flow_point(rid, "displaced", churn=True)
+            if not self._hand_up(rid, st, released):
+                requeue.append(st)
+        # back-to-front so the batch keeps FIFO-within-class order in any
+        # shared home queue (a cumulative-budget drop leaves its slot empty)
+        for st in reversed(requeue):
+            self._requeue_or_livelock_drop(st)
+        return old if self.levels == 1 else requeue
 
     # -- release / churn ------------------------------------------------------
 
@@ -1475,9 +1441,8 @@ class RegionalControlPlane(ChainBroker):
         st = self._span_active.pop(rid, None)
         if st is not None:
             # guarded teardown (tolerates a sibling whose region already
-            # dropped its local ticket); the request-level release is
-            # accounted once, by the broker's ledger — segment releases
-            # are regional bookkeeping, exactly like displacement
+            # dropped its reservation); the request-level release is
+            # accounted once, by this ledger
             self._teardown_span(st)
             self.span_tenants[st.tenant].released += 1
             if self.tracer.enabled:
@@ -1488,117 +1453,77 @@ class RegionalControlPlane(ChainBroker):
         del self._local[rid]
         del self._grid_of[(r, lrid)]
 
-    def _displace_spans(self, pred) -> list[Ticket]:
-        """Tear down every active spanning placement matching ``pred`` and
-        requeue its request with its home region (environment displacement
-        is handled exactly like preemption: accounted, never dropped).
-        Returns the old part tickets, mirroring the centralized churn
-        contract."""
-        old: list[Ticket] = []
-        displaced: list[SpanningTicket] = []
-        for rid in [
-            g for g, st in self._span_active.items() if pred(st)
-        ]:
-            st = self._span_active.pop(rid)
-            old += self._teardown_span(st)
-            self.span_stats["displaced"] += 1
-            self.span_tenants[st.tenant].preempted += 1
-            if self.tracer.enabled:
-                self.tracer.flow_point(rid, "displaced", churn=True)
-            if rid in self._broker_held:
-                self._broker_held.discard(rid)
-                self.span_tenants[st.tenant].released += 1
-                if self.on_broker_displace is not None:
-                    self.on_broker_displace(rid)
-                continue
-            displaced.append(st)
-        # back-to-front so the batch keeps FIFO-within-class order in any
-        # shared home queue (a cumulative-budget drop simply leaves its
-        # slot empty)
-        for st in reversed(displaced):
-            self._requeue_or_livelock_drop(st)
-        return old
-
     def _span_uses_node(self, st: SpanningTicket, v: int) -> bool:
-        """Does the placement touch global node ``v`` — as a gateway of
-        any hop, or anywhere on a segment's (region-local) route?"""
-        for (u, w) in st.cuts:
-            if v in (u, w):
-                return True
+        """Does the placement touch node ``v`` — as a gateway of any hop,
+        or anywhere inside a region's segment (asked of the region, in its
+        ids)?"""
+        if any(v in c for c in st.cuts):
+            return True
         for part in st.parts:
             view = self.views[part.region]
-            if not view.contains(v):
-                continue
-            lv = view.to_local(v)
-            tk = self.regions[part.region].placer.tickets.get(part.tid)
-            if tk is not None and lv in tk.mapping.route:
+            if view.contains(v) and self.regions[part.region].broker_uses_node(
+                    part.tid, int(view.to_local(v))):
                 return True
         return False
 
     def _span_uses_link(self, st: SpanningTicket, u: int, v: int) -> bool:
+        """Does a segment of the placement ride the in-region link (u, v)?"""
         for part in st.parts:
             view = self.views[part.region]
-            if not (view.contains(u) and view.contains(v)):
-                continue
-            lu, lv = view.to_local(u), view.to_local(v)
-            tk = self.regions[part.region].placer.tickets.get(part.tid)
-            if tk is not None and (
-                (lu, lv) in tk.edge_load or (lv, lu) in tk.edge_load
-            ):
+            if view.contains(u) and view.contains(v) and \
+                    self.regions[part.region].broker_uses_link(
+                        part.tid, int(view.to_local(u)),
+                        int(view.to_local(v))):
                 return True
         return False
 
-    def _churn_call(self, fn) -> tuple[list[Ticket], list[Ticket]]:
+    def _churn_call(self, fn) -> tuple[list, list]:
         """Run a region churn operation collecting any spanning placements
         its rescue preemptions displace, so the ``(alive, requeued)``
         return covers every handle the event invalidated."""
-        self._churn_collector = hook_old = []
+        self._churn_collector = collected = []
         try:
             alive, requeued = fn()
         finally:
             self._churn_collector = None
-        return alive, requeued + hook_old
+        return alive, requeued + collected
 
-    def fail_node(self, v: int) -> tuple[list[Ticket], list[Ticket]]:
-        """Take global node ``v`` down.  Spanning placements touching it
-        (as a gateway or anywhere on a segment route) are displaced back
-        to their broker queues first, then the owning region re-maps its
-        local tickets on the degraded subgraph (in its local id space; the
-        region's view is invalidated — bijection generation bumped).  Same
-        ``(alive, requeued)`` contract as the centralized plane;
-        ``requeued`` also covers spanning placements displaced by rescue
-        preemptions during the re-map."""
+    def fail_node(self, v: int) -> tuple[list, list]:
+        """Take node ``v`` down.  Spanning placements touching it (as a
+        gateway or anywhere inside a segment) are displaced back to their
+        queues first, then the owning region re-maps its own work in its
+        local ids (its view is invalidated; the bump propagates to the
+        enclosing views).  Same ``(alive, requeued)`` contract as the
+        centralized plane; ``requeued`` also covers spanning placements
+        displaced by rescue preemptions during the re-map."""
         v = int(v)
         self.node_up[v] = False
         requeued_span = self._displace_spans(
             lambda st: self._span_uses_node(st, v)
         )
         r = int(self.region_of[v])
-        self.views[r].invalidate()
-        lv = int(self.views[r].to_local(v))
         alive, requeued = self._churn_call(
-            lambda: self.regions[r].fail_node(lv)
+            lambda: self.regions[r].fail_node(int(self.views[r].to_local(v)))
         )
         return alive, requeued + requeued_span
 
-    def fail_link(self, u: int, v: int) -> tuple[list[Ticket], list[Ticket]]:
+    def fail_link(self, u: int, v: int) -> tuple[list, list]:
         """Take a (symmetric) link down: an in-region link fails through
-        the owning region (translated to its local id space); a *cut*
-        link degrades the quotient graph — every spanning placement riding
-        it is displaced and requeued, and chains re-route around it on the
-        next pump (healed by ``restore_link``)."""
+        the owning region (in its ids) once the spanning placements riding
+        it have left through this broker; a *cut* link degrades the
+        quotient graph — every spanning placement riding it is displaced
+        and requeued, and chains re-route around it on the next pump
+        (healed by ``restore_link``)."""
         u, v = int(u), int(v)
         if self.region_of[u] == self.region_of[v]:
-            # spanning segments routed over the link must leave through the
-            # broker (the inner remap cannot requeue a composite placement)
             requeued_span = self._displace_spans(
                 lambda st: self._span_uses_link(st, u, v)
             )
             r = int(self.region_of[u])
-            self.views[r].invalidate()
-            lu, lv = int(self.views[r].to_local(u)), int(self.views[r].to_local(v))
+            view = self.views[r]
             alive, requeued = self._churn_call(
-                lambda: self.regions[r].fail_link(lu, lv)
+                lambda: self.regions[r].fail_link(
+                    int(view.to_local(u)), int(view.to_local(v)))
             )
             return alive, requeued + requeued_span
         for e in ((u, v), (v, u)):
@@ -1613,17 +1538,15 @@ class RegionalControlPlane(ChainBroker):
         v = int(v)
         self.node_up[v] = True
         r = int(self.region_of[v])
-        self.views[r].invalidate()
         self.regions[r].restore_node(int(self.views[r].to_local(v)))
 
     def restore_link(self, u: int, v: int) -> None:
         u, v = int(u), int(v)
         if self.region_of[u] == self.region_of[v]:
             r = int(self.region_of[u])
-            self.views[r].invalidate()
+            view = self.views[r]
             self.regions[r].restore_link(
-                int(self.views[r].to_local(u)), int(self.views[r].to_local(v))
-            )
+                int(view.to_local(u)), int(view.to_local(v)))
             return
         for e in ((u, v), (v, u)):
             if e in self.cut_link_up:
@@ -1632,49 +1555,59 @@ class RegionalControlPlane(ChainBroker):
     # -- defragmentation ------------------------------------------------------
 
     def defrag(self, *, max_extras: Optional[int] = None) -> list:
-        """Per-region re-optimization — there is deliberately no global
+        """Leaf-local re-optimization — there is deliberately no global
         re-solve (that would be the centralized plane again).  Spanning
-        segments are standing tickets with pinned gateways, so each region
+        segments are standing tickets with pinned gateways, so each leaf
         may re-pack them locally; tids (and thus spanning handles) are
-        preserved.  Returns one DefragResult per region."""
-        return [cp.defrag(max_extras=max_extras) for cp in self.regions]
+        preserved.  Returns one DefragResult per leaf, depth-first."""
+        return [cp.defrag(max_extras=max_extras)
+                for _, cp in self.leaf_planes()]
 
     # -- reporting / invariants ----------------------------------------------
 
+    def leaf_planes(self) -> list:
+        """Every leaf region's (view from this plane's ids, ControlPlane),
+        depth-first — nested bijections composed into one, for
+        cross-level write-through checks and reporting."""
+        return [
+            (view if cv is None else view.compose(cv), cp)
+            for view, child in zip(self.views, self.regions)
+            for cv, cp in child.leaf_planes()
+        ]
+
     def _kernel_impl_counts(self) -> dict:
-        """Per-backend solve counts summed over every region's placer."""
+        """Per-backend solve counts summed over every leaf placer."""
         out: dict[str, int] = {}
-        for cp in self.regions:
-            for k, v in cp._kernel_impl_counts().items():
+        for child in self.regions:
+            for k, v in child._kernel_impl_counts().items():
                 out[k] = out.get(k, 0) + v
         return out
 
     def _solve_counts(self) -> tuple[int, int]:
         solves = n_sum = 0
-        for cp in self.regions:
-            s, n = cp._solve_counts()
+        for child in self.regions:
+            s, n = child._solve_counts()
             solves += s
             n_sum += n
         return solves, n_sum
 
     def engine_stats(self) -> engine.Stats:
         s = engine.Stats(method=self.method)
-        s.preemptions = sum(
-            cp.placer.stats.preempted for cp in self.regions)
-        s.defrag_rounds = sum(
-            cp.placer.stats.defrag_rounds for cp in self.regions)
-        s.solve_ms = sum(cp.placer.stats.solve_ms for cp in self.regions)
-        s.overhead_ms = sum(
-            cp.placer.stats.overhead_ms for cp in self.regions)
-        s.conflict_resolve_ms = sum(
-            cp.placer.stats.conflict_resolve_ms for cp in self.regions)
-        s.stale_batches = sum(
-            cp.placer.stats.stale_batches for cp in self.regions)
-        s.fallbacks = sum(cp.placer.stats.fallbacks for cp in self.regions)
+        for child in self.regions:
+            cs = child.engine_stats()
+            s.preemptions += cs.preemptions
+            s.defrag_rounds += cs.defrag_rounds
+            s.solve_ms += cs.solve_ms
+            s.overhead_ms += cs.overhead_ms
+            s.conflict_resolve_ms += cs.conflict_resolve_ms
+            s.stale_batches += cs.stale_batches
+            s.fallbacks += cs.fallbacks
+            s.gossip_messages += cs.gossip_messages
+            s.twopc_messages += cs.twopc_messages
         s.batch_size = self.micro_batch
         s.rounds = self.bus.rounds
-        s.gossip_messages = self.bus.messages_sent
-        s.twopc_messages = self._twopc_msgs
+        s.gossip_messages += self.bus.messages_sent
+        s.twopc_messages += self._twopc_msgs
         s.messages_sent = s.gossip_messages + s.twopc_messages
         solves, n_sum = self._solve_counts()
         if solves:
@@ -1687,31 +1620,30 @@ class RegionalControlPlane(ChainBroker):
 
     def metrics_registry(self) -> obs_metrics.MetricsRegistry:
         """One merged registry: every region's registry labeled
-        ``plane=r{r}`` (mirroring the gossip aggregation direction), plus
-        the broker's own gossip / 2PC / spanning counters."""
+        ``plane=r{r}`` (label paths compose per level, e.g. ``r0/r1``),
+        plus this broker's gossip / 2PC / spanning counters."""
         reg = obs_metrics.MetricsRegistry()
-        for r, cp in enumerate(self.regions):
-            reg.merge(cp.metrics_registry(), plane=f"r{r}")
+        for r, child in enumerate(self.regions):
+            reg.merge(child.metrics_registry(), plane=f"r{r}")
         obs_metrics.absorb_gossip_stats(reg, self.bus.gossip_stats())
         obs_metrics.absorb_span_stats(reg, self.span_stats)
         reg.inc("twopc.messages", float(self._twopc_msgs))
+        reg.gauge("plane.levels", float(self.levels))
         return reg
 
     def solve_size_report(self) -> dict:
         """The compute-locality story in numbers: the padded node
-        dimension every regional DP actually ran over, next to the global
-        ``n`` the masked (pre-compaction) plane would have paid."""
-        per = []
-        for r, cp in enumerate(self.regions):
-            st = cp.placer.stats
-            per.append({
-                "region": r,
-                "n_r": self.views[r].n_local,
-                "solves": st.solves,
-                "mean_solve_n": st.mean_solve_n,
-            })
+        dimension every leaf DP actually ran over, next to the global
+        ``n`` an uncompacted plane would have paid."""
+        leaves = self.leaf_planes()
+        per = [{
+            "region": i,
+            "n_r": view.n_local,
+            "solves": cp.placer.stats.solves,
+            "mean_solve_n": cp.placer.stats.mean_solve_n,
+        } for i, (view, cp) in enumerate(leaves)]
         solves = sum(p["solves"] for p in per)
-        nsum = sum(cp.placer.stats.solve_n_sum for cp in self.regions)
+        nsum = sum(cp.placer.stats.solve_n_sum for _, cp in leaves)
         return {
             "global_n": self.base.n,
             "regions": per,
@@ -1719,32 +1651,27 @@ class RegionalControlPlane(ChainBroker):
             "mean_solve_n": (nsum / solves) if solves else 0.0,
             "max_solve_n": max(
                 (p["n_r"] for p in per if p["solves"]), default=0),
-            "balanced_n_r": math.ceil(self.base.n / max(self.R, 1)),
+            "balanced_n_r": math.ceil(self.base.n / len(leaves)),
         }
 
-    def resident_state_report(self) -> dict:
-        """Max per-component resident state — the scaling metric the
-        hierarchical plane is graded on.  Each region holds its
-        ``n_r``-sized solve/residual state plus one gossip record per peer
-        (R at steady state); the broker holds the quotient graph (R) plus
-        its boundary id table — the distinct gateway node ids in the cut
-        ledger.  A flat plane's broker is therefore O(boundary + R); the
-        hierarchy keeps every level's boundary and peer count at
-        O(branching)."""
+    def resident_state_report(self, peers: int = 0) -> dict:
+        """Max per-component resident state across the whole tree — the
+        scaling metric nesting is graded on.  The broker holds its
+        boundary id table (the distinct gateway ids of its cut ledger) plus
+        one quotient entry and gossip record per region; each region adds
+        one gossip record per sibling (``peers``) to its own state, down
+        to the leaves' ``n_r``-sized solves.  One level is therefore
+        O(boundary + R); nesting keeps every level at O(branching)."""
         gateway_ids = {v for e in self.cut_base for v in e}
         comps = [{
             "component": "broker",
             "id_table": len(gateway_ids),
-            "peers": self.R,
-            "state": len(gateway_ids) + self.R,
+            "peers": self.R + peers,
+            "state": len(gateway_ids) + self.R + peers,
         }]
-        for r in range(self.R):
-            comps.append({
-                "component": f"region[{r}]",
-                "solve_n": self.views[r].n_local,
-                "peers": self.R,
-                "state": self.views[r].n_local + self.R,
-            })
+        for r, child in enumerate(self.regions):
+            for c in child.resident_state_report(peers=self.R)["components"]:
+                comps.append({**c, "component": f"region[{r}].{c['component']}"})
         return {
             "components": comps,
             "max_component_state": max(c["state"] for c in comps),
@@ -1753,9 +1680,13 @@ class RegionalControlPlane(ChainBroker):
     def coordination_report(self) -> dict:
         """The decentralization story in numbers: gossip volume/staleness
         and 2PC traffic next to the spanning admission outcomes and the
-        compacted solve sizes."""
+        compacted solve sizes; ``*_total`` sums every level."""
+        es = self.engine_stats()
         return {
+            "levels": self.levels,
             "regions": self.R,
+            "branching": self.R,
+            "leaf_regions": self.leaf_regions,
             "fanout": self.bus.fanout,
             "gossip_period": self.gossip_period,
             "gossip_rounds": self.bus.rounds,
@@ -1763,11 +1694,15 @@ class RegionalControlPlane(ChainBroker):
             "gossip_messages_per_round": (
                 self.bus.messages_sent / max(self.bus.rounds, 1)
             ),
+            "gossip_messages_total": es.gossip_messages,
             "max_staleness": self.bus.max_staleness(),
             "gossip": self.bus.gossip_stats(),
             "twopc_messages": self._twopc_msgs,
+            "twopc_messages_total": es.twopc_messages,
             "spanning": dict(self.span_stats),
             "cut_edges": len(self.cut_base),
+            "children": [] if self.levels == 1 else [
+                c.coordination_report() for c in self.regions],
             "solve_size": self.solve_size_report(),
             "resident": self.resident_state_report(),
         }
@@ -1779,27 +1714,25 @@ class RegionalControlPlane(ChainBroker):
             {t: st.cfg.weight for t, st in self.span_tenants.items()},
         )
         rep["coordination"] = self.coordination_report()
+        es = self.engine_stats()
         rep["timing"] = {
-            "solve_ms": sum(
-                cp.placer.stats.solve_ms for cp in self.regions),
-            "overhead_ms": sum(
-                cp.placer.stats.overhead_ms for cp in self.regions),
-            "conflict_resolve_ms": sum(
-                cp.placer.stats.conflict_resolve_ms for cp in self.regions),
+            "solve_ms": es.solve_ms,
+            "overhead_ms": es.overhead_ms,
+            "conflict_resolve_ms": es.conflict_resolve_ms,
         }
         return rep
 
     def check_invariants(self) -> None:
-        """Every region's placer + ledger invariants, the global ledger,
+        """Every region's invariants (recursively), this broker's ledger,
         cut-bandwidth conservation, spanning-handle integrity (liveness,
-        chain well-formedness, bijection versions), and the write-through
-        global conservation of the compacted substrate: the per-region
-        local residuals + local ticket loads, lifted through the views,
-        must re-assemble the base network exactly."""
-        for cp in self.regions:
-            cp.check_invariants()
+        chain well-formedness, view versions), and the write-through
+        conservation of the compacted substrate: the leaf residuals and
+        ticket loads, lifted through the composed views, must re-assemble
+        the base network exactly."""
+        for child in self.regions:
+            child.check_invariants()
         led = self.conservation()
-        assert led["ok"], f"global ticket conservation violated: {led}"
+        assert led["ok"], f"ticket conservation violated: {led}"
         # span accounting: attempts/admitted are counted at exactly one
         # site each (_try_place_spanning entry / 2PC commit), so the
         # counters nest strictly — a double-count on any path breaks this
@@ -1836,27 +1769,27 @@ class RegionalControlPlane(ChainBroker):
                 assert int(self.region_of[u]) == st.parts[i].region
                 assert int(self.region_of[v]) == st.parts[i + 1].region
             for part in st.parts:
-                tk = self.regions[part.region].placer.tickets.get(part.tid)
-                assert tk is not None and tk.df is part.seg, (
-                    f"spanning rid {rid} holds a stale segment in region "
-                    f"{part.region}"
+                assert self.regions[part.region].broker_holds(
+                    part.tid, part.seg), (
+                    f"spanning rid {rid} holds a stale or dead reservation "
+                    f"in region {part.region}"
                 )
                 assert self._part_of.get((part.region, part.tid)) == rid
                 assert part.version <= self.views[part.region].version, (
                     f"spanning rid {rid}: part minted under a future "
-                    "bijection version"
+                    "view version"
                 )
-        # write-through conservation: re-assemble the global network from
-        # the compacted regional state.  Node capacity must reconstruct
-        # exactly; in-region bandwidth likewise; cut bandwidth is checked
-        # above (it belongs to the broker, not to any region).
-        cap_res = np.zeros(self.base.n)
-        cap_load = np.zeros(self.base.n)
-        bw_res = np.zeros((self.base.n, self.base.n))
-        bw_load = np.zeros((self.base.n, self.base.n))
-        in_region = np.zeros((self.base.n, self.base.n), bool)
-        for r, cp in enumerate(self.regions):
-            view = self.views[r]
+        # write-through conservation: re-assemble this plane's network
+        # from the leaf state.  Node capacity must reconstruct exactly;
+        # in-region bandwidth likewise; cut bandwidth is checked above (it
+        # belongs to a broker, not to any region).
+        n = self.base.n
+        cap_res = np.zeros(n)
+        cap_load = np.zeros(n)
+        bw_res = np.zeros((n, n))
+        bw_load = np.zeros((n, n))
+        in_region = np.zeros((n, n), bool)
+        for view, cp in self.leaf_planes():
             cap_res += view.uncompact_node_vec(cp.placer.cap)
             bw_res += view.uncompact_link_mat(cp.placer.bw)
             in_region |= view.uncompact_link_mat(

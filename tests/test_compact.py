@@ -438,18 +438,17 @@ def test_invalidate_propagates_through_derivation_chain():
     # the regional plane drives this end to end: churn in one leaf region
     # of a hierarchy bumps the enclosing group view automatically
     from repro.core import region_tree
-    from repro.service import HierarchicalControlPlane
 
     trg, assign = region_tree(2, 2, 3, seed=1)
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         trg, levels=2, region_of=assign, seed=0, **PYM)
     cp.register_tenant("a")
-    g = int(cp.group_of[0])
+    g = int(cp.region_of[0])
     top0 = cp.views[g].version
-    leaf0 = cp.children[g].views[0].version
+    leaf0 = cp.regions[g].views[0].version
     other = [v.version for v in cp.views if v is not cp.views[g]]
     cp.fail_node(0)
-    assert cp.children[g].views[0].version == leaf0 + 1
+    assert cp.regions[g].views[0].version == leaf0 + 1
     assert cp.views[g].version == top0 + 1  # propagated up
     assert [v.version for v in cp.views if v is not cp.views[g]] == other
     cp.check_invariants()

@@ -1,8 +1,6 @@
-"""Hierarchical control plane property suite.
+"""Nested regional brokers: the property suite of ``levels >= 2``.
 
-The nesting claims, fuzz-enforced: the levels=1 hierarchy is bit-identical
-to the flat regional plane (the same composition argument as R=1 vs the
-centralized plane, one level up); nested planes keep every level's ticket
+The nesting claims, fuzz-enforced: nested planes keep every level's ticket
 ledger, cut conservation and cross-level write-through intact under
 adversarial interleavings; spanning decomposition recurses (a top-level
 segment may split again inside its child); churn displacement chains
@@ -13,11 +11,10 @@ no component's resident state scales with the global plane.
 import numpy as np
 import pytest
 
-from repro.core import DataflowPath, random_dataflow, region_tree, waxman
+from repro.core import DataflowPath, random_dataflow, region_tree
 from repro.service import (
     ControlPlane,
     FairSharePolicy,
-    HierarchicalControlPlane,
     RegionalControlPlane,
     resolve_nesting,
 )
@@ -69,9 +66,9 @@ def test_region_tree_generator_shape():
 def test_facade_dispatches_on_levels():
     rg, assign = region_tree(2, 2, 3, seed=0)
     cp = ControlPlane(rg, levels=2, region_of=assign, **PYM)
-    assert isinstance(cp, HierarchicalControlPlane)
-    assert cp.levels == 2 and cp.B == 2 and cp.leaf_regions == 4
-    assert all(isinstance(c, RegionalControlPlane) for c in cp.children)
+    assert isinstance(cp, RegionalControlPlane)
+    assert cp.levels == 2 and cp.R == 2 and cp.leaf_regions == 4
+    assert all(isinstance(c, RegionalControlPlane) for c in cp.regions)
     # the solver config must never see the nesting kwargs
     cp.register_tenant("a")
     cp.submit("a", DataflowPath.make([0.0, 0.1], [1.0], 0, 1))
@@ -82,14 +79,14 @@ def test_facade_dispatches_on_levels():
     assert isinstance(flat, RegionalControlPlane) and flat.R == 4
     # regions= alone resolves branching when it is a perfect power
     cp3 = ControlPlane(rg, levels=2, regions=4, **PYM)
-    assert isinstance(cp3, HierarchicalControlPlane) and cp3.B == 2
+    assert isinstance(cp3, RegionalControlPlane) and cp3.R == 2
     # deeper nesting recurses
     rg3, assign3 = region_tree(3, 2, 3, seed=1)
     cp4 = ControlPlane(rg3, levels=3, region_of=assign3, **PYM)
-    assert isinstance(cp4, HierarchicalControlPlane)
+    assert isinstance(cp4, RegionalControlPlane)
     assert all(
-        isinstance(c, HierarchicalControlPlane) for c in cp4.children)
-    assert all(c.levels == 2 for c in cp4.children)
+        isinstance(c, RegionalControlPlane) for c in cp4.regions)
+    assert all(c.levels == 2 for c in cp4.regions)
 
 
 def test_nesting_kwargs_fail_fast():
@@ -112,97 +109,13 @@ def test_nesting_kwargs_fail_fast():
         ControlPlane(rg, levels=2, branching=3, region_of=assign, **PYM)
     with pytest.raises(ValueError, match="branching=5 contradicts 3 leaf"):
         resolve_nesting(1, 5, 3)
-    # direct construction of the flat plane rejects the nesting kwargs too
-    with pytest.raises(ValueError, match="flat"):
+    # direct construction of the broker resolves the nesting kwargs the
+    # same way: one level takes no branching=, and two levels need a
+    # perfect-power leaf count
+    with pytest.raises(ValueError, match="not a perfect levels=2 power"):
         RegionalControlPlane(rg, regions=2, levels=2, **PYM)
     with pytest.raises(ValueError, match="hierarchical"):
         RegionalControlPlane(rg, regions=2, branching=2, **PYM)
-
-
-# ---------------------------------------------------------------------------
-# levels=1 bit-identity (the flat plane falls out as the special case)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("seed", [0, 1])
-def test_levels1_hierarchy_bit_identical_to_flat(seed):
-    """HierarchicalControlPlane(levels=1) replays the exact flat
-    RegionalControlPlane behavior — same rids, same tickets, same residual
-    arrays bit for bit, same ledger — step by step under a fuzzed op
-    sequence (the R=1-vs-centralized argument, one level up: one child
-    under the identity view, pure delegation, seeds aligned)."""
-    rg = waxman(14, seed=4)
-    kw = dict(micro_batch=6, max_attempts=3, seed=seed,
-              policy=FairSharePolicy(slack=0.4), **PYM)
-    flat = RegionalControlPlane(rg, regions=3, **kw)
-    hier = HierarchicalControlPlane(rg, levels=1, regions=3, **kw)
-    assert hier.B == 1 and hier.children[0].R == 3
-    for cp in (flat, hier):
-        cp.register_tenant("a", weight=3.0)
-        cp.register_tenant("b", weight=1.0)
-    rng = np.random.default_rng(seed)
-    failed: list[int] = []
-    for step in range(60):
-        op = rng.choice(
-            ["submit", "pump", "release", "fail", "restore", "defrag"],
-            p=[0.35, 0.28, 0.15, 0.08, 0.07, 0.07],
-        )
-        if op == "submit":
-            df = random_dataflow(rg, 4, seed=3000 * seed + step,
-                                 creq_range=(0.05, 0.3),
-                                 breq_range=(0.5, 3.0))
-            t = str(rng.choice(["a", "b"]))
-            k = int(rng.integers(0, 3))
-            assert flat.submit(t, df, klass=k) == hier.submit(t, df, klass=k)
-        elif op == "pump":
-            r = int(rng.integers(1, 3))
-            hf = [(getattr(t, "tid", None), getattr(t, "rid", None))
-                  for t in flat.pump(rounds=r)]
-            hh = [(getattr(t, "tid", None), getattr(t, "rid", None))
-                  for t in hier.pump(rounds=r)]
-            assert hf == hh
-        elif op == "release":
-            ids = flat.active_ids()
-            assert ids == hier.active_ids()
-            if ids:
-                rid = int(rng.choice(ids))
-                flat.release(rid)
-                hier.release(rid)
-        elif op == "fail" and len(failed) < 3:
-            v = int(rng.integers(0, rg.n))
-            if v not in failed:
-                a1, q1 = flat.fail_node(v)
-                a2, q2 = hier.fail_node(v)
-                assert [t.tid for t in a1] == [t.tid for t in a2]
-                assert [t.tid for t in q1] == [t.tid for t in q2]
-                failed.append(v)
-        elif op == "restore" and failed:
-            v = failed.pop(int(rng.integers(0, len(failed))))
-            flat.restore_node(v)
-            hier.restore_node(v)
-        elif op == "defrag":
-            rf = flat.defrag()
-            rh = hier.defrag()
-            assert [(r.committed, r.repacked, r.moved) for r in rf] == \
-                [(r.committed, r.repacked, r.moved) for r in rh]
-        # -- bit-for-bit state equality, every step
-        inner = hier.children[0]
-        assert flat.active_ids() == hier.active_ids()
-        for r in range(flat.R):
-            np.testing.assert_array_equal(
-                flat.regions[r].placer.cap, inner.regions[r].placer.cap)
-            np.testing.assert_array_equal(
-                flat.regions[r].placer.bw, inner.regions[r].placer.bw)
-            assert sorted(flat.regions[r].placer.tickets) == \
-                sorted(inner.regions[r].placer.tickets)
-        assert flat.cut_residual == inner.cut_residual
-        assert flat.conservation() == hier.conservation()
-        flat.check_invariants()
-        hier.check_invariants()
-    # the enclosing level spent zero coordination messages at levels=1
-    assert hier.bus.messages_sent == 0 and hier._twopc_msgs == 0
-    assert hier.engine_stats().twopc_messages == \
-        flat.engine_stats().twopc_messages
 
 
 # ---------------------------------------------------------------------------
@@ -266,7 +179,7 @@ def _fuzz_hierarchy(cp, rg, seed, steps=60):
 @pytest.mark.parametrize("seed", [0, 1])
 def test_fuzz_hierarchy_conservation(seed):
     rg, assign = region_tree(2, 3, 4, seed=3)  # 9 leaves, n=36
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         rg, levels=2, region_of=assign, micro_batch=6, max_attempts=3,
         seed=seed, policy=FairSharePolicy(slack=0.4), **PYM,
     )
@@ -281,7 +194,7 @@ def test_fuzz_hierarchy_conservation(seed):
 @pytest.mark.parametrize("seed", [2, 3])
 def test_fuzz_hierarchy_conservation_3level(seed):
     rg, assign = region_tree(3, 2, 3, seed=4)  # 8 leaves, n=24
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         rg, levels=3, region_of=assign, micro_batch=6, max_attempts=3,
         seed=seed, policy=FairSharePolicy(slack=0.4), **PYM,
     )
@@ -298,7 +211,7 @@ def test_fuzz_hierarchy_conservation_3level(seed):
 
 def _tree_plane(levels=2, b=2, k=4, seed=0, **kw):
     rg, assign = region_tree(levels, b, k, seed=seed)
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         rg, levels=levels, region_of=assign, micro_batch=8,
         max_attempts=4, seed=seed, **PYM, **kw,
     )
@@ -325,20 +238,20 @@ def test_cross_group_spanning_splits_at_every_level():
     assert cp.span_stats["admitted"] == 1
     # each part is a live broker-held reservation inside its child
     for part in st.parts:
-        child = cp.children[part.region]
+        child = cp.regions[part.region]
         assert part.tid in child._broker_held
         assert part.tid in child._span_active
     cp.check_invariants()
     # src and dst leaves are in different groups AND different leaf
     # regions inside them, so at least one child had to split again
     # (its broker-held span has its own cut) or place via its gateway
-    assert cp.group_of[0] != cp.group_of[rg.n - 1]
+    assert cp.region_of[0] != cp.region_of[rg.n - 1]
     cp.release(rid)
     cp.check_invariants()
     led = cp.conservation()
     assert led["active"] == 0 and led["ok"]
     # the teardown released every child holding too
-    for child in cp.children:
+    for child in cp.regions:
         assert not child._broker_held
 
 
@@ -350,7 +263,7 @@ def test_gateway_failure_displaces_top_span_and_heals():
     alive, requeued = cp.fail_node(u)
     assert st in requeued
     assert rid not in cp._span_active
-    for child in cp.children:
+    for child in cp.regions:
         assert not child._broker_held  # sibling reservations torn down
     cp.check_invariants()
     assert cp.conservation()["ok"]
@@ -392,8 +305,33 @@ def test_child_displacement_chains_up_through_broker_hook():
     assert used, "span places no interior node; pick a bigger instance"
     cp.fail_node(used[0])
     assert rid not in cp._span_active
-    for child in cp.children:
+    for child in cp.regions:
         assert not child._broker_held
+    cp.check_invariants()
+    assert cp.conservation()["ok"]
+
+
+def test_leaf_preemption_of_a_segment_chains_up_to_the_top():
+    """A top-class request inside a leaf that preempts a segment of a
+    top-level span: the leaf hands the victim to its broker, which hands
+    the displaced reservation up, and the top level tears the whole
+    composite down and requeues it — nothing leaks at any level."""
+    rg, assign, cp = _tree_plane(levels=2, b=2, k=4)
+    span_rid = cp.submit("a", _cross_tree_df(rg, creq=0.5))
+    (st,) = cp.pump()
+    u = next(
+        view.to_global(lv)
+        for view, leaf in cp.leaf_planes()
+        for tk in leaf.placer.tickets.values()
+        for lv, c in sorted(tk.node_load.items()) if c > 0
+    )
+    w = next(x for x in range(rg.n) if x != u and assign[x] == assign[u])
+    hog = cp.submit("a", DataflowPath.make([float(rg.cap[u]), 0.0], [0.1],
+                                           u, w), klass=2)
+    cp.pump()
+    assert hog in cp.active_ids()
+    assert cp.span_stats["displaced"] == 1
+    assert cp._span_active.get(span_rid) is not st
     cp.check_invariants()
     assert cp.conservation()["ok"]
 
@@ -404,7 +342,7 @@ def test_release_rejects_parent_held_rid_at_child_level():
     (st,) = cp.pump()
     part = st.parts[0]
     with pytest.raises(KeyError, match="broker"):
-        cp.children[part.region].release(part.tid)
+        cp.regions[part.region].release(part.tid)
 
 
 def test_flat_broker_admit_release_roundtrip():
@@ -445,7 +383,7 @@ def test_tree_gossip_message_and_record_budget():
     at most ``branching`` aggregated records — never one record per leaf
     region, let alone per node."""
     rg, assign = region_tree(2, 4, 3, seed=6)  # 16 leaves, n=48
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         rg, levels=2, region_of=assign, fanout=2, seed=0, **PYM)
     cp.register_tenant("a")
     rng = np.random.default_rng(0)
@@ -455,9 +393,9 @@ def test_tree_gossip_message_and_record_budget():
                                        breq_range=(0.5, 2.0)))
     cp.pump(rounds=6)
     top = cp.bus.gossip_stats()
-    assert top["messages_per_round"] <= cp.B * cp.bus.fanout
-    assert top["records_per_message"] <= cp.B
-    for child in cp.children:
+    assert top["messages_per_round"] <= cp.R * cp.bus.fanout
+    assert top["records_per_message"] <= cp.R
+    for child in cp.regions:
         st = child.bus.gossip_stats()
         assert st["messages_per_round"] <= child.R * child.bus.fanout
         assert st["records_per_message"] <= child.R
@@ -472,7 +410,7 @@ def test_tree_gossip_message_and_record_budget():
                                          breq_range=(0.5, 2.0)))
     flat.pump(rounds=6)
     fst = flat.bus.gossip_stats()
-    assert fst["records_per_message"] > cp.B  # the flat view is R-sized
+    assert fst["records_per_message"] > cp.R  # the flat view is R-sized
     cp.check_invariants()
 
 
@@ -504,7 +442,7 @@ def test_resident_state_hierarchy_strictly_below_flat():
     must hold every gateway id and every region as a peer."""
     rg, assign = region_tree(2, 4, 3, seed=7)  # 16 leaves, n=48
     flat = RegionalControlPlane(rg, region_of=assign, seed=0, **PYM)
-    hier = HierarchicalControlPlane(
+    hier = RegionalControlPlane(
         rg, levels=2, region_of=assign, seed=0, **PYM)
     f = flat.resident_state_report()
     h = hier.resident_state_report()
@@ -548,7 +486,8 @@ def test_routing_knobs_propagate_down_the_tree():
         assert p.chain_k == 3
         assert p.congestion_weight == 0.5
         assert p.max_cum_attempts == 7
-        planes.extend(getattr(p, "children", []))
+        if p.levels > 1:
+            planes.extend(p.regions)
     cp.register_tenant("a")
     cp.submit("a", _cross_tree_df(rg))
     cp.pump()
@@ -575,11 +514,11 @@ def test_congestion_published_at_every_level():
     own = cp._gateways_of.get(0, ())
     assert own and all(u in view for u in own)
     assert all(0.0 <= view[u] <= 1.0 for u in view)
-    for g in range(cp.B):
+    for g in range(cp.R):
         for u in cp._gateways_of.get(g, ()):
             assert 0.0 <= cp.node_occupancy(int(u)) <= 1.0
     # the child planes publish their own (local-id) gateway estimates too
-    child = cp.children[0]
+    child = cp.regions[0]
     crec = child.bus.views[0].get(0)
     assert crec is not None and isinstance(crec.congestion, dict)
 
@@ -611,7 +550,7 @@ def test_fuzz_hierarchy_k_chain_conservation(seed):
     """The full fuzz suite with the k-chain racer live at every level of
     a 3-sibling tree (real bypass chains exist top-level)."""
     rg, assign = region_tree(2, 3, 4, seed=3)
-    cp = HierarchicalControlPlane(
+    cp = RegionalControlPlane(
         rg, levels=2, region_of=assign, micro_batch=6, max_attempts=3,
         seed=seed, chain_k=3, policy=FairSharePolicy(slack=0.4), **PYM,
     )

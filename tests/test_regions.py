@@ -326,6 +326,27 @@ def test_spanning_fairness_uses_gossiped_estimates():
     cp.check_invariants()
 
 
+def test_region_drain_counts_segments_it_holds_for_the_broker():
+    """A region's fair-share drain sees the spanning segments it holds for
+    the broker (placer tickets, not its own requests): with gossip off,
+    the tenant holding a segment there drains after one holding nothing,
+    though both are equally weighted and it sorts first by name."""
+    rg = waxman(18, seed=0)
+    cp = RegionalControlPlane(rg, regions=2, seed=0, fanout=0, micro_batch=1,
+                              **PYM)
+    cp.register_tenant("a")
+    cp.register_tenant("b")
+    (u, v) = max(cp.cut_base, key=cp.cut_base.get)
+    rid = cp.submit("a", DataflowPath.make([0.5, 0.5], [1.0], src=u, dst=v))
+    assert [getattr(t, "rid", None) for t in cp.pump()] == [rid]
+    x = int(np.nonzero(cp.region_of == cp.region_of[u])[0][0])
+    for tenant in ("a", "b"):
+        cp.submit(tenant, DataflowPath.make([0.1], [], src=x, dst=x))
+    (first,) = cp.pump()
+    assert first.tenant == "b"
+    cp.check_invariants()
+
+
 # ---------------------------------------------------------------------------
 # property suite: seeded fuzz across R regions
 # ---------------------------------------------------------------------------
@@ -685,7 +706,7 @@ def test_region_dropping_segment_tears_down_whole_span():
     """Regression: churn driven through the INNER region plane (bypassing
     the broker's own displacement pass) drops a spanning segment the
     local plane has no rid for.  The broker must still learn of it
-    (on_foreign_preempt hand-off) and tear down the sibling reservations
+    (on_broker_displace hand-off) and tear down the sibling reservations
     + cut bandwidth instead of leaking them."""
     rg, cp = _line_plane(2)
     (u, v) = max(cp.cut_base, key=cp.cut_base.get)
@@ -735,9 +756,9 @@ def test_displace_span_part_is_idempotent():
     part = t.parts[0]
     tk = cp.regions[part.region].placer.tickets[part.tid]
     cp.regions[part.region].placer.release(part.tid, reason=None)
-    cp._displace_span_part(part.region, tk)
+    cp._child_displaced(part.region, tk.tid, [tk])
     led1 = cp.conservation()
-    cp._displace_span_part(part.region, tk)  # double teardown: no-op
+    cp._child_displaced(part.region, tk.tid, [tk])  # double teardown: no-op
     assert cp.conservation() == led1
     assert led1["queued"] == 1
     cp.check_invariants()
